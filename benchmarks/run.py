@@ -27,6 +27,8 @@ def main() -> None:
     ap.add_argument("--json", metavar="PATH", default=None,
                     help="write the serving sweep as JSON to PATH")
     args = ap.parse_args()
+    from repro.runtime.device import use_compile_cache
+    use_compile_cache()
     t0 = time.time()
 
     print("name,us_per_call,derived")

@@ -37,11 +37,13 @@ from repro.kernels import ops, ref
 from repro.launch.serve import dense_generate
 from repro.models import lm
 from repro.parallel.sharding import make_rules
+from repro.runtime.device import use_compile_cache
 from repro.serve import (
     PagedKVCache, PagedLM, RecurrentLM, Request, Scheduler,
     recurrent_reference_generate, static_batch_generate,
 )
 
+use_compile_cache()
 rng = np.random.default_rng(0)
 
 # --- Part 1: dense baseline loop ---------------------------------------------

@@ -2,14 +2,15 @@
 
 Every op takes ``impl`` ∈ {'pallas', 'ref'}:
 
-* ``'pallas'`` — the TPU kernel (``interpret=True`` automatically on CPU, so
-  the same call validates on this container and compiles on real TPUs);
+* ``'pallas'`` — the TPU kernel (``interpret=True`` automatically on a CPU
+  host, so the same call validates in the tests; compiled by Mosaic on a
+  TPU);
 * ``'ref'``    — the pure-jnp oracle (differentiable; used for training paths
   that need gradients and as the allclose ground truth).
 
-Wrappers own all the unglamorous parts: padding counts to pack granularity,
-padding rows to lane width, and undoing both on the way out — mirroring how
-an AXI-Pack requestor aligns bursts to the bus rather than to addresses.
+Wrappers own the shape work around the kernels: padding workload tiles and
+reshaping pools into the row stacks the stream converters move (the
+converters align rows to the chip's tiling themselves).
 """
 from __future__ import annotations
 
@@ -18,11 +19,9 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from . import ref
 from .stream_converters import (
-    DEFAULT_PACK_ROWS,
     indirect_gather_kernel,
     indirect_scatter_kernel,
     strided_gather_kernel,
@@ -86,13 +85,7 @@ def strided_gather(
     if impl == "ref" or stride == 1:
         # stride==1 → the base (contiguous) converter: plain dynamic slice.
         return ref.strided_gather(src, base, stride, count)
-    padded = count + ((-count) % DEFAULT_PACK_ROWS)
-    # Keep padded reads in-bounds by clamping the stream tail.
-    need = base + (padded - 1) * stride + 1
-    if need > src.shape[0]:
-        src = jnp.pad(src, [(0, need - src.shape[0])] + [(0, 0)] * (src.ndim - 1))
-    out = strided_gather_kernel(src, base, stride, padded, interpret=_interpret())
-    return out[:count]
+    return strided_gather_kernel(src, base, stride, count, interpret=_interpret())
 
 
 def strided_scatter(
@@ -101,39 +94,26 @@ def strided_scatter(
     """dst[base + k*stride] = packed[k] — packed strided write."""
     if impl == "ref" or stride == 1:
         return ref.strided_scatter(dst, packed, base, stride)
-    count = packed.shape[0]
-    if count % DEFAULT_PACK_ROWS:
-        # Tail rows are written via the ref path to avoid out-of-bounds DMAs.
-        main = count - count % DEFAULT_PACK_ROWS
-        dst = strided_scatter(dst, packed[:main], base, stride, impl) if main else dst
-        return ref.strided_scatter(
-            dst, packed[main:], base + main * stride, stride
-        )
     return strided_scatter_kernel(dst, packed, base, stride, interpret=_interpret())
 
 
-def _flat_state_view(pool: jax.Array) -> Tuple[jax.Array, int, int, int]:
-    """(L, B, *row) state pool → ((L·B, row) view, L, B, row_width)."""
-    l, b = int(pool.shape[0]), int(pool.shape[1])
-    row = int(np.prod(pool.shape[2:])) if pool.ndim > 2 else 1
-    return pool.reshape(l * b, row), l, b, row
+def _state_rows(pool: jax.Array) -> jax.Array:
+    """(L, B, *row) state pool → its (L·B, *row) row stack (a free reshape)."""
+    return pool.reshape((pool.shape[0] * pool.shape[1],) + pool.shape[2:])
 
 
 def recurrent_state_read(pool: jax.Array, slot: int, impl: str = "pallas") -> jax.Array:
     """Gather one sequence's recurrent state rows from an (L, B, *row) pool.
 
     Slot ``s`` of a layer-major pool is rows ``s, s+B, s+2B, ...`` of the
-    flat (L·B, row) view — exactly a strided burst with base=slot, stride=B,
-    count=L, which is the access the strided PACK converter accelerates.
+    flat (L·B, *row) view — exactly a strided burst with base=slot,
+    stride=B, count=L, which is the access the strided PACK converter
+    accelerates.
     """
     if impl == "ref":
         return ref.recurrent_state_read(pool, slot)
-    flat, l, b, row = _flat_state_view(pool)
-    pad = (-row) % 128  # strided converter packs ≥128-lane rows
-    if pad:
-        flat = jnp.pad(flat, [(0, 0), (0, pad)])
-    out = strided_gather(flat, int(slot), b, l, impl=impl)
-    return out[:, :row].reshape((l,) + pool.shape[2:])
+    l, b = int(pool.shape[0]), int(pool.shape[1])
+    return strided_gather(_state_rows(pool), int(slot), b, l, impl=impl)
 
 
 def recurrent_state_write(
@@ -143,14 +123,9 @@ def recurrent_state_write(
     the write half of the strided read-modify-write each decode step does."""
     if impl == "ref":
         return ref.recurrent_state_write(pool, slot, value)
-    flat, l, b, row = _flat_state_view(pool)
-    vflat = value.reshape(l, row)
-    pad = (-row) % 128
-    if pad:
-        flat = jnp.pad(flat, [(0, 0), (0, pad)])
-        vflat = jnp.pad(vflat, [(0, 0), (0, pad)])
-    out = strided_scatter(flat, vflat, int(slot), b, impl=impl)
-    return out[:, :row].reshape(pool.shape)
+    b = int(pool.shape[1])
+    out = strided_scatter(_state_rows(pool), value, int(slot), b, impl=impl)
+    return out.reshape(pool.shape)
 
 
 def indirect_gather(
@@ -159,9 +134,7 @@ def indirect_gather(
     """out[k] = src[indices[k]] — packed indirect read (in-memory indices)."""
     if impl == "ref":
         return ref.indirect_gather(src, indices)
-    idx, count = _pad_rows(indices.astype(jnp.int32), DEFAULT_PACK_ROWS)
-    out = indirect_gather_kernel(src, idx, interpret=_interpret())
-    return out[:count]
+    return indirect_gather_kernel(src, indices, interpret=_interpret())
 
 
 def indirect_scatter(
@@ -171,25 +144,14 @@ def indirect_scatter(
     mode: str = "set",
     impl: str = "pallas",
 ) -> jax.Array:
-    """dst[indices[k]] = packed[k] — packed indirect write."""
+    """dst[indices[k]] = packed[k] — packed indirect write.
+
+    Out-of-range indices write nothing; duplicates are last-writer-wins.
+    """
     if impl == "ref" or mode == "add":
         # Accumulating scatter needs read-modify-write; route to ref.
         return ref.indirect_scatter(dst, packed, indices, mode)
-    count = packed.shape[0]
-    pad = (-count) % DEFAULT_PACK_ROWS
-    if pad:
-        # Padded slots self-scatter row `indices[-1]`'s current value — route
-        # them to a scratch row appended to dst, then drop it.
-        dst_ext = jnp.pad(dst, [(0, 1)] + [(0, 0)] * (dst.ndim - 1))
-        packed_p = jnp.pad(packed, [(0, pad)] + [(0, 0)] * (packed.ndim - 1))
-        idx_p = jnp.concatenate(
-            [indices.astype(jnp.int32), jnp.full((pad,), dst.shape[0], jnp.int32)]
-        )
-        out = indirect_scatter_kernel(dst_ext, packed_p, idx_p, interpret=_interpret())
-        return out[:-1]
-    return indirect_scatter_kernel(
-        dst, packed, indices.astype(jnp.int32), interpret=_interpret()
-    )
+    return indirect_scatter_kernel(dst, packed, indices, interpret=_interpret())
 
 
 # ---------------------------------------------------------------------------
@@ -439,10 +401,11 @@ def paged_kv_append(
     of an int8 KV pool — turns this into *quantize-on-write*: the new rows
     are quantized per (token, kv-head) over ``D`` (``ref.quantize_kv``), the
     int8 codes scatter into the pages and the scales into the scale pools
-    through the **same** flat indices (one extra narrow indirect burst per
-    pool — the AXI-Pack picture: the value stream plus its sideband metadata
-    share one descriptor).  Returns ``(k_pages, v_pages, new_lengths)``,
-    plus ``(k_scale, v_scale)`` appended when quantizing.
+    through the **same** flat indices (the AXI-Pack picture: the value
+    stream plus its sideband metadata share one descriptor; the sideband
+    itself is written by :func:`_scatter_scales`).  Returns
+    ``(k_pages, v_pages, new_lengths)``, plus ``(k_scale, v_scale)``
+    appended when quantizing.
     """
     if impl == "ref":
         return ref.paged_kv_append(
@@ -465,25 +428,44 @@ def paged_kv_append(
         active = jnp.ones_like(lengths, dtype=bool)
     # Inactive rows — and rows whose append position falls past their table
     # row (the oracle's ``mode='drop'`` case: an un-clamped out-of-bounds
-    # gather would otherwise alias a real page) — target the scratch row
-    # appended below, then get dropped.
+    # gather would otherwise alias a real page) — target row ``p * page``,
+    # one past the pool, which the scatter converter drops.
     flat_idx = jnp.where(
         active & (lengths < n_pages * page), flat_idx, p * page
     )
 
-    def write(pool, new, width):
-        flat = jnp.pad(pool.reshape(p * page, width), ((0, 1), (0, 0)))
-        flat = indirect_scatter(flat, new.reshape(-1, width), flat_idx, impl=impl)
-        return flat[:-1]
+    def write(pool, new):
+        row = pool.shape[2:]
+        flat = pool.reshape((p * page,) + row)
+        out = indirect_scatter(flat, new.reshape((-1,) + row), flat_idx,
+                               impl=impl)
+        return out.reshape(pool.shape)
 
-    k_pages = write(k_pages, k_new, kvh * d).reshape(p, page, kvh, d)
-    v_pages = write(v_pages, v_new, kvh * d).reshape(p, page, kvh, d)
+    k_pages = write(k_pages, k_new)
+    v_pages = write(v_pages, v_new)
     new_len = lengths + active.astype(lengths.dtype)
     if quantized:
-        k_scale = write(k_scale, k_s, kvh).reshape(p, page, kvh)
-        v_scale = write(v_scale, v_s, kvh).reshape(p, page, kvh)
-        return k_pages, v_pages, new_len, k_scale, v_scale
+        return (k_pages, v_pages, new_len,
+                _scatter_scales(k_scale, flat_idx, k_s),
+                _scatter_scales(v_scale, flat_idx, v_s))
     return k_pages, v_pages, new_len
+
+
+def _scatter_scales(pool: jax.Array, flat_idx: jax.Array,
+                    new: jax.Array) -> jax.Array:
+    """Write per-token scale rows into a ``(P, page, KVH)`` scale pool.
+
+    A token's scales are ``KVH`` fp32 (16 B at KVH=4), far narrower than a
+    row Mosaic can DMA (128 lanes): through the converters every write would
+    move a padded copy of the whole pool.  XLA's scatter updates the
+    (donated) pool in place at the same flat indices instead, dropping
+    out-of-range ones like the converters do.  It indexes the 3-D pool:
+    flattening ``(P, page)`` would change the tiled ``(page, KVH)`` minor
+    dims and so relayout-copy the pool.
+    """
+    page = pool.shape[1]
+    return pool.at[flat_idx // page, flat_idx % page].set(
+        new.reshape((-1,) + pool.shape[2:]), mode="drop")
 
 
 def paged_kv_write_chunk(
@@ -513,8 +495,8 @@ def paged_kv_write_chunk(
     turns this into *quantize-on-write* (same contract as
     :func:`paged_kv_append`): the chunk is quantized per (token, kv-head)
     over ``D``, the int8 codes go through the window gather/scatter above
-    and the scales through an identical (narrower) window walk over the
-    scale pools — same page ids, same local indices, same out-of-bounds
+    and the scales straight into the scale pools (:func:`_scatter_scales`)
+    at the pool slots of the same window pages, with the same out-of-bounds
     routing.  Returns ``(k_pages, v_pages)``, plus ``(k_scale, v_scale)``
     appended when quantizing.
     """
@@ -546,28 +528,33 @@ def paged_kv_write_chunk(
     loc = (jnp.arange(r, dtype=jnp.int32)[:, None] * w + wp) * page + pos % page
     loc = jnp.where(valid, loc, r * w * page).reshape(-1)
 
-    def write(pool, new, width):
-        flat = pool.reshape(p, page * width)
+    def write(pool, new):
+        row = pool.shape[2:]
         win = indirect_gather(
-            flat, jnp.clip(pids, 0, p - 1).reshape(-1), impl=impl
-        )                                                         # (R*W, ...)
-        win = jnp.pad(
-            win.reshape(r * w * page, width), ((0, 1), (0, 0))
+            pool, jnp.clip(pids, 0, p - 1).reshape(-1), impl=impl
+        )                                                     # (R*W, page, *row)
+        win = indirect_scatter(
+            win.reshape((r * w * page,) + row), new.reshape((-1,) + row), loc,
+            impl=impl,
         )
-        win = indirect_scatter(win, new.reshape(-1, width), loc, impl=impl)
-        win = win[:-1].reshape(r * w, page * width)
-        out = jnp.pad(flat, ((0, 1), (0, 0)))
-        out = indirect_scatter(
-            out, win, jnp.where(real, pids, p).reshape(-1), impl=impl
+        # Window slots that cover no valid token target page ``p`` (one past
+        # the pool), which the scatter converter drops.
+        return indirect_scatter(
+            pool, win.reshape((r * w, page) + row),
+            jnp.where(real, pids, p).reshape(-1), impl=impl,
         )
-        return out[:-1]
 
-    kp = write(k_pages, k_new, kvh * d).reshape(p, page, kvh, d)
-    vp = write(v_pages, v_new, kvh * d).reshape(p, page, kvh, d)
+    kp = write(k_pages, k_new)
+    vp = write(v_pages, v_new)
     if quantized:
-        ks = write(k_scale, k_s, kvh).reshape(p, page, kvh)
-        vs = write(v_scale, v_s, kvh).reshape(p, page, kvh)
-        return kp, vp, ks, vs
+        # Token (r, c)'s pool slot: its window page, kept only where that
+        # page is written back above.
+        tok_pid = jnp.take_along_axis(pids, wp, axis=1)           # (R, C)
+        tok_real = jnp.take_along_axis(real, wp, axis=1)
+        flat = jnp.where(valid & tok_real, tok_pid * page + pos % page,
+                         p * page).reshape(-1)
+        return (kp, vp, _scatter_scales(k_scale, flat, k_s),
+                _scatter_scales(v_scale, flat, v_s))
     return kp, vp
 
 

@@ -242,7 +242,7 @@ def paged_prefill_attention_kernel(
         body,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r, c, h, d), q.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

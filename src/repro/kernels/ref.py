@@ -344,8 +344,10 @@ def paged_kv_append(
         active = jnp.ones_like(lengths, dtype=bool)
     # Route inactive writes past the pool; scatter mode='drop' discards them.
     pids = jnp.where(active, pids, p)
-    k_pages = k_pages.at[pids, off].set(k_new, mode="drop")
-    v_pages = v_pages.at[pids, off].set(v_new, mode="drop")
+    k_pages = k_pages.at[pids, off].set(k_new.astype(k_pages.dtype),
+                                        mode="drop")
+    v_pages = v_pages.at[pids, off].set(v_new.astype(v_pages.dtype),
+                                        mode="drop")
     new_len = lengths + active.astype(lengths.dtype)
     if quantized:
         k_scale = k_scale.at[pids, off].set(k_s, mode="drop")
@@ -401,8 +403,10 @@ def paged_kv_write_chunk(
     flat = flat.reshape(-1)
     kf = k_pages.reshape(p * page, kvh, d)
     vf = v_pages.reshape(p * page, kvh, d)
-    kf = kf.at[flat].set(k_new.reshape(-1, kvh, d), mode="drop")
-    vf = vf.at[flat].set(v_new.reshape(-1, kvh, d), mode="drop")
+    kf = kf.at[flat].set(k_new.reshape(-1, kvh, d).astype(kf.dtype),
+                         mode="drop")
+    vf = vf.at[flat].set(v_new.reshape(-1, kvh, d).astype(vf.dtype),
+                         mode="drop")
     k_pages = kf.reshape(p, page, kvh, d)
     v_pages = vf.reshape(p, page, kvh, d)
     if quantized:

@@ -333,6 +333,10 @@ class PagedLM:
     serving analogue of packing narrower elements onto a fixed-width bus
     (packing factor ``bus/elem``: 8-bit elements quadruple the FP32 factor).
     The matching cache must be created with the same ``kv_dtype``.
+
+    ``params`` serves given weights (e.g. another engine's, so a ``ref``
+    and a ``pallas`` engine can share one copy) instead of drawing random
+    ones from ``key``.
     """
 
     #: Max resident jitted prefill *and* verify programs (one shared LRU).
@@ -345,7 +349,8 @@ class PagedLM:
     def __init__(self, cfg: ArchConfig, key: jax.Array, impl: str = "pallas",
                  prefill_cache_cap: Optional[int] = None,
                  kv_dtype: Optional[str] = None, spec_k: int = 1,
-                 drafter: Optional[Drafter] = None):
+                 drafter: Optional[Drafter] = None,
+                 params: Optional[Dict[str, jax.Array]] = None):
         if spec_k < 1:
             raise ValueError(f"spec_k must be >= 1, got {spec_k}")
         self.cfg = cfg
@@ -370,16 +375,19 @@ class PagedLM:
         # re-jits — correctness never depends on residency).
         self._prefill_cache: "collections.OrderedDict[Tuple[int, int], Any]" \
             = collections.OrderedDict()
-        ks = jax.random.split(key, 5)
-        init = lambda k, *s: (jax.random.normal(k, s, jnp.float32)
-                              / np.sqrt(s[-2]))
-        self.params = {
-            "embed": jax.random.normal(ks[0], (cfg.vocab, d), jnp.float32) * 0.02,
-            "wq": init(ks[1], L, d, h * cfg.hd),
-            "wk": init(ks[2], L, d, kvh * cfg.hd),
-            "wv": init(ks[3], L, d, kvh * cfg.hd),
-            "wo": init(ks[4], L, h * cfg.hd, d),
-        }
+        if params is None:
+            ks = jax.random.split(key, 5)
+            init = lambda k, *s: (jax.random.normal(k, s, jnp.float32)
+                                  / np.sqrt(s[-2]))
+            params = {
+                "embed": jax.random.normal(ks[0], (cfg.vocab, d),
+                                           jnp.float32) * 0.02,
+                "wq": init(ks[1], L, d, h * cfg.hd),
+                "wk": init(ks[2], L, d, kvh * cfg.hd),
+                "wv": init(ks[3], L, d, kvh * cfg.hd),
+                "wo": init(ks[4], L, h * cfg.hd, d),
+            }
+        self.params = params
 
     def bind(self, cache: PagedKVCache) -> "PagedFamily":
         """Wrap this model + ``cache`` as the scheduler-facing family."""

@@ -2,6 +2,10 @@ import importlib.util
 import os
 import sys
 
+# The suite runs on the CPU (Pallas kernels in interpret mode) and never
+# takes a chip: a TPU belongs to one process at a time.
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), '..'))
 
 # The property tests import `hypothesis`; it is an optional dev dependency.

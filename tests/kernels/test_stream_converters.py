@@ -114,3 +114,70 @@ def test_strided_equals_indirect_with_arange(stride, count, seed):
     a = ops.strided_gather(src, 0, stride, count)
     b = ops.indirect_gather(src, jnp.arange(count, dtype=jnp.int32) * stride)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# Row shapes the serving path streams, in the dtypes it streams them in: K/V
+# token rows (KVH, hd) and pages (page, KVH, hd), int8 scale rows (KVH,),
+# flat rows, and odd rows that need a padded DMA view.
+ROWS = [(4, 128), (8, 4, 128), (512,), (4,), (2, 32), (3, 5)]
+ROW_DTYPES = [jnp.float32, jnp.bfloat16, jnp.int8]
+
+
+def _rows(rng, n, row, dtype):
+    x = rng.normal(size=(n,) + row) * 50
+    if dtype == jnp.int8:
+        return jnp.asarray(np.clip(x, -127, 127).astype(np.int8))
+    return jnp.asarray(x, dtype=dtype)
+
+
+@pytest.mark.parametrize("row", ROWS)
+@pytest.mark.parametrize("dtype", ROW_DTYPES)
+@pytest.mark.parametrize("op", ["strided_gather", "strided_scatter",
+                                "indirect_gather", "indirect_scatter"])
+def test_converters_match_ref_on_narrow_and_multidim_rows(op, dtype, row):
+    """Every converter equals its oracle bit for bit on bf16 and int8 rows
+    and on rows of any rank; scatters keep untouched rows, drop targets
+    out of range, and resolve duplicate targets last-writer-wins."""
+    rng = np.random.default_rng(4)
+    n, count = 37, 11
+    src = _rows(rng, n, row, dtype)
+    packed = _rows(rng, count, row, dtype)
+    if op == "strided_gather":
+        out = ops.strided_gather(src, 2, 3, count)
+        expect = ref.strided_gather(src, 2, 3, count)
+    elif op == "strided_scatter":
+        # stride 4 from base 1 runs rows 1..41: the last rows fall off.
+        out = ops.strided_scatter(src, packed, 1, 4)
+        expect = ref.strided_scatter(src, packed, 1, 4)
+    elif op == "indirect_gather":
+        idx = jnp.asarray(rng.integers(0, n, count), jnp.int32)
+        out = ops.indirect_gather(src, idx)
+        expect = ref.indirect_gather(src, idx)
+    else:
+        idx = jnp.asarray([5, 0, 36, 5, n, 9, 9, 9, 1, n + 3, 5], jnp.int32)
+        out = ops.indirect_scatter(src, packed, idx)
+        expect = ref.indirect_scatter(src, packed, idx)
+        got = np.asarray(out)
+        np.testing.assert_array_equal(got[5], np.asarray(packed)[10])
+        np.testing.assert_array_equal(got[9], np.asarray(packed)[7])
+        np.testing.assert_array_equal(got[2], np.asarray(src)[2])
+    assert out.shape == expect.shape and out.dtype == expect.dtype
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(expect))
+
+
+@pytest.mark.parametrize("op", ["strided_scatter", "indirect_scatter"])
+def test_scatter_casts_rows_to_destination_dtype(op):
+    """fp32 rows written into a bf16 pool land rounded, as XLA's scatter
+    rounds them (the served model computes K/V in fp32 over bf16 pools)."""
+    rng = np.random.default_rng(5)
+    dst = _rows(rng, 16, (4, 128), jnp.bfloat16)
+    packed = _rows(rng, 3, (4, 128), jnp.float32)
+    if op == "strided_scatter":
+        out = ops.strided_scatter(dst, packed, 1, 5)
+        expect = ref.strided_scatter(dst, packed.astype(dst.dtype), 1, 5)
+    else:
+        idx = jnp.asarray([3, 11, 7], jnp.int32)
+        out = ops.indirect_scatter(dst, packed, idx)
+        expect = ref.indirect_scatter(dst, packed.astype(dst.dtype), idx)
+    assert out.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(expect))

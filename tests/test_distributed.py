@@ -12,21 +12,12 @@ import sys
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from repro.configs import smoke_config
 from repro.models import lm
 from repro.parallel.sharding import make_rules
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-# Several subprocess bodies drive the explicit-mesh sharding APIs
-# (jax.sharding.AxisType / jax.set_mesh) introduced in jax 0.5+; on older
-# pinned jaxlib hosts they cannot run at all.
-requires_axis_type = pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="needs jax>=0.5 explicit-mesh APIs (jax.sharding.AxisType)",
-)
 
 
 def _run(body: str) -> str:
@@ -44,7 +35,6 @@ def _run(body: str) -> str:
     return out.stdout
 
 
-@requires_axis_type
 def test_ep_moe_matches_fallback():
     out = _run("""
 import jax, jax.numpy as jnp, numpy as np, dataclasses
@@ -83,7 +73,6 @@ print("EP_OK", err, gerr)
     assert "EP_OK" in out
 
 
-@requires_axis_type
 def test_sharded_embedding_gather_matches_take():
     out = _run("""
 import jax, jax.numpy as jnp, numpy as np
@@ -112,7 +101,6 @@ print("EMB_OK")
     assert "EMB_OK" in out
 
 
-@requires_axis_type
 def test_int8_psum_error_feedback():
     out = _run("""
 import jax, jax.numpy as jnp, numpy as np
@@ -158,7 +146,6 @@ def test_w8a16_quantized_forward_close():
     assert float(jnp.abs(l1 - l2).max()) < 0.1
 
 
-@requires_axis_type
 def test_compressed_train_step_runs():
     out = _run("""
 import jax, jax.numpy as jnp, numpy as np, dataclasses

@@ -1,0 +1,222 @@
+"""Compile-only checks for one TPU v5e chip, with no chip attached.
+
+The TPU compiler is installed even where no TPU is: these tests describe a
+``v5e:2x2`` topology, lower the serving kernels with ``interpret=False`` at
+the widths ``chip_smoke.py`` serves (yi-6b: 32 q / 4 kv heads of 128; the
+rwkv6-3b state rows), and compile them for its first chip.  They catch what
+interpret mode cannot — a block or DMA slice the tiling refuses, too much
+SMEM or VMEM — at no chip time.  Nothing runs, so nothing here says
+anything about results or speed.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and every test worker imports
+every test file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+import chip_smoke
+from repro.configs import get_config
+from repro.kernels import ops
+from repro.kernels import stream_converters as sc
+from repro.kernels.paged_decode import paged_decode_attention_kernel
+from repro.kernels.paged_prefill import paged_prefill_attention_kernel
+from repro.kernels.paged_verify import paged_verify_attention_kernel
+from repro.serve import PagedKVCache, PagedLM, RecurrentLM
+
+H, KVH, HD = 32, 4, 128
+PAGE, BATCH, MAX_LEN = chip_smoke.PAGE, chip_smoke.BATCH, chip_smoke.MAX_LEN
+N_PAGES = MAX_LEN // PAGE
+POOL_PAGES = BATCH * N_PAGES
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """The described chip; the module leaves the environment and the JAX
+    config as it found them, for the tests that share its worker."""
+    from jax.experimental import topologies
+    with pytest.MonkeyPatch.context() as mp:
+        # The TPU compiler logs under /tmp unless told otherwise.
+        if "TPU_LOG_DIR" not in os.environ:
+            mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            t = topologies.get_topology_desc(platform="tpu",
+                                             topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 — any failure means "cannot"
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        # A compile for a described chip is written to the persistent cache
+        # but cannot be read back without one; keep the cache out of it.
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        try:
+            yield t
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def spec(topo):
+    """Shape → ShapeDtypeStruct placed on the described chip."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def make(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    return make
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """Make the ops layer take its chip branch: it asks the default backend,
+    which is the CPU here."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+
+
+def _on_chip(spec, tree):
+    return jax.tree.map(lambda s: spec(s.shape, s.dtype), tree)
+
+
+def _compile(fn, *args, donate_argnums=(), **kwargs):
+    compiled = jax.jit(fn, donate_argnums=donate_argnums).lower(
+        *args, **kwargs).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "kernel lowered as interpreted HLO"
+    return compiled
+
+
+# ---------------------------------------------------------------------------
+# The four stream converters
+# ---------------------------------------------------------------------------
+
+CONVERTER_ROWS = [(jnp.bfloat16, 512), (jnp.int8, 512), (jnp.float32, 4)]
+
+
+@pytest.mark.parametrize("dtype,width", CONVERTER_ROWS,
+                         ids=["bf16x512", "int8x512", "f32x4"])
+@pytest.mark.parametrize("op", ["strided_gather", "strided_scatter",
+                                "indirect_gather", "indirect_scatter"])
+def test_converter_compiles(spec, op, dtype, width):
+    n, count = POOL_PAGES * PAGE, 20
+    table = spec((n, width), dtype)
+    rows = spec((count, width), dtype)
+    idx = spec((count,), jnp.int32)
+    if op == "strided_gather":
+        _compile(lambda s: sc.strided_gather_kernel(s, 3, BATCH, count), table)
+    elif op == "strided_scatter":
+        _compile(lambda d, p: sc.strided_scatter_kernel(d, p, 3, BATCH),
+                 table, rows)
+    elif op == "indirect_gather":
+        _compile(sc.indirect_gather_kernel, table, idx)
+    else:
+        _compile(sc.indirect_scatter_kernel, table, rows, idx)
+
+
+# ---------------------------------------------------------------------------
+# The paged attention kernels
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("kernel", ["decode", "prefill", "verify"])
+def test_paged_attention_compiles(spec, kernel, kv):
+    kv_dtype = jnp.int8 if kv == "int8" else jnp.bfloat16
+    pages = spec((POOL_PAGES, PAGE, KVH, HD), kv_dtype)
+    scales = ([spec((POOL_PAGES, PAGE, KVH), jnp.float32)] * 2
+              if kv == "int8" else [])
+    table = spec((BATCH, N_PAGES), jnp.int32)
+    lens = spec((BATCH,), jnp.int32)
+    if kernel == "decode":
+        q = spec((BATCH, H, HD), jnp.float32)
+        _compile(paged_decode_attention_kernel, q, pages, pages, table, lens,
+                 *scales)
+        return
+    c = chip_smoke.CHUNK if kernel == "prefill" else chip_smoke.SPEC_K
+    q = spec((BATCH, c, H, HD), jnp.float32)
+    kern = (paged_prefill_attention_kernel if kernel == "prefill"
+            else paged_verify_attention_kernel)
+    # starts/lengths and counts share one shape; pass the same spec twice.
+    _compile(kern, q, pages, pages, table, lens, lens, *scales)
+
+
+@pytest.mark.parametrize("op,launches", [("append", 2), ("chunk", 6)])
+def test_int8_kv_writes_compile(spec, on_tpu, op, launches):
+    """Quantize-on-write: the int8 codes go through the converters (one
+    launch per pool for an append; gather, scatter, write-back for a chunk)
+    and the fp32 scales through XLA's in-place scatter — no converter
+    launch, and so no lane-padded copy of the scale pools."""
+    pages = spec((POOL_PAGES, PAGE, KVH, HD), jnp.int8)
+    scales = spec((POOL_PAGES, PAGE, KVH), jnp.float32)
+    table = spec((BATCH, N_PAGES), jnp.int32)
+    lens = spec((BATCH,), jnp.int32)
+    if op == "append":
+        new = spec((BATCH, KVH, HD), jnp.float32)
+
+        def write(kp, vp, ks, vs, kn, vn, table, lens):
+            return ops.paged_kv_append(kp, vp, kn, vn, table, lens,
+                                       k_scale=ks, v_scale=vs)
+    else:
+        new = spec((BATCH, chip_smoke.CHUNK, KVH, HD), jnp.float32)
+
+        def write(kp, vp, ks, vs, kn, vn, table, lens):
+            return ops.paged_kv_write_chunk(kp, vp, kn, vn, table, lens, lens,
+                                            k_scale=ks, v_scale=vs)
+    compiled = _compile(write, pages, pages, scales, scales, new, new, table,
+                        lens, donate_argnums=(0, 1, 2, 3))
+    assert compiled.as_text().count("tpu_custom_call") == launches
+
+
+# ---------------------------------------------------------------------------
+# Recurrent state rows (rwkv6-3b widths)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("op", ["read", "write"])
+def test_recurrent_state_ops_compile(spec, on_tpu, op):
+    cfg = get_config("rwkv6-3b")
+    pool = jax.eval_shape(
+        lambda k: RecurrentLM(cfg, k).init_pool(BATCH).tensors,
+        jax.random.PRNGKey(0),
+    )
+    assert {t.shape[2:] for t in pool.values()} == {(40, 64, 64), (2560,)}
+    for t in pool.values():
+        if op == "read":
+            _compile(lambda p: ops.recurrent_state_read(p, 3),
+                     spec(t.shape, t.dtype))
+        else:
+            value = spec((t.shape[0],) + t.shape[2:], t.dtype)
+            _compile(lambda p, v: ops.recurrent_state_write(p, 3, v),
+                     spec(t.shape, t.dtype), value)
+
+
+# ---------------------------------------------------------------------------
+# One whole served program: PagedLM's fused decode at yi-6b widths
+# ---------------------------------------------------------------------------
+
+
+def test_paged_lm_fused_decode_program_compiles(spec, on_tpu):
+    cfg = get_config("yi-6b")
+    key = jax.random.PRNGKey(0)
+    params = jax.eval_shape(lambda k: PagedLM(cfg, k).params, key)
+    model = PagedLM(cfg, key, params=_on_chip(spec, params))
+    assert (model.h, model.kvh, model.hd) == (H, KVH, HD)
+
+    def pools():
+        c = PagedKVCache.create(cfg, batch=BATCH, max_len=MAX_LEN, page=PAGE)
+        return c.k_pages, c.v_pages, c.page_table, c.lengths
+
+    kp, vp, table, lens = _on_chip(spec, jax.eval_shape(pools))
+    assert kp.dtype == jnp.bfloat16 and kp.shape[0] == cfg.n_layers
+    tokens = spec((BATCH,), jnp.int32)
+    active = spec((BATCH,), jnp.bool_)
+    compiled = model._decode_many.lower(
+        model.params, tokens, kp, vp, None, None, table, lens, active, n=4,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert used < 16e9, f"decode program needs {used / 1e9:.1f} GB"
