@@ -153,6 +153,7 @@ def flash_attention_kernel(
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
+        name="flash_attention",
         interpret=interpret,
     )(q, k, v)
 
@@ -193,6 +194,7 @@ def flash_attention_fwd_kernel(
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
+        name="flash_attention_fwd",
         interpret=interpret,
     )(q, k, v)
 
@@ -359,6 +361,7 @@ def flash_attention_bwd_kernel(
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
         ],
+        name="flash_attention_bwd_dkv",
         interpret=interpret,
     )(q, k, v, do, lse, delta)
 
@@ -380,6 +383,7 @@ def flash_attention_bwd_kernel(
         out_specs=pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        name="flash_attention_bwd_dq",
         interpret=interpret,
     )(q, k, v, do, lse, delta)
     return dq, dk, dv

@@ -201,6 +201,7 @@ def paged_decode_attention_kernel(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
+        name="paged_decode",
         interpret=interpret,
     )(flat_table, lengths, used, *args)
 

@@ -245,6 +245,7 @@ def paged_prefill_attention_kernel(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
+        name="paged_prefill",
         interpret=interpret,
     )(flat_table, starts, counts, used, *args)
 

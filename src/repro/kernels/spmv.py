@@ -58,6 +58,7 @@ def spmv_ell_kernel(
         ],
         out_specs=pl.BlockSpec((row_block, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, 1), vals.dtype),
+        name="spmv_ell",
         interpret=interpret,
     )(vals, cols, x2)
     return y.reshape(r)
@@ -115,6 +116,7 @@ def spmv_ell_panel_kernel(
         functools.partial(_spmv_panel_body, panel=panel),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r, 1), vals.dtype),
+        name="spmv_ell_panel",
         interpret=interpret,
     )(panel_ids, vals, cols, x2)
     return y.reshape(r)

@@ -163,6 +163,7 @@ def strided_gather_kernel(
         out_specs=_HBM,
         scratch_shapes=[pltpu.SemaphoreType.DMA(())],
         out_shape=jax.ShapeDtypeStruct((count,) + view.shape[1:], view.dtype),
+        name="strided_gather",
         interpret=interpret,
     )(view)
     return undo(out)
@@ -212,6 +213,7 @@ def strided_scatter_kernel(
         scratch_shapes=[pltpu.SemaphoreType.DMA(())],
         out_shape=jax.ShapeDtypeStruct(dst_v.shape, dst_v.dtype),
         input_output_aliases={1: 0},
+        name="strided_scatter",
         interpret=interpret,
     )(packed_v, dst_v)
     return undo(out)
@@ -258,6 +260,7 @@ def indirect_gather_kernel(
         functools.partial(_indirect_gather_body, n=src.shape[0], count=count),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((count,) + view.shape[1:], view.dtype),
+        name="indirect_gather",
         interpret=interpret,
     )(indices.astype(jnp.int32), view)
     return undo(out)
@@ -322,6 +325,7 @@ def indirect_scatter_kernel(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(dst_v.shape, dst_v.dtype),
         input_output_aliases={2: 0},
+        name="indirect_scatter",
         interpret=interpret,
     )(_last_writer(indices.astype(jnp.int32), n), packed_v, dst_v)
     return undo(out)

@@ -31,5 +31,6 @@ def transpose_kernel(
         in_specs=[pl.BlockSpec((block, block), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((block, block), lambda i, j: (j, i)),
         out_shape=jax.ShapeDtypeStruct((c, r), x.dtype),
+        name="transpose",
         interpret=interpret,
     )(x)

@@ -43,6 +43,7 @@ from repro.core.streams import (
     verify_table_streams,
 )
 from repro.kernels import ops as kops
+from repro.runtime.tracing import span
 from .drafter import Drafter, NGramDrafter
 from .family import ServableFamily
 from .kv import PagedKVCache, _donation_noop_ok
@@ -312,6 +313,16 @@ def _paged_lm_verify_steps(params, feed, dstate, k_pages, v_pages, k_scale,
             lengths)
 
 
+def _jit(fn, static_argnames=(), donate_argnums=(), **bound):
+    """``jax.jit`` of ``fn`` with ``bound`` keywords fixed, named after
+    ``fn`` (``jit_paged_lm_decode_steps`` in a profile; a bare
+    ``functools.partial`` would read ``jit__unknown``)."""
+    f = functools.partial(fn, **bound)
+    f.__name__ = fn.__name__.lstrip("_")
+    return jax.jit(f, static_argnames=static_argnames,
+                   donate_argnums=donate_argnums)
+
+
 class PagedLM:
     """Attention-only LM serving straight out of a :class:`PagedKVCache`.
 
@@ -395,30 +406,26 @@ class PagedLM:
 
     @functools.cached_property
     def _decode(self):
-        return jax.jit(functools.partial(
-            _paged_lm_decode_step, h=self.h, kvh=self.kvh, hd=self.hd,
-            impl=self.impl,
-        ), donate_argnums=(2, 3, 4, 5))
+        return _jit(_paged_lm_decode_step, donate_argnums=(2, 3, 4, 5),
+                    h=self.h, kvh=self.kvh, hd=self.hd, impl=self.impl)
 
     @functools.cached_property
     def _decode_many(self):
-        return jax.jit(functools.partial(
-            _paged_lm_decode_steps, vocab=self.cfg.vocab, h=self.h,
-            kvh=self.kvh, hd=self.hd, impl=self.impl,
-        ), static_argnames=("n",), donate_argnums=(2, 3, 4, 5))
+        return _jit(_paged_lm_decode_steps, static_argnames=("n",),
+                    donate_argnums=(2, 3, 4, 5), vocab=self.cfg.vocab,
+                    h=self.h, kvh=self.kvh, hd=self.hd, impl=self.impl)
 
     def _prefill(self, page: int, ctx_pages: int):
-        return jax.jit(functools.partial(
-            _paged_lm_prefill_batch, h=self.h, kvh=self.kvh, hd=self.hd,
-            page=page, ctx_pages=ctx_pages, impl=self.impl,
-        ), donate_argnums=(5, 6, 7, 8))
+        return _jit(_paged_lm_prefill_batch, donate_argnums=(5, 6, 7, 8),
+                    h=self.h, kvh=self.kvh, hd=self.hd, page=page,
+                    ctx_pages=ctx_pages, impl=self.impl)
 
     def _verify(self, spec_k: int, ctx_pages: int):
-        return jax.jit(functools.partial(
-            _paged_lm_verify_steps, drafter=self.drafter, spec_k=spec_k,
-            vocab=self.cfg.vocab, h=self.h, kvh=self.kvh, hd=self.hd,
-            ctx_pages=ctx_pages, impl=self.impl,
-        ), static_argnames=("n",), donate_argnums=(3, 4, 5, 6))
+        return _jit(_paged_lm_verify_steps, static_argnames=("n",),
+                    donate_argnums=(3, 4, 5, 6), drafter=self.drafter,
+                    spec_k=spec_k, vocab=self.cfg.vocab, h=self.h,
+                    kvh=self.kvh, hd=self.hd, ctx_pages=ctx_pages,
+                    impl=self.impl)
 
     def _cached_program(self, key, make):
         """Shared LRU over jitted prefill *and* verify programs: refreshed
@@ -530,13 +537,16 @@ class PagedLM:
         with _donation_noop_ok():
             while rem:
                 m = 1 << (rem.bit_length() - 1)
-                toks, feed, kp, vp, ks, vs, lens = self._decode_many(
-                    self.params, feed, kp, vp, ks, vs, cache.page_table,
-                    lens, act_dev, n=m,
-                )
+                with span("engine.launch", kind="decode", n=m,
+                          ctx_pages=cache.pages_per_seq):
+                    toks, feed, kp, vp, ks, vs, lens = self._decode_many(
+                        self.params, feed, kp, vp, ks, vs, cache.page_table,
+                        lens, act_dev, n=m,
+                    )
                 parts.append(toks)
                 rem -= m
-        out = np.concatenate([np.asarray(t) for t in parts], axis=0)  # sync
+        with span("engine.sync", kind="decode"):
+            out = np.concatenate([np.asarray(t) for t in parts], axis=0)
         cache = dataclasses.replace(
             cache, k_pages=kp, v_pages=vp, k_scale=ks, v_scale=vs,
             lengths=lens,
@@ -597,17 +607,19 @@ class PagedLM:
         with _donation_noop_ok():
             while rem:
                 m = 1 << (rem.bit_length() - 1)
-                toks, counts, feed, dstate, kp, vp, ks, vs, lens = fn(
-                    self.params, feed, dstate, kp, vp, ks, vs,
-                    cache.page_table, lens, act_dev, caps_dev, n=m,
-                )
+                with span("engine.launch", kind="verify", n=m, ctx_pages=ctx):
+                    toks, counts, feed, dstate, kp, vp, ks, vs, lens = fn(
+                        self.params, feed, dstate, kp, vp, ks, vs,
+                        cache.page_table, lens, act_dev, caps_dev, n=m,
+                    )
                 tok_parts.append(toks)
                 cnt_parts.append(counts)
                 rem -= m
-        toks_h = np.concatenate(
-            [np.asarray(t) for t in tok_parts], axis=0
-        )                                                   # sync
-        counts_h = np.concatenate([np.asarray(c) for c in cnt_parts], axis=0)
+        with span("engine.sync", kind="verify"):
+            toks_h = np.concatenate([np.asarray(t) for t in tok_parts],
+                                    axis=0)
+            counts_h = np.concatenate([np.asarray(c) for c in cnt_parts],
+                                      axis=0)
         cache = dataclasses.replace(
             cache, k_pages=kp, v_pages=vp, k_scale=ks, v_scale=vs,
             lengths=lens,
@@ -641,14 +653,15 @@ class PagedLM:
         fn = self._cached_program(
             (page, ctx), lambda: self._prefill(page, ctx)
         )
-        with _donation_noop_ok():
+        real = counts > 0
+        with span("engine.launch", kind="prefill", rows=int(real.sum()),
+                  ctx_pages=ctx), _donation_noop_ok():
             logits, kp, vp, ks, vs, new_len = fn(
                 self.params, jnp.asarray(tokens), jnp.asarray(counts),
                 jnp.asarray(slots), jnp.asarray(starts),
                 cache.k_pages, cache.v_pages, cache.k_scale, cache.v_scale,
                 cache.page_table, cache.lengths,
             )
-        real = counts > 0
         lens_host = cache.lengths_host
         if lens_host is not None:
             lens_host = lens_host.copy()

@@ -57,6 +57,7 @@ from repro.models import mamba as mamba_mod
 from repro.models import rwkv6 as rwkv_mod
 from repro.models.common import Param, init_params, rms_norm, stack_layer_defs
 from repro.parallel.sharding import make_rules
+from repro.runtime.tracing import span
 from .family import OutOfPages, ServableFamily
 from .kv import _donation_noop_ok
 
@@ -217,7 +218,8 @@ class RecurrentLM:
         cfg, vocab = self.cfg, self.cfg.vocab
         n_layers = cfg.n_layers
 
-        def run(layers, embed, pool, cur, toks, use_input, active):
+        def recurrent_lm_steps(layers, embed, pool, cur, toks, use_input,
+                               active):
             # pool: name → (L, B, *row); cur (B,) i32 carried token;
             # toks (n, B) i32; use_input (n,) bool; active (n, B) bool.
             def body(carry, xs):
@@ -252,7 +254,7 @@ class RecurrentLM:
             )
             return pool_f, cur_f, logits, toks_out
 
-        return jax.jit(run, donate_argnums=(2,))
+        return jax.jit(recurrent_lm_steps, donate_argnums=(2,))
 
     def prefill_chunk(self, tensors, toks, active):
         """Feed given tokens: toks (C, B) i32, active (C, B) bool.
@@ -262,7 +264,9 @@ class RecurrentLM:
         c, b = toks.shape
         cur = np.zeros((b,), np.int32)
         use = np.ones((c,), bool)
-        with _donation_noop_ok():
+        rows = int(np.asarray(active).any(axis=0).sum())
+        with span("engine.launch", kind="prefill", rows=rows), \
+                _donation_noop_ok():
             tensors, _, logits, _ = self._steps(
                 self.layers, self.embed, tensors, cur, toks, use, active
             )
@@ -285,11 +289,13 @@ class RecurrentLM:
             toks = np.zeros((m, b), np.int32)
             use = np.zeros((m,), bool)
             act = np.broadcast_to(np.asarray(active, bool), (m, b))
-            with _donation_noop_ok():
+            with span("engine.launch", kind="decode", n=m), \
+                    _donation_noop_ok():
                 tensors, cur, _, toks_out = self._steps(
                     self.layers, self.embed, tensors, cur, toks, use, act
                 )
-            outs.append(np.asarray(toks_out))
+            with span("engine.sync", kind="decode"):
+                outs.append(np.asarray(toks_out))
             rem -= m
         return tensors, np.concatenate(outs, axis=0)
 
@@ -406,7 +412,8 @@ class RecurrentFamily(ServableFamily):
         self.pool.tensors, logits = self.model.prefill_chunk(
             self.pool.tensors, toks, act
         )
-        lg = np.asarray(logits)  # (C, B, vocab)
+        with span("engine.sync", kind="prefill"):
+            lg = np.asarray(logits)  # (C, B, vocab)
         out = lg[np.maximum(np.asarray(counts, np.int64) - 1, 0),
                  np.asarray(slots, np.int64)]
         # Scalar loop: padding rows alias slot 0 with count 0, and fancy

@@ -122,6 +122,7 @@ from typing import (
 import numpy as np
 
 from repro.core.packing import Traffic
+from repro.runtime.tracing import install_gc_spans, span
 from .family import ServableFamily
 from .faults import FaultPlan
 
@@ -412,7 +413,6 @@ class StepRecord:
 class ServeStats:
     records: List[StepRecord] = dataclasses.field(default_factory=list)
     n_evictions: int = 0            # evict-and-requeue events (replayable)
-    wall_s: float = 0.0
     prefill_tokens_saved: int = 0   # prompt tokens mapped instead of prefilled
     cow_copies: int = 0             # copy-on-write page copies performed
     n_preempted: int = 0            # terminal preemptions (budget exhausted)
@@ -482,10 +482,6 @@ class ServeStats:
     @property
     def pack_efficiency(self) -> float:
         return self.useful_bytes / self.pack_bytes if self.pack_bytes else 1.0
-
-    @property
-    def tokens_per_s(self) -> float:
-        return self.tokens / self.wall_s if self.wall_s else 0.0
 
     # -- prefill-side aggregates (same Traffic records, kind='prefill') ------
 
@@ -607,6 +603,7 @@ class Scheduler:
         self._admit_counter = 0
         self._submit_counter = 0
         self._free_slots = list(range(family.batch))[::-1]
+        install_gc_spans()
 
     @property
     def cache(self):
@@ -734,10 +731,8 @@ class Scheduler:
         :class:`SchedulerStalledError` — with a full per-request dump —
         if work is still pending after ``max_steps``.
         """
-        t0 = time.perf_counter()
         while (self.queue or self.resident) and self._step < max_steps:
             self.step()
-        self.stats.wall_s += time.perf_counter() - t0
         if self.queue or self.resident:
             raise SchedulerStalledError(self._stall_report(max_steps))
         return {rid: r.generated for rid, r in sorted(self.finished.items())}
@@ -750,24 +745,31 @@ class Scheduler:
         watchdog."""
         self._step += 1
         t0 = time.perf_counter()
-        if self.faults is not None and self.faults.drop_prefix(self._step):
-            if self.prefix_index is None:
-                # The fault action doesn't apply to this family/config (no
-                # prefix index to drop): counted no-op, never a raise.
-                self.stats.n_prefix_drop_skips += 1
-            else:
-                self._drop_prefix_fault()
-        self._expire_deadlines()
-        self._admit()
-        self._prefill_all()
-        self._decode()
-        self._retire()
-        if self.watchdog is not None:
-            injected = (self.faults.delay(self._step)
-                        if self.faults is not None else 0.0)
-            if self.watchdog.observe(time.perf_counter() - t0,
-                                     injected=injected):
-                self.stats.n_stragglers += 1
+        with span("sched.step", step=self._step):
+            if (self.faults is not None
+                    and self.faults.drop_prefix(self._step)):
+                if self.prefix_index is None:
+                    # The fault action doesn't apply to this family/config
+                    # (no prefix index to drop): counted no-op, never a
+                    # raise.
+                    self.stats.n_prefix_drop_skips += 1
+                else:
+                    self._drop_prefix_fault()
+            self._expire_deadlines()
+            with span("sched.admit"):
+                self._admit()
+            with span("sched.prefill"):
+                self._prefill_all()
+            with span("sched.decode"):
+                self._decode()
+            with span("sched.retire"):
+                self._retire()
+            if self.watchdog is not None:
+                injected = (self.faults.delay(self._step)
+                            if self.faults is not None else 0.0)
+                if self.watchdog.observe(time.perf_counter() - t0,
+                                         injected=injected):
+                    self.stats.n_stragglers += 1
 
     # -- fault hooks ---------------------------------------------------------
 
@@ -962,9 +964,10 @@ class Scheduler:
                 # re-derives the mappings, so re-admission reuses the pages
                 # (bit-identical KV, int8 scales included) it had before.
                 self.stats.prefill_tokens_saved += tail_start
-                traffic, streams = self.family.share_account(
-                    tail_start, shared
-                )
+                with span("sched.account", kind="share", steps=1):
+                    traffic, streams = self.family.share_account(
+                        tail_start, shared
+                    )
                 self.stats.records.append(StepRecord(
                     step=self._step, kind="share", n_active=1, new_tokens=0,
                     traffic=traffic, streams=streams,
@@ -1012,19 +1015,27 @@ class Scheduler:
                     )
                     self.family.retain_units(new_pages)
         if completed:
-            lg = np.asarray(logits)  # host sync: admission boundary only
-            for i, r in completed:
-                tok = int(np.argmax(lg[i, : self.family.vocab]))
-                r.generated.append(tok)
-                new_tokens += 1
-                if r.on_token:
-                    r.on_token(r, tok)
+            # Host sync: admission boundary only.  A family that hands back
+            # host logits has already synced under its own span.
+            if isinstance(logits, np.ndarray):
+                lg = logits
+            else:
+                with span("engine.sync", kind="prefill"):
+                    lg = np.asarray(logits)
+            with span("sched.handoff"):
+                for i, r in completed:
+                    tok = int(np.argmax(lg[i, : self.family.vocab]))
+                    r.generated.append(tok)
+                    new_tokens += 1
+                    if r.on_token:
+                        r.on_token(r, tok)
         # Stream descriptors + traffic in the family's own dialect, from
         # the same host-shadow math its kernels resolve (as decode does).
         n = len(pending)
-        traffic, streams = self.family.prefill_account(
-            slots[:n], starts[:n], counts[:n]
-        )
+        with span("sched.account", kind="prefill", steps=1):
+            traffic, streams = self.family.prefill_account(
+                slots[:n], starts[:n], counts[:n]
+            )
         self.stats.records.append(StepRecord(
             step=self._step, kind="prefill", n_active=n,
             new_tokens=new_tokens, traffic=traffic, streams=streams,
@@ -1065,7 +1076,8 @@ class Scheduler:
         ]
         if not running:
             return
-        running = self._grow_units(running)
+        with span("sched.grow"):
+            running = self._grow_units(running)
         if not running:
             return
         b = self.family.batch
@@ -1094,25 +1106,27 @@ class Scheduler:
             return
         # Per-step accounting snapshots come *before* the launch mutates the
         # family's host shadows — identical records to a step-at-a-time run.
-        accounts = self.family.step_streams(active, n)
+        with span("sched.account", kind="decode", steps=n):
+            accounts = self.family.step_streams(active, n)
         out = self.family.decode_steps(tokens, active, n)
 
-        for s in range(n):
-            traffic, streams = accounts[s]
-            new_tokens = 0
-            for r in running:
-                r.fed += 1
-                if r.fed < len(r.generated):
-                    continue  # replay after eviction: output already known
-                tok = int(out[s, r.slot])
-                r.generated.append(tok)
-                new_tokens += 1
-                if r.on_token:
-                    r.on_token(r, tok)
-            self.stats.records.append(StepRecord(
-                step=self._step, kind="decode", n_active=len(running),
-                new_tokens=new_tokens, traffic=traffic, streams=streams,
-            ))
+        with span("sched.handoff"):
+            for s in range(n):
+                traffic, streams = accounts[s]
+                new_tokens = 0
+                for r in running:
+                    r.fed += 1
+                    if r.fed < len(r.generated):
+                        continue  # replay after eviction: output already known
+                    tok = int(out[s, r.slot])
+                    r.generated.append(tok)
+                    new_tokens += 1
+                    if r.on_token:
+                        r.on_token(r, tok)
+                self.stats.records.append(StepRecord(
+                    step=self._step, kind="decode", n_active=len(running),
+                    new_tokens=new_tokens, traffic=traffic, streams=streams,
+                ))
 
     def _decode_speculative(self, running: List[Request],
                             tokens: np.ndarray, active: np.ndarray,
@@ -1134,30 +1148,32 @@ class Scheduler:
         k = self.family.spec_k
         lens0 = np.array(self._lengths(), copy=True)
         toks, counts = self.family.verify_steps(tokens, active, n)
-        accounts = self.family.verify_account(lens0, active, counts)
-        for s in range(n):
-            traffic, streams = accounts[s]
-            new_tokens = 0
-            for r in running:
-                c = int(counts[s, r.slot])
-                self.stats.n_drafted += k - 1
-                self.stats.n_accepted += max(c - 1, 0)
-                for i in range(c):
-                    if r.done:
-                        break  # surplus emissions past max_new: dropped
-                    r.fed += 1
-                    if r.fed < len(r.generated):
-                        continue  # replay after eviction: output known
-                    tok = int(toks[s, r.slot, i])
-                    r.generated.append(tok)
-                    new_tokens += 1
-                    self.stats.n_emitted += 1
-                    if r.on_token:
-                        r.on_token(r, tok)
-            self.stats.records.append(StepRecord(
-                step=self._step, kind="verify", n_active=len(running),
-                new_tokens=new_tokens, traffic=traffic, streams=streams,
-            ))
+        with span("sched.account", kind="verify", steps=n):
+            accounts = self.family.verify_account(lens0, active, counts)
+        with span("sched.handoff"):
+            for s in range(n):
+                traffic, streams = accounts[s]
+                new_tokens = 0
+                for r in running:
+                    c = int(counts[s, r.slot])
+                    self.stats.n_drafted += k - 1
+                    self.stats.n_accepted += max(c - 1, 0)
+                    for i in range(c):
+                        if r.done:
+                            break  # surplus emissions past max_new: dropped
+                        r.fed += 1
+                        if r.fed < len(r.generated):
+                            continue  # replay after eviction: output known
+                        tok = int(toks[s, r.slot, i])
+                        r.generated.append(tok)
+                        new_tokens += 1
+                        self.stats.n_emitted += 1
+                        if r.on_token:
+                            r.on_token(r, tok)
+                self.stats.records.append(StepRecord(
+                    step=self._step, kind="verify", n_active=len(running),
+                    new_tokens=new_tokens, traffic=traffic, streams=streams,
+                ))
 
     def _grow_units(self, running: List[Request]) -> List[Request]:
         """Allocate a unit for every running request whose next token lands

@@ -382,7 +382,6 @@ def test_injected_latency_trips_straggler_watchdog():
     assert sched.stats.n_stragglers == 1
     # Nobody actually slept: the injected 30 s is bookkeeping, not wall time.
     assert sum(watchdog.history) >= 30.0
-    assert sched.stats.wall_s == 0.0  # chaos_drive steps manually
 
 
 # ---------------------------------------------------------------------------

@@ -1,0 +1,177 @@
+"""The program's host spans (``repro.runtime.tracing``) in a profiler trace.
+
+Each serving test drives the ``Scheduler`` inside a profiler window (CPU,
+``impl='ref'``) and reads the window's host events back: every span name is
+written, each span lies inside the phase that opens it, and the stats agree
+with the scheduler's own records.  Nothing here is timed.
+"""
+import gc
+import glob
+import os
+import re
+
+import jax
+import numpy as np
+import pytest
+
+from repro.configs import smoke_config
+from repro.runtime import tracing
+from repro.runtime.tracing import SPANS, install_gc_spans
+from repro.serve import (
+    PagedKVCache,
+    PagedLM,
+    RecurrentLM,
+    Request,
+    Scheduler,
+)
+
+SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
+    __file__))), "src", "repro")
+
+#: The phases of one ``sched.step``, in the order it opens them.
+PHASES = ("sched.admit", "sched.prefill", "sched.decode", "sched.retire")
+
+#: Where each span may be opened: the spans it lies inside.
+PARENTS = {
+    "sched.admit": {"sched.step"},
+    "sched.prefill": {"sched.step"},
+    "sched.decode": {"sched.step"},
+    "sched.retire": {"sched.step"},
+    "sched.grow": {"sched.decode"},
+    "sched.account": {"sched.admit", "sched.prefill", "sched.decode"},
+    "sched.handoff": {"sched.prefill", "sched.decode"},
+    "engine.launch": {"sched.prefill", "sched.decode"},
+    "engine.sync": {"sched.prefill", "sched.decode"},
+}
+
+
+def _profile(tmp_path, fn):
+    """Run ``fn`` inside a profiler window; returns its spans as
+    ``(name, start_ns, end_ns, stats)``."""
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                     recursive=True)[0]
+    pd = ProfileData.from_file(path)
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+            for pl in pd.planes if pl.name == "/host:CPU"
+            for ln in pl.lines for e in ln.events if e.name in SPANS]
+
+
+def _named(spans, name):
+    return [sp for sp in spans if sp[0] == name]
+
+
+def _paged(spec_k):
+    cfg = smoke_config("yi-6b")
+    model = PagedLM(cfg, jax.random.PRNGKey(0), impl="ref", spec_k=spec_k)
+    cache = PagedKVCache.create(cfg, batch=2, max_len=32, page=4)
+    # Prefix sharing gives the admission's share accounting a span too.
+    return cfg, Scheduler(model, cache, chunk=4,
+                          prefix_sharing=spec_k == 1)
+
+
+def _recurrent(spec_k):
+    cfg = smoke_config("rwkv6-3b")
+    model = RecurrentLM(cfg, jax.random.PRNGKey(0), impl="ref")
+    return cfg, Scheduler(model, model.init_pool(2), chunk=4)
+
+
+def _serve(make, spec_k):
+    """A scheduler and the requests it will serve: two share a page-aligned
+    prefix, three compete for two slots, and decode crosses pages."""
+    cfg, sched = make(spec_k)
+    rng = np.random.default_rng(0)
+    system = rng.integers(0, cfg.vocab, 8)
+    prompts = [np.concatenate([system, rng.integers(0, cfg.vocab, 3)]),
+               np.concatenate([system, rng.integers(0, cfg.vocab, 2)]),
+               rng.integers(0, cfg.vocab, 6)]
+    reqs = [Request(rid=i, prompt=p.astype(np.int32), max_new=7)
+            for i, p in enumerate(prompts)]
+    for r in reqs:
+        sched.submit(r)
+    return sched, reqs
+
+
+@pytest.mark.parametrize("make,spec_k", [(_paged, 1), (_paged, 2),
+                                         (_recurrent, 1)],
+                         ids=["paged", "speculative", "recurrent"])
+def test_spans_of_a_served_run(tmp_path, make, spec_k):
+    sched, reqs = _serve(make, spec_k)
+    spans = _profile(tmp_path, sched.run)
+    assert {sp[0] for sp in spans} >= set(SPANS) - {"gc"}
+
+    for name, s, e, _ in spans:
+        parents = PARENTS.get(name)
+        if parents:
+            assert any(p[0] in parents and p[1] <= s and e <= p[2]
+                       for p in spans), f"{name} at {s} outside {parents}"
+
+    steps = _named(spans, "sched.step")
+    assert [sp[3]["step"] for sp in steps] == list(range(1, sched._step + 1))
+    # Each step opens every phase once, in order.
+    for _, s, e, _ in steps:
+        phases = sorted((ps, name) for name, ps, pe, _ in spans
+                        if name in PHASES and s <= ps and pe <= e)
+        assert [name for _, name in phases] == list(PHASES)
+
+    records = sched.stats.records
+    launches = _named(spans, "engine.launch")
+    decoded = sum(sp[3]["n"] for sp in launches
+                  if sp[3]["kind"] in ("decode", "verify"))
+    assert decoded == sum(r.kind in ("decode", "verify") for r in records)
+    prefills = [sp[3]["rows"] for sp in launches
+                if sp[3]["kind"] == "prefill"]
+    assert prefills == [r.n_active for r in records if r.kind == "prefill"]
+    assert sum(sp[3]["steps"] for sp in _named(spans, "sched.account")) \
+        == len(records)
+    # One wait per prefill at most: the family's, or the scheduler's read
+    # of device logits, never both.
+    for _, s, e, _ in _named(spans, "sched.prefill"):
+        assert sum(s <= sp[1] and sp[2] <= e
+                   for sp in _named(spans, "engine.sync")) <= 1
+    kinds = {sp[3]["kind"] for sp in _named(spans, "sched.account")}
+    assert kinds == {r.kind for r in records}
+    if make is _paged and spec_k == 1:
+        assert kinds == {"share", "prefill", "decode"}
+
+
+def test_gc_span_per_collection_of_the_older_generations(tmp_path):
+    install_gc_spans()
+    install_gc_spans()
+    assert gc.callbacks.count(tracing._GC_SPANS) == 1
+
+    def collect():
+        was = gc.isenabled()
+        gc.disable()                    # no collection but the two below
+        try:
+            ring = [[] for _ in range(100)]
+            for a, b in zip(ring, ring[1:] + ring[:1]):
+                a.append(b)             # a reference cycle only gc frees
+            del ring, a, b
+            gc.collect(2)
+            gc.collect(0)
+        finally:
+            if was:
+                gc.enable()
+
+    spans = _profile(tmp_path, collect)
+    got = _named(spans, "gc")
+    assert len(got) == 1
+    assert got[0][3]["generation"] == 2
+    assert got[0][3]["collected"] >= 100
+
+
+def test_every_span_the_program_opens_is_listed():
+    opened = set()
+    for path in glob.glob(os.path.join(SRC, "**", "*.py"), recursive=True):
+        with open(path) as f:
+            opened |= set(re.findall(r'\bspan\(\s*"([^"]+)"', f.read()))
+    assert opened == set(SPANS)
