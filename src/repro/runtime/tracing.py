@@ -17,9 +17,9 @@ Where each span is opened:
   ``share_account``);
 * ``sched.handoff``: appending served tokens to their requests and calling
   ``on_token`` (for decode and verify, building their step records);
-* ``engine.launch`` (``kind``, ``n`` or ``rows``, ``ctx_pages``): one jitted
-  program call, from the call to its return (dispatch and argument
-  transfer);
+* ``engine.launch`` (``kind``, ``n`` or ``rows``, ``ctx_pages``; for decode
+  ``ppb`` and ``block_fill``): one jitted program call, from the call to its
+  return (dispatch and argument transfer);
 * ``engine.sync`` (``kind``): a host read that waits for a program's result;
 * ``gc`` (``generation``, ``collected``): a collection of generation 1 or 2
   (:func:`install_gc_spans`).

@@ -43,12 +43,24 @@ from repro.core.streams import (
     verify_table_streams,
 )
 from repro.kernels import ops as kops
+from repro.kernels.paged_decode import pages_per_block
 from repro.runtime.tracing import span
 from .drafter import Drafter, NGramDrafter
 from .family import ServableFamily
 from .kv import PagedKVCache, _donation_noop_ok
 
 __all__ = ["PagedFamily", "PagedLM", "static_batch_generate"]
+
+
+def _block_fill(lengths, active, page: int, ppb: int) -> float:
+    """Share of the decode kernel's walked page slots that hold live pages:
+    the active rows' pages over ``ppb`` times their blocks, at ``lengths``
+    (the first step of a launch); 0 with no host shadow or no live page."""
+    if lengths is None:
+        return 0.0
+    used = -(-np.asarray(lengths)[np.asarray(active, bool)] // page)
+    slots = ppb * (-(-used // ppb)).sum()
+    return float(used.sum() / slots) if slots else 0.0
 
 
 def _paged_lm_decode_step(params, tokens, k_pages, v_pages, k_scale, v_scale,
@@ -532,19 +544,26 @@ class PagedLM:
         kp, vp = cache.k_pages, cache.v_pages
         ks, vs = cache.k_scale, cache.v_scale
         lens = cache.lengths
+        ppb = pages_per_block(cache.page_size, self.kvh, self.hd,
+                              kp.dtype, cache.pages_per_seq, cache.quantized)
         parts = []
         rem = n
+        done = 0
         with _donation_noop_ok():
             while rem:
                 m = 1 << (rem.bit_length() - 1)
                 with span("engine.launch", kind="decode", n=m,
-                          ctx_pages=cache.pages_per_seq):
+                          ctx_pages=cache.pages_per_seq, ppb=ppb,
+                          block_fill=_block_fill(
+                              self._shift_lengths(cache, act_host, done + 1),
+                              act_host, cache.page_size, ppb)):
                     toks, feed, kp, vp, ks, vs, lens = self._decode_many(
                         self.params, feed, kp, vp, ks, vs, cache.page_table,
                         lens, act_dev, n=m,
                     )
                 parts.append(toks)
                 rem -= m
+                done += m
         with span("engine.sync", kind="decode"):
             out = np.concatenate([np.asarray(t) for t in parts], axis=0)
         cache = dataclasses.replace(

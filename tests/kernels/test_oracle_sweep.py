@@ -2,8 +2,9 @@
 
 One seeded, parametrized suite replacing the per-kernel ad-hoc ref≡pallas
 cases (the GQA/int8 spot checks that used to live in test_paged_prefill.py
-and test_workload_kernels.py): both paged kernels × {fp32, int8} ×
-{GQA group 1/2/4} × ragged / page-boundary length patterns.  A future
+and test_workload_kernels.py): both paged kernels × {fp32, int8} (and bf16
+pools for decode) × {GQA group 1/2/4} × ragged / page-boundary length
+patterns, and for decode the edges of its multi-page block walk.  A future
 kernel edit gets the full cross-product for free — a new length pattern or
 GQA shape added below lands on every kernel and dtype at once.
 
@@ -19,6 +20,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels.paged_decode import pages_per_block
 
 PAGE = 4
 POOL = 24
@@ -38,6 +40,18 @@ DECODE_LENGTHS = {
     "full": [16, 16, 16],
 }
 
+#: Block-walk patterns: the decode kernel gathers ``ppb`` pages per block
+#: (:func:`pages_per_block`, 16 here), and these table widths are not a
+#: multiple of it.  Their rows are empty, one token, one page, one block
+#: less, exactly and one more than a block (``ppb * PAGE``), and the full
+#: table.
+DECODE_WALKS = {"walk37": 37, "walk40": 40}
+
+
+def _walk_lengths(npg, ppb):
+    block = ppb * PAGE
+    return [0, 1, PAGE, block - 1, block, block + 1, npg * PAGE]
+
 #: Prefill (starts, counts) patterns with a chunk width of 8: ragged
 #: mid-page starts, chunks straddling page boundaries, start+count landing
 #: exactly on page boundaries, and padding rows (count 0, including a
@@ -54,9 +68,9 @@ def _seed(*parts) -> int:
     return zlib.crc32("/".join(str(p) for p in parts).encode())
 
 
-def _pool(rng, kvh, d, quantize):
-    kp = jnp.asarray(rng.normal(size=(POOL, PAGE, kvh, d)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(POOL, PAGE, kvh, d)), jnp.float32)
+def _pool(rng, kvh, d, quantize, dtype=jnp.float32, pages=POOL):
+    kp = jnp.asarray(rng.normal(size=(pages, PAGE, kvh, d)), dtype)
+    vp = jnp.asarray(rng.normal(size=(pages, PAGE, kvh, d)), dtype)
     if not quantize:
         return kp, vp, {}
     kq, ks = ref.quantize_kv(kp)
@@ -64,19 +78,30 @@ def _pool(rng, kvh, d, quantize):
     return kq, vq, dict(k_scale=ks, v_scale=vs)
 
 
-@pytest.mark.parametrize("lengths_name", sorted(DECODE_LENGTHS))
+@pytest.mark.parametrize("lengths_name",
+                         sorted(DECODE_LENGTHS) + sorted(DECODE_WALKS))
 @pytest.mark.parametrize("group", sorted(GQA))
-@pytest.mark.parametrize("dtype", ["fp32", "int8"])
+@pytest.mark.parametrize("dtype", ["fp32", "int8", "bf16"])
 def test_paged_decode_matches_ref(dtype, group, lengths_name):
     h, kvh = GQA[group]
     d = 16
-    lengths = DECODE_LENGTHS[lengths_name]
-    b, npg = len(lengths), 4
+    quantize = dtype == "int8"
+    pool_dtype = jnp.bfloat16 if dtype == "bf16" else jnp.float32
+    npg = DECODE_WALKS.get(lengths_name, 4)
+    ppb = pages_per_block(PAGE, kvh, d, jnp.int8 if quantize else pool_dtype,
+                          npg, quantize)
+    if lengths_name in DECODE_WALKS:
+        assert 1 < ppb < npg and npg % ppb
+        lengths = _walk_lengths(npg, ppb)
+    else:
+        lengths = DECODE_LENGTHS[lengths_name]
+    b = len(lengths)
+    pages = max(POOL, b * npg)
     rng = np.random.default_rng(_seed("decode", dtype, group, lengths_name))
-    kp, vp, scales = _pool(rng, kvh, d, dtype == "int8")
+    kp, vp, scales = _pool(rng, kvh, d, quantize, pool_dtype, pages)
     q = jnp.asarray(rng.normal(size=(b, h, d)), jnp.float32)
     pt = jnp.asarray(
-        rng.permutation(POOL)[: b * npg].reshape(b, npg), jnp.int32
+        rng.permutation(pages)[: b * npg].reshape(b, npg), jnp.int32
     )
     ln = jnp.asarray(lengths, jnp.int32)
     got = ops.paged_decode_attention(q, kp, vp, pt, ln, impl="pallas", **scales)

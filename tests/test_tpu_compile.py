@@ -13,6 +13,7 @@ process at a time may load the TPU library, and every test worker imports
 every test file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +21,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 import chip_smoke
+from bench.trace import is_pallas, scalar_operands
 from repro.configs import get_config
 from repro.kernels import ops
 from repro.kernels import stream_converters as sc
@@ -32,6 +34,8 @@ H, KVH, HD = 32, 4, 128
 PAGE, BATCH, MAX_LEN = chip_smoke.PAGE, chip_smoke.BATCH, chip_smoke.MAX_LEN
 N_PAGES = MAX_LEN // PAGE
 POOL_PAGES = BATCH * N_PAGES
+#: The benchmark's longctx cell: 64 slots of a 256-page table.
+CELL_BATCH, CELL_PAGES = 64, 4096 // PAGE
 
 
 @pytest.fixture(scope="module")
@@ -88,6 +92,16 @@ def _compile(fn, *args, donate_argnums=(), **kwargs):
     return compiled
 
 
+def _hlo_lines(compiled):
+    """The compiled program's instructions, operands printed with their
+    shapes as a profiler trace names them."""
+    from jax._src.lib import _jax
+    opts = _jax.HloPrintOptions()
+    opts.print_operand_shape = True
+    module = compiled.runtime_executable().hlo_modules()[0]
+    return module.to_string(opts).splitlines()
+
+
 # ---------------------------------------------------------------------------
 # The four stream converters
 # ---------------------------------------------------------------------------
@@ -121,18 +135,32 @@ def test_converter_compiles(spec, op, dtype, width):
 
 
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
-@pytest.mark.parametrize("kernel", ["decode", "prefill", "verify"])
+@pytest.mark.parametrize("kernel", ["decode", "prefill", "verify",
+                                    "decode_longctx"])
 def test_paged_attention_compiles(spec, kernel, kv):
+    """Each kernel at ``chip_smoke``'s shapes, and decode at the longctx
+    cell's too (64 rows of a 256-page table).  The decode kernel keeps the
+    signature ``bench/metrics/paged_decode_roofline.py`` finds it by: one
+    Pallas call named ``paged_decode`` whose operands open with three
+    ``s32`` scalars, aliasing none."""
     kv_dtype = jnp.int8 if kv == "int8" else jnp.bfloat16
-    pages = spec((POOL_PAGES, PAGE, KVH, HD), kv_dtype)
-    scales = ([spec((POOL_PAGES, PAGE, KVH), jnp.float32)] * 2
+    batch, n_pages = ((CELL_BATCH, CELL_PAGES) if kernel == "decode_longctx"
+                      else (BATCH, N_PAGES))
+    pages = spec((batch * n_pages, PAGE, KVH, HD), kv_dtype)
+    scales = ([spec((batch * n_pages, PAGE, KVH), jnp.float32)] * 2
               if kv == "int8" else [])
-    table = spec((BATCH, N_PAGES), jnp.int32)
-    lens = spec((BATCH,), jnp.int32)
-    if kernel == "decode":
-        q = spec((BATCH, H, HD), jnp.float32)
-        _compile(paged_decode_attention_kernel, q, pages, pages, table, lens,
-                 *scales)
+    table = spec((batch, n_pages), jnp.int32)
+    lens = spec((batch,), jnp.int32)
+    if kernel.startswith("decode"):
+        q = spec((batch, H, HD), jnp.float32)
+        compiled = _compile(paged_decode_attention_kernel, q, pages, pages,
+                            table, lens, *scales)
+        calls = [ln for ln in _hlo_lines(compiled)
+                 if "paged_decode" in ln.split("=", 1)[0]
+                 and "custom-call(" in ln]
+        assert len(calls) == 1
+        assert is_pallas(calls[0]) and scalar_operands(calls[0]) == 3
+        assert "output_to_operand_aliasing" not in calls[0]
         return
     c = chip_smoke.CHUNK if kernel == "prefill" else chip_smoke.SPEC_K
     q = spec((BATCH, c, H, HD), jnp.float32)
@@ -216,6 +244,13 @@ def test_paged_lm_fused_decode_program_compiles(spec, on_tpu):
         model.params, tokens, kp, vp, None, None, table, lens, active, n=4,
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    # The kernels read the pools where they lie: no copy or transpose of a
+    # layer's pool, or of the stack, is made for them.
+    pool = re.compile(r"[\[,]" + ",".join(map(str, kp.shape[1:])) + r"\]")
+    for ln in _hlo_lines(compiled):
+        m = re.search(r"= (\S+) ([\w-]+)\(", ln)
+        assert not (m and pool.search(m.group(1))
+                    and m.group(2) in ("copy", "transpose")), ln
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)

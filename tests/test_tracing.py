@@ -11,10 +11,16 @@ import os
 import re
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import smoke_config
+from repro.kernels.paged_decode import (
+    MAX_PAGES_PER_BLOCK,
+    VMEM_BUDGET,
+    pages_per_block,
+)
 from repro.runtime import tracing
 from repro.runtime.tracing import SPANS, install_gc_spans
 from repro.serve import (
@@ -24,6 +30,7 @@ from repro.serve import (
     Request,
     Scheduler,
 )
+from repro.serve.paged_lm import _block_fill
 
 SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
     __file__))), "src", "repro")
@@ -141,6 +148,60 @@ def test_spans_of_a_served_run(tmp_path, make, spec_k):
     assert kinds == {r.kind for r in records}
     if make is _paged and spec_k == 1:
         assert kinds == {"share", "prefill", "decode"}
+
+
+#: (page, KV heads, head dim, table width) of the longctx cell, of
+#: ``chip_smoke.py`` and of the benchmark's CPU rehearsal.
+CELL, SMOKE, REHEARSAL = (16, 4, 128, 256), (16, 4, 128, 40), (16, 2, 32, 16)
+
+
+@pytest.mark.parametrize("kv", [jnp.bfloat16, jnp.int8, jnp.float32])
+@pytest.mark.parametrize("shape", [CELL, SMOKE, REHEARSAL],
+                         ids=["cell", "smoke", "rehearsal"])
+def test_pages_per_block_fits_its_vmem_and_its_table(shape, kv):
+    page, kvh, d, n_pages = shape
+    quantized = kv == jnp.int8
+    ppb = pages_per_block(page, kvh, d, kv, n_pages, quantized)
+    assert ppb & (ppb - 1) == 0 and 1 < ppb <= min(MAX_PAGES_PER_BLOCK,
+                                                   n_pages)
+    # Two buffers of K and V (and scale) pages; a token's (KVH, D) tile
+    # pads to (8 * 4 / itemsize, 128), a scale page to 128 lanes.
+    item = jnp.dtype(kv).itemsize
+    sub = 8 * 4 // item
+    tile = -(-kvh // sub) * sub * -(-d // 128) * 128 * item
+    per_page = page * tile + (page * 128 * 4 if quantized else 0)
+    assert 2 * 2 * ppb * per_page <= VMEM_BUDGET
+    if shape == CELL:
+        assert ppb == 16                 # 256 tokens, 512 KiB of K+V rows
+
+
+def test_decode_launches_carry_the_kernels_block_walk(tmp_path):
+    """Each decode ``engine.launch`` names the decode kernel's pages per
+    block and how full the active rows' walked blocks are, from the host
+    lengths at the launch's first step."""
+    sched, _ = _serve(_paged, 1)
+    model, cache = sched.family.model, sched.family.cache
+    ppb = pages_per_block(cache.page_size, model.kvh, model.hd,
+                          cache.k_pages.dtype, cache.pages_per_seq)
+    launches = [sp[3] for sp in _named(_profile(tmp_path, sched.run),
+                                       "engine.launch")]
+    decode = [st for st in launches if st["kind"] == "decode"]
+    assert decode and all(st["ppb"] == ppb for st in decode)
+    assert all(0 < st["block_fill"] <= 1 for st in decode)
+    assert not any("ppb" in st or "block_fill" in st for st in launches
+                   if st["kind"] != "decode")
+
+
+@pytest.mark.parametrize("lengths,active,fill", [
+    ([5, 0, 9], [True, False, True], (2 + 3) / (2 * (1 + 2))),
+    ([16, 17, 0], [True, True, False], (4 + 5) / (2 * (2 + 3))),
+    ([3, 7], [False, False], 0.0),
+])
+def test_block_fill_counts_live_pages_over_walked_slots(lengths, active,
+                                                        fill):
+    assert _block_fill(np.asarray(lengths), np.asarray(active), 4, 2) \
+        == pytest.approx(fill)
+    assert _block_fill(None, np.asarray(active), 4, 2) == 0.0
 
 
 def test_gc_span_per_collection_of_the_older_generations(tmp_path):
