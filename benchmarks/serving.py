@@ -326,8 +326,7 @@ def family_rows(
     rows = []
     for arch in archs:
         cfg = smoke_config(arch_cfg[arch])
-        model = RecurrentLM(cfg, jax.random.PRNGKey(0), arch=arch,
-                            impl="ref")
+        model = RecurrentLM(cfg, jax.random.PRNGKey(0), arch=arch)
         for b in batch_sizes:
             lens = rng.integers(4, max_prompt + 1, b)
             prompts = [rng.integers(0, cfg.vocab, int(n)).astype(np.int32)

@@ -5,7 +5,7 @@ Drives ``Scheduler`` → ``PagedLM`` → the paged Pallas kernels (decode,
 prefill, verify, and the indirect write converters) at yi-6b's published
 widths — d_model 4096, 32 query / 4 KV heads of 128, vocab 64000 — with
 random weights drawn from ``--seed``, then ``RecurrentLM`` at rwkv6-3b's
-widths through the same scheduler (the strided state converters).  Phases:
+widths through the same scheduler (its state slots).  Phases:
 
 * ``yi-6b/bf16``  — all 32 layers, bf16 KV pages;
 * ``yi-6b/int8``  — int8 KV pages with fp32 scales (depth cut, printed);
@@ -233,11 +233,10 @@ def paged_phase(name, cfg, geo, seed, counter, on_chip, *, kv_dtype,
     return row
 
 
-def recurrent_phase(name, cfg, geo, seed, counter, on_chip):
+def recurrent_phase(name, cfg, geo, seed, counter):
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from repro.kernels import ops
     from repro.serve import (RecurrentLM, Request, Scheduler,
                              recurrent_reference_generate)
 
@@ -259,14 +258,7 @@ def recurrent_phase(name, cfg, geo, seed, counter, on_chip):
         for p in (sched.family.pool, pool) for t in p.tensors.values()
     )
     in_use, peak = _memory(jax)     # weights and both pools live
-    # Admission zeroes a slot's state rows through the strided write
-    # converter; it must be the Mosaic kernel, not interpreted HLO.
     state = sched.family.pool.tensors
-    text = jax.jit(
-        lambda p: {k: ops.recurrent_state_write(
-            v, 0, jnp.zeros((v.shape[0],) + v.shape[2:], v.dtype))
-            for k, v in p.items()}
-    ).lower(_spec(jax, state)).as_text()
     row = {
         "phase": name,
         "layers": cfg.n_layers,
@@ -281,14 +273,12 @@ def recurrent_phase(name, cfg, geo, seed, counter, on_chip):
         "compile_s": counter.secs - s0,
         "bytes_in_use": in_use,
         "peak_bytes_in_use": peak,
-        "state_write_tpu_custom_call": "tpu_custom_call" in text,
         "all_terminal": all_terminal,
         "sched_eq_static": not mismatch,
         "mismatched_requests": mismatch,
         "finite": finite,
     }
-    row["ok"] = (all_terminal and not mismatch and finite
-                 and (row["state_write_tpu_custom_call"] or not on_chip))
+    row["ok"] = all_terminal and not mismatch and finite
     return row
 
 
@@ -330,7 +320,7 @@ def main(argv=None) -> int:
                             geo, args.seed, counter, on_chip, kv_dtype="bf16",
                             spec_k=SPEC_K),
         lambda: recurrent_phase("rwkv6-3b", cut("rwkv6-3b", FOLLOW_UP_LAYERS),
-                                geo, args.seed, counter, on_chip),
+                                geo, args.seed, counter),
     ]
     ok = True
     for run in phases:

@@ -115,7 +115,9 @@ class ArchConfig:
         h, kvh, hd = self.n_heads, self.n_kv_heads, self.hd
         attn = d * h * hd + 2 * d * kvh * hd + h * hd * d
         if self.ssm == "rwkv6":
-            per_layer = 4 * d * d + 2 * d * f // 2 + d * f  # time-mix + channel-mix
+            # time mix r,k,v,g,o and channel-mix receptance; channel-mix
+            # key and value (LoRAs and vectors are under 1%)
+            per_layer = 6 * d * d + 2 * d * f
         else:
             mlp = (3 if self.glu else 2) * d * f
             if self.n_experts:

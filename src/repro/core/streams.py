@@ -18,6 +18,8 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+import functools
+import itertools
 import math
 from typing import Optional, Sequence, Tuple
 
@@ -389,20 +391,25 @@ def recurrent_state_streams(
     The family builds these each step and derives the
     :func:`repro.core.packing.recurrent_decode_traffic` accounting from the
     same (slots, batch, layers, bytes) quantities, so descriptors and byte
-    accounting share one source of truth — mirroring the paged path.
+    accounting share one source of truth — mirroring the paged path.  A
+    slot's descriptors never change, so each slot's are made once and
+    shared (they are frozen).
     """
+    shape = (int(batch), int(n_layers), tuple(int(rb) for rb in row_bytes))
+    return tuple(itertools.chain.from_iterable(
+        _slot_state_streams(int(slot), *shape) for slot in slots))
+
+
+@functools.lru_cache(maxsize=4096)
+def _slot_state_streams(slot: int, batch: int, n_layers: int,
+                        row_bytes: Tuple[int, ...]
+                        ) -> Tuple["StridedStream", ...]:
+    """One slot's read and write-back burst per state tensor."""
     out = []
-    for slot in slots:
-        for rb in row_bytes:
-            for _ in range(2):  # read burst + write-back burst
-                out.append(
-                    StridedStream(
-                        base=int(slot),
-                        elem_bits=int(rb) * 8,
-                        count=int(n_layers),
-                        stride=int(batch),
-                    )
-                )
+    for rb in row_bytes:
+        burst = StridedStream(base=slot, elem_bits=rb * 8, count=n_layers,
+                              stride=batch)
+        out += [burst, burst]  # read burst + write-back burst
     return tuple(out)
 
 

@@ -38,8 +38,6 @@ __all__ = [
     "on_cpu",
     "strided_gather",
     "strided_scatter",
-    "recurrent_state_read",
-    "recurrent_state_write",
     "indirect_gather",
     "indirect_scatter",
     "tiled_transpose",
@@ -95,37 +93,6 @@ def strided_scatter(
     if impl == "ref" or stride == 1:
         return ref.strided_scatter(dst, packed, base, stride)
     return strided_scatter_kernel(dst, packed, base, stride, interpret=_interpret())
-
-
-def _state_rows(pool: jax.Array) -> jax.Array:
-    """(L, B, *row) state pool → its (L·B, *row) row stack (a free reshape)."""
-    return pool.reshape((pool.shape[0] * pool.shape[1],) + pool.shape[2:])
-
-
-def recurrent_state_read(pool: jax.Array, slot: int, impl: str = "pallas") -> jax.Array:
-    """Gather one sequence's recurrent state rows from an (L, B, *row) pool.
-
-    Slot ``s`` of a layer-major pool is rows ``s, s+B, s+2B, ...`` of the
-    flat (L·B, *row) view — exactly a strided burst with base=slot,
-    stride=B, count=L, which is the access the strided PACK converter
-    accelerates.
-    """
-    if impl == "ref":
-        return ref.recurrent_state_read(pool, slot)
-    l, b = int(pool.shape[0]), int(pool.shape[1])
-    return strided_gather(_state_rows(pool), int(slot), b, l, impl=impl)
-
-
-def recurrent_state_write(
-    pool: jax.Array, slot: int, value: jax.Array, impl: str = "pallas"
-) -> jax.Array:
-    """Scatter one sequence's state rows back into an (L, B, *row) pool —
-    the write half of the strided read-modify-write each decode step does."""
-    if impl == "ref":
-        return ref.recurrent_state_write(pool, slot, value)
-    b = int(pool.shape[1])
-    out = strided_scatter(_state_rows(pool), value, int(slot), b, impl=impl)
-    return out.reshape(pool.shape)
 
 
 def indirect_gather(

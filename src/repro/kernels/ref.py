@@ -35,16 +35,6 @@ def strided_scatter(
     return dst.at[idx].set(packed)
 
 
-def recurrent_state_read(pool: jax.Array, slot: int) -> jax.Array:
-    """out[l] = pool[l, slot] — one sequence's state rows from (L, B, *row)."""
-    return pool[:, slot]
-
-
-def recurrent_state_write(pool: jax.Array, slot: int, value: jax.Array) -> jax.Array:
-    """pool[l, slot] = value[l] — write-back half of the state RMW."""
-    return pool.at[:, slot].set(value)
-
-
 def indirect_gather(src: jax.Array, indices: jax.Array) -> jax.Array:
     """out[k] = src[indices[k]]; indices memory-resident (vlimxei semantics)."""
     return jnp.take(src, indices, axis=0, mode="clip")

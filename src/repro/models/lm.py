@@ -45,7 +45,7 @@ def layer_defs(cfg: ArchConfig, q_heads: int, kv_heads: int) -> Dict[str, Any]:
         "ln2": Param((d,), ("d_model",), init="zeros"),
     }
     if cfg.ssm == "rwkv6":
-        return {**rwkv_mod.rwkv_defs(cfg, q_heads), **norms}
+        return rwkv_mod.rwkv_defs(cfg, q_heads)  # its own LayerNorms
     if cfg.ssm == "hymba":
         return {
             "hymba": mamba_mod.hymba_defs(cfg, q_heads, kv_heads),
@@ -170,7 +170,7 @@ def global_flags(cfg: ArchConfig) -> np.ndarray:
 def _block_train(p, x, cfg, rules, is_global, positions):
     """Returns (x, aux_loss)."""
     if cfg.ssm == "rwkv6":
-        x, _ = rwkv_mod.rwkv_block(p, x, cfg, rules, p)
+        x, _ = rwkv_mod.rwkv_block(p, x, cfg, rules)
         return x, jnp.float32(0.0)
     if cfg.ssm == "hymba":
         h = mamba_mod.hymba_block_fwd(
@@ -192,7 +192,7 @@ def _block_train(p, x, cfg, rules, is_global, positions):
 
 def _block_prefill(p, x, cfg, rules, is_global, cache):
     if cfg.ssm == "rwkv6":
-        x, st = rwkv_mod.rwkv_block(p, x, cfg, rules, p, state=None)
+        x, st = rwkv_mod.rwkv_block(p, x, cfg, rules)
         # prefill leaves the final state in the cache
         return x, st
     if cfg.ssm == "hymba":
@@ -215,7 +215,7 @@ def _block_prefill(p, x, cfg, rules, is_global, cache):
 
 def _block_decode(p, x, cfg, rules, is_global, cache, pos):
     if cfg.ssm == "rwkv6":
-        return rwkv_mod.rwkv_block(p, x, cfg, rules, p, state=cache)
+        return rwkv_mod.rwkv_block(p, x, cfg, rules, state=cache)
     if cfg.ssm == "hymba":
         h, cache = mamba_mod.hymba_block_decode(
             p["hymba"], rms_norm(x, p["ln1"]), cfg, rules, is_global, cache, pos

@@ -1,12 +1,41 @@
 """RWKV6 "Finch" block: data-dependent-decay time mix + channel mix.
 
-The WKV recurrence  S_t = diag(w_t) S_{t-1} + k_t vᵀ_t,
-                    y_t = r_t·(S_{t-1} + diag(u) k_t vᵀ_t)
-is evaluated with the chunked decayed-cumsum helper: exact, differentiable,
-O(chunk·H·hd²) live memory — the recurrent state never materializes for the
-whole sequence.  Decode is a single state update (attention-free: this arch
-is the long_500k-capable pure-SSM assignee; packed streams only touch its
-embedding/LM-head gathers — see DESIGN.md §Arch-applicability).
+The published block (arXiv:2404.05892; RWKV/v6-Finch-*-HF, RWKV-LM v6),
+one definition for training (:mod:`repro.models.lm`), serving
+(:class:`repro.serve.RecurrentLM`) and the tests:
+
+* time mix on ``a = LayerNorm_1(x)`` (weight and bias, eps 1e-5): token
+  shift ``sx = a_{t-1} - a``; the five inputs ``a + sx·(mu_i + tanh(xxx
+  W1_i) W2_i)`` with ``xxx = a + sx·mu_x`` (rank-32 LoRA, order w, k, v, r,
+  g); ``r, k, v = x_r R, x_k K, x_v V``, ``g = silu(x_g G)``; the decay
+  ``w = exp(-exp(time_decay + tanh(x_w D1) D2))`` (rank-64 LoRA,
+  :func:`decay`); the WKV recurrence
+
+      S_t = diag(w_t) S_{t-1} + k_t v_tᵀ,   y_t = r_t·(S_{t-1} + diag(u) k_t v_tᵀ)
+
+  over heads of 64 with a float32 64×64 state per head; a GroupNorm over the
+  heads (weight and bias, eps ``1e-5 · head_size_divisor²``); ``(y ⊙ g) O``;
+* channel mix on ``c = LayerNorm_2(x)``: ``sigmoid(x_r Wr) ⊙ (relu(x_k Wk)²
+  Wv)`` with the token-shifted lerps ``x_k``, ``x_r``;
+* around the layers (:func:`rwkv_lm_defs`): ``ln0`` on the embedding,
+  ``ln_out`` before an untied head.
+
+The recurrence is evaluated two ways that agree: :func:`wkv6`, the chunked
+decayed-cumsum form (exact, differentiable, O(chunk·H·hd²) live memory) for
+whole sequences, and :func:`wkv6_step`, one token, which serving and
+single-token decode run.  Decay and state are float32 whatever the compute
+dtype.
+
+Departures from the published model:
+
+* ``rescale_every`` (halving the residual every few layers at inference) is
+  an fp16 overflow guard that is the identity in exact arithmetic; it is
+  left out.
+* :mod:`repro.models.lm`'s training path keeps its own embedding and final
+  RMSNorm around these blocks (no ``ln0``, ``ln_out`` as an RMSNorm);
+  serving uses :func:`rwkv_lm_defs`.
+* The LoRA factors are stored per input (``lora_a`` (5, D, 32)) rather
+  than as one (D, 160) matrix: the same products, in training's layout.
 
 TP: time-mix projections are head-shaped (d → H×64) and shard over 'model'
 like attention heads (padded 40→48 under TP-16, recorded in the config).
@@ -21,11 +50,18 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
 from repro.parallel.sharding import ShardingRules, constrain
-from .common import Param, decayed_cumsum, rms_norm
+from .common import Param, decayed_cumsum, layer_norm
 
+#: Rank of the five mix LoRAs (RWKV-LM v6 TIME_MIX_EXTRA_DIM below 4096 wide).
 LORA_R = 32
+#: Rank of the decay LoRA (TIME_DECAY_EXTRA_DIM below 4096 wide).
+DECAY_R = 64
 HEAD_DIM = 64
-MIX_NAMES = ("r", "w", "k", "v", "g")
+#: Finch's ``head_size_divisor``: it sets the head GroupNorm's eps.
+HEAD_SIZE_DIVISOR = 8
+LN_EPS = 1e-5
+GN_EPS = LN_EPS * HEAD_SIZE_DIVISOR ** 2
+MIX_NAMES = ("w", "k", "v", "r", "g")
 
 
 def rwkv_heads(cfg: ArchConfig, padded: bool = False) -> int:
@@ -34,24 +70,32 @@ def rwkv_heads(cfg: ArchConfig, padded: bool = False) -> int:
     return cfg.d_model // HEAD_DIM
 
 
+def _norm_defs(shape, dims) -> Dict[str, Param]:
+    return {"w": Param(shape, dims, init="ones"),
+            "b": Param(shape, dims, init="zeros")}
+
+
 def rwkv_defs(cfg: ArchConfig, heads: Optional[int] = None) -> Dict[str, Any]:
+    """One layer: ``ln1``, ``ln2``, time mix ``tm`` and channel mix ``cm``."""
     d, f = cfg.d_model, cfg.d_ff
     h = heads or rwkv_heads(cfg)
+    head = (h, HEAD_DIM)
     tm = {
         "mu_x": Param((d,), ("d_model",), init="zeros"),
         "mu": Param((5, d), (None, "d_model"), init="zeros"),
         "lora_a": Param((5, d, LORA_R), (None, "d_model", None), scale=0.01),
         "lora_b": Param((5, LORA_R, d), (None, None, "d_model"), scale=0.01),
-        "w_base": Param((h, HEAD_DIM), ("heads", "head_dim"), init="zeros"),
-        "wa": Param((d, LORA_R * 2), ("d_model", None), scale=0.01),
-        "wb": Param((LORA_R * 2, h, HEAD_DIM), (None, "heads", "head_dim"), scale=0.01),
-        "u": Param((h, HEAD_DIM), ("heads", "head_dim"), init="zeros"),
+        "w_base": Param(head, ("heads", "head_dim"), init="zeros"),
+        "wa": Param((d, DECAY_R), ("d_model", None), scale=0.01),
+        "wb": Param((DECAY_R, h, HEAD_DIM), (None, "heads", "head_dim"),
+                    scale=0.01),
+        "u": Param(head, ("heads", "head_dim"), init="zeros"),
         "wr": Param((d, h, HEAD_DIM), ("fsdp", "heads", "head_dim")),
         "wk": Param((d, h, HEAD_DIM), ("fsdp", "heads", "head_dim")),
         "wv": Param((d, h, HEAD_DIM), ("fsdp", "heads", "head_dim")),
         "wg": Param((d, h, HEAD_DIM), ("fsdp", "heads", "head_dim")),
         "wo": Param((h, HEAD_DIM, d), ("heads", "head_dim", "fsdp")),
-        "ln_g": Param((h, HEAD_DIM), ("heads", "head_dim"), init="zeros"),
+        "ln_x": _norm_defs(head, ("heads", "head_dim")),
     }
     cm = {
         "mu_k": Param((d,), ("d_model",), init="zeros"),
@@ -62,7 +106,37 @@ def rwkv_defs(cfg: ArchConfig, heads: Optional[int] = None) -> Dict[str, Any]:
         # replicated it costs d² per layer in params+grads+moments.
         "wr": Param((d, d), ("fsdp", "heads")),
     }
-    return {"tm": tm, "cm": cm}
+    return {"tm": tm, "cm": cm,
+            "ln1": _norm_defs((d,), ("d_model",)),
+            "ln2": _norm_defs((d,), ("d_model",))}
+
+
+def rwkv_lm_defs(cfg: ArchConfig) -> Dict[str, Any]:
+    """Around the layers: embedding with ``ln0``, ``ln_out`` and an untied
+    head."""
+    d, v = cfg.d_model, cfg.vocab
+    return {
+        "embed": Param((v, d), ("vocab", "d_model"), init="embed"),
+        "ln0": _norm_defs((d,), ("d_model",)),
+        "ln_out": _norm_defs((d,), ("d_model",)),
+        "head": Param((d, v), ("d_model", "vocab")),
+    }
+
+
+def _ln(x: jax.Array, p: Dict[str, jax.Array]) -> jax.Array:
+    return layer_norm(x, p["w"], p["b"], LN_EPS)
+
+
+def rwkv_embed(p, tokens: jax.Array, dtype) -> jax.Array:
+    """``ln0(E[tokens])`` in ``dtype``."""
+    return _ln(p["embed"][tokens].astype(dtype), p["ln0"])
+
+
+def rwkv_logits(p, x: jax.Array) -> jax.Array:
+    """Float32 logits ``ln_out(x) H`` of activations ``x`` (..., D)."""
+    x = _ln(x, p["ln_out"])
+    return jnp.dot(x, p["head"].astype(x.dtype),
+                   preferred_element_type=jnp.float32)
 
 
 def _shift(x: jax.Array, x_last: Optional[jax.Array]) -> jax.Array:
@@ -75,7 +149,7 @@ def wkv6(
     r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
     u: jax.Array, s0: jax.Array, chunk: int = 32,
 ) -> Tuple[jax.Array, jax.Array]:
-    """r,k,v,w: (B,T,H,hd); u: (H,hd); s0: (B,H,hd,hd) → (y, s_final).
+    """r,k,v,w: (B,T,H,hd); u: (H,hd); s0: (B,H,hd,hd) → (y f32, s_final).
 
     The chunk step is rematerialized (jax.checkpoint): backward keeps only
     the per-chunk state carry (B·H·hd² f32) and recomputes the chunk-local
@@ -107,21 +181,44 @@ def wkv6(
 
     s_final, ys = jax.lax.scan(step, s0.astype(jnp.float32), (rc, kc, vc, wc))
     y = ys.transpose(2, 0, 1, 3, 4).reshape(b, t, h, hd)
-    return y.astype(r.dtype), s_final
+    return y, s_final
 
 
-def _ddlerp(p, x, sx):
-    """Data-dependent lerp producing the five mixed inputs (r,w,k,v,g).
+def wkv6_step(
+    r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
+    u: jax.Array, s: jax.Array,
+) -> Tuple[jax.Array, jax.Array]:
+    """One token of the recurrence: r,k,v,w (B,H,hd); u (H,hd); s
+    (B,H,hd,hd) float32 → (y (B,H,hd) float32, next state)."""
+    r, k, v, w = (x.astype(jnp.float32) for x in (r, k, v, w))
+    kv = k[..., :, None] * v[..., None, :]
+    y = jnp.einsum("bhk,bhkv->bhv", r,
+                   s + u.astype(jnp.float32)[..., None] * kv)
+    return y, w[..., None] * s + kv
+
+
+def decay(p, x_w: jax.Array) -> jax.Array:
+    """Per-channel decay in (0, 1), float32:
+    ``exp(-exp(time_decay + tanh(x_w D1) D2))``."""
+    lora = jnp.tanh(x_w.astype(jnp.float32) @ p["wa"].astype(jnp.float32))
+    w_log = p["w_base"].astype(jnp.float32) + jnp.einsum(
+        "bsr,rhk->bshk", lora, p["wb"].astype(jnp.float32))
+    return jnp.exp(-jnp.exp(w_log))
+
+
+def _ddlerp(p, x, sx, dt):
+    """Data-dependent lerp producing the five mixed inputs (w,k,v,r,g).
 
     Computed per-name (not as one stacked (5,B,T,D) einsum): the stacked form
     made the backward materialize 5×(B·T,D) f32 cotangents at once (~15 GB on
     the rwkv6-3b train_4k dry-run).
     """
-    xxx = x + sx * p["mu_x"]
+    xxx = x + sx * p["mu_x"].astype(dt)
     out = {}
     for i, name in enumerate(MIX_NAMES):
-        lora = jnp.tanh(xxx @ p["lora_a"][i]) @ p["lora_b"][i]
-        out[name] = x + sx * (p["mu"][i] + lora)
+        lora = (jnp.tanh(xxx @ p["lora_a"][i].astype(dt))
+                @ p["lora_b"][i].astype(dt))
+        out[name] = x + sx * (p["mu"][i].astype(dt) + lora)
     return out
 
 
@@ -129,42 +226,46 @@ def time_mix(
     p, x, cfg: ArchConfig, rules: ShardingRules,
     state: Optional[Dict[str, jax.Array]] = None,
 ):
-    """state: {'s': (B,H,hd,hd), 'x_tm': (B,D)} for decode; None for train."""
+    """state: {'s': (B,H,hd,hd), 'x_tm': (B,D)} for decode; None for train.
+
+    One token with a state runs :func:`wkv6_step`; anything longer the
+    chunked :func:`wkv6`.
+    """
     dt = cfg.compute_dtype
     b, t, d = x.shape
     h = p["u"].shape[0]
     x_last = None if state is None else state["x_tm"]
     sx = _shift(x, x_last) - x
-    pf = {k_: v_.astype(dt) for k_, v_ in p.items()}
-    mixed = _ddlerp(pf, x, sx)
+    mixed = _ddlerp(p, x, sx, dt)
 
-    r = jnp.einsum("bsd,dhk->bshk", mixed["r"], pf["wr"])
-    k = jnp.einsum("bsd,dhk->bshk", mixed["k"], pf["wk"])
-    v = jnp.einsum("bsd,dhk->bshk", mixed["v"], pf["wv"])
-    g = jax.nn.silu(jnp.einsum("bsd,dhk->bshk", mixed["g"], pf["wg"]))
-    for name, arr in (("r", r), ("k", k), ("v", v)):
+    def proj(name):
+        return jnp.einsum("bsd,dhk->bshk", mixed[name], p["w" + name].astype(dt))
+
+    r, k, v = proj("r"), proj("k"), proj("v")
+    g = jax.nn.silu(proj("g"))
+    for arr in (r, k, v):
         constrain(arr, rules, ("act_batch", "seq", "heads", "head_dim"))
-    w_log = p["w_base"].astype(jnp.float32) + jnp.einsum(
-        "bsd,dr,rhk->bshk",
-        mixed["w"].astype(jnp.float32),
-        jnp.tanh(p["wa"].astype(jnp.float32)),
-        p["wb"].astype(jnp.float32),
-    )
-    w = jnp.exp(-jnp.exp(w_log))
+    w = decay(p, mixed["w"])
 
     s0 = (
         jnp.zeros((b, h, HEAD_DIM, HEAD_DIM), jnp.float32)
         if state is None
         else state["s"]
     )
-    y, s_new = wkv6(r, k, v, w.astype(r.dtype), p["u"], s0)
+    if state is not None and t == 1:
+        y, s_new = wkv6_step(r[:, 0], k[:, 0], v[:, 0], w[:, 0], p["u"], s0)
+        y = y[:, None]
+    else:
+        y, s_new = wkv6(r, k, v, w, p["u"], s0)
 
-    # per-head group norm
-    mu = jnp.mean(y.astype(jnp.float32), axis=-1, keepdims=True)
-    var = jnp.var(y.astype(jnp.float32), axis=-1, keepdims=True)
-    y = ((y - mu) * jax.lax.rsqrt(var + 1e-5)).astype(dt)
-    y = y * (1.0 + pf["ln_g"]) * g
-    out = jnp.einsum("bshk,hkd->bsd", y, pf["wo"])
+    # GroupNorm over the heads, float32.
+    mu = jnp.mean(y, axis=-1, keepdims=True)
+    var = jnp.var(y, axis=-1, keepdims=True)
+    y = (y - mu) * jax.lax.rsqrt(var + GN_EPS)
+    y = y * p["ln_x"]["w"].astype(jnp.float32) + p["ln_x"]["b"].astype(
+        jnp.float32)
+    y = y.astype(dt) * g
+    out = jnp.einsum("bshk,hkd->bsd", y, p["wo"].astype(dt))
     new_state = {"s": s_new, "x_tm": x[:, -1]}
     return constrain(out, rules, ("act_batch", "seq", "d_model")), new_state
 
@@ -190,17 +291,13 @@ def channel_mix(
 
 
 def rwkv_block(
-    p, x, cfg: ArchConfig, rules: ShardingRules, norms,
+    p, x, cfg: ArchConfig, rules: ShardingRules,
     state: Optional[Dict[str, jax.Array]] = None,
 ):
-    """One RWKV layer: x + TM(norm(x)); x + CM(norm(x)). Returns (x, state)."""
-    tm_out, st_tm = time_mix(
-        p["tm"], rms_norm(x, norms["ln1"]), cfg, rules, state
-    )
+    """One RWKV layer: x + TM(ln1(x)); x + CM(ln2(x)). Returns (x, state)."""
+    tm_out, st_tm = time_mix(p["tm"], _ln(x, p["ln1"]), cfg, rules, state)
     x = x + tm_out
-    cm_out, st_cm = channel_mix(
-        p["cm"], rms_norm(x, norms["ln2"]), cfg, rules, state
-    )
+    cm_out, st_cm = channel_mix(p["cm"], _ln(x, p["ln2"]), cfg, rules, state)
     x = x + cm_out
     return x, {**st_tm, **st_cm}
 

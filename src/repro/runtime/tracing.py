@@ -17,9 +17,13 @@ Where each span is opened:
   ``share_account``);
 * ``sched.handoff``: appending served tokens to their requests and calling
   ``on_token`` (for decode and verify, building their step records);
-* ``engine.launch`` (``kind``, ``n`` or ``rows``, ``ctx_pages``; for decode
-  ``ppb`` and ``block_fill``): one jitted program call, from the call to its
-  return (dispatch and argument transfer);
+* ``engine.launch`` (``kind``, ``n`` or ``rows``, ``ctx_pages``; for paged
+  decode ``ppb`` and ``block_fill``; for recurrent launches ``row_fill``):
+  one jitted program call, from the call to its return (dispatch and
+  argument transfer);
+* ``engine.reset`` (``slots``): the family's reset of an admitted slot
+  (``replay``), opened by the scheduler's admission (host work only: a
+  recurrent slot's state is zeroed by its first prefill launch);
 * ``engine.sync`` (``kind``): a host read that waits for a program's result;
 * ``gc`` (``generation``, ``collected``): a collection of generation 1 or 2
   (:func:`install_gc_spans`).
@@ -47,6 +51,7 @@ SPANS = (
     "sched.retire",
     "engine.launch",
     "engine.sync",
+    "engine.reset",
     "gc",
 )
 

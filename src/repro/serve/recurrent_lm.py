@@ -12,25 +12,27 @@ accounting in ``BENCH_serving.json``.
 
 Pieces:
 
-* :class:`RecurrentLM` — a deliberately minimal tied-embedding LM over the
-  real :func:`repro.models.rwkv6.rwkv_block` / :func:`repro.models.mamba
-  .mamba_fwd` blocks.  **Every** token, prefill or decode, runs through one
-  fused ``lax.scan`` program (:meth:`RecurrentLM._steps`) whose per-step
-  body is identical regardless of trip count — the property that makes
-  scheduler-served output bit-for-bit equal to a direct sequential forward
-  at the same batch shape, no matter how chunked prefill and fused decode
-  slice the token stream.  Inactive rows carry their state through
-  ``jnp.where`` untouched (bit-exact), so batch composition never leaks
-  between sequences.
+* :class:`RecurrentLM` — the published RWKV6 "Finch" model
+  (:mod:`repro.models.rwkv6`: ``ln0``, the blocks, ``ln_out``, an untied
+  head), or a tied-embedding LM over :func:`repro.models.mamba.mamba_fwd`.
+  **Every** token, prefill or decode, runs the same per-token body inside a
+  ``lax.scan`` (:meth:`RecurrentLM.prefill_chunk`,
+  :meth:`RecurrentLM.decode_chain`), identical regardless of trip count —
+  the property that makes scheduler-served output bit-for-bit equal to a
+  direct sequential forward at the same batch shape, no matter how chunked
+  prefill and fused decode slice the token stream.  Inactive rows carry
+  their state through ``jnp.where`` untouched (bit-exact), so batch
+  composition never leaks between sequences.
 * :class:`RecurrentStatePool` — the donated state pool + host bookkeeping
   (slot ownership, lengths), the recurrent analogue of
   :class:`repro.serve.kv.PagedKVCache`.
 * :class:`RecurrentFamily` — the :class:`repro.serve.family.ServableFamily`
   implementation the scheduler drives: slots are the resource unit
   (``units_for(n) == 1``), capacity is unbounded so growth/lookahead never
-  fire, eviction-replay re-prefills from a zeroed state row
-  (:meth:`RecurrentFamily.replay`, via the strided state-write op), and the
-  accounting dialect is :func:`repro.core.packing.recurrent_decode_traffic`
+  fire, eviction-replay re-prefills from a zeroed state row (the prefill
+  program zeroes each row it starts at position 0, so
+  :meth:`RecurrentFamily.replay` launches nothing), and the accounting
+  dialect is :func:`repro.core.packing.recurrent_decode_traffic`
   + :func:`repro.core.streams.recurrent_state_streams`.
 * :func:`recurrent_reference_generate` — the direct sequential forward the
   bitwise tests and the serving benchmark compare against.
@@ -52,7 +54,6 @@ from repro.core.packing import (
     recurrent_prefill_traffic,
 )
 from repro.core.streams import recurrent_state_streams
-from repro.kernels import ops as kops
 from repro.models import mamba as mamba_mod
 from repro.models import rwkv6 as rwkv_mod
 from repro.models.common import Param, init_params, rms_norm, stack_layer_defs
@@ -129,18 +130,31 @@ class RecurrentStatePool:
 
 
 class RecurrentLM:
-    """Minimal tied-embedding LM over real RWKV6 / Mamba blocks.
+    """A language model over RWKV6 or Mamba blocks, served from state slots.
 
-    Mirrors :class:`repro.serve.paged_lm.PagedLM`'s austerity (float32
-    params, no final norm, greedy-friendly) so every per-token computation
-    is row-wise — a sequence's outputs depend only on its own tokens and
-    state rows, the property the scheduler's bitwise-equivalence guarantees
-    rest on.  ``arch`` picks the block: ``'rwkv6'`` (wkv state per head) or
-    ``'mamba'`` (SSM state + conv tail); both share all pool plumbing.
+    ``arch='rwkv6'`` is the published Finch model (:mod:`repro.models.rwkv6`):
+    ``ln0`` on the embedding, the layers, ``ln_out`` and an untied head.
+    ``arch='mamba'`` keeps a tied embedding around
+    :func:`repro.models.mamba.mamba_fwd` layers.  ``params`` (the tree of
+    :meth:`param_defs`) replaces the weights drawn from ``key``.  Parameters
+    are kept in ``cfg.param_dtype``, activations in ``cfg.dtype``; state and
+    decay are float32.
+
+    Every token, prefill or decode, runs the same per-token body (embed, all
+    layers, state write-back masked by the row's ``active`` flag) inside a
+    ``lax.scan``, so a sequence's outputs depend only on its own tokens and
+    state rows, and scheduler-served output equals
+    :func:`recurrent_reference_generate` bit for bit at the same batch shape
+    however prefill and decode slice the stream.  Two programs run it:
+    :meth:`prefill_chunk` (given tokens; a row that starts its sequence reads
+    a zero state at its first token; the head once, after the scan, on each
+    row's last fed position) and :meth:`decode_chain` (the head and greedy
+    argmax every step; tokens out).  No per-step logits stack is made.
     """
 
-    def __init__(self, cfg: ArchConfig, key: jax.Array,
-                 arch: Optional[str] = None, impl: str = "pallas"):
+    def __init__(self, cfg: ArchConfig, key: Optional[jax.Array],
+                 arch: Optional[str] = None,
+                 params: Optional[Dict[str, Any]] = None):
         arch = arch or ("rwkv6" if cfg.ssm == "rwkv6" else "mamba")
         if arch not in ("rwkv6", "mamba"):
             raise ValueError(f"unknown recurrent arch: {arch!r}")
@@ -152,22 +166,25 @@ class RecurrentLM:
             raise ValueError("mamba needs ssm_conv >= 2 (a conv state tail)")
         self.cfg = cfg
         self.arch = arch
-        self.impl = impl
         self.rules = make_rules()
+        if params is None:
+            dtype = jnp.dtype(cfg.param_dtype)
+            params = init_params(self.param_defs(), key, dtype)
+        self.params = params
+
+    def param_defs(self) -> Dict[str, Any]:
+        cfg = self.cfg
         d = cfg.d_model
-        k_embed, k_layers = jax.random.split(key)
-        self.embed = (
-            jax.random.normal(k_embed, (cfg.vocab, d), jnp.float32) * 0.02
-        )
-        norm = lambda: Param((d,), ("d_model",), init="zeros")
-        if arch == "rwkv6":
-            ldefs: Dict[str, Any] = {
-                **rwkv_mod.rwkv_defs(cfg), "ln1": norm(), "ln2": norm(),
-            }
-        else:
-            ldefs = {"mamba": mamba_mod.mamba_defs(cfg), "ln": norm()}
-        self.layers = init_params(stack_layer_defs(ldefs, cfg.n_layers),
-                                  k_layers)
+        if self.arch == "rwkv6":
+            return {**rwkv_mod.rwkv_lm_defs(cfg), "layers": stack_layer_defs(
+                rwkv_mod.rwkv_defs(cfg), cfg.n_layers)}
+        return {
+            "embed": Param((cfg.vocab, d), ("vocab", "d_model"), scale=0.02),
+            "layers": stack_layer_defs(
+                {"mamba": mamba_mod.mamba_defs(cfg),
+                 "ln": Param((d,), ("d_model",), init="zeros")},
+                cfg.n_layers),
+        }
 
     def bind(self, pool: RecurrentStatePool) -> "RecurrentFamily":
         """Wrap this model + ``pool`` as the scheduler-facing family."""
@@ -194,82 +211,117 @@ class RecurrentLM:
             "conv": ((cfg.ssm_conv - 1, d * 2), dt),
         }
 
+    # -- the per-token body ---------------------------------------------------
+
+    def _embed(self, params, tok):
+        """(B,) token ids → (B, D) activations."""
+        dt = self.cfg.compute_dtype
+        if self.arch == "rwkv6":
+            return rwkv_mod.rwkv_embed(params, tok, dt)
+        return params["embed"][tok].astype(dt)
+
+    def _head(self, params, x):
+        """(B, D) activations → (B, vocab) float32 logits."""
+        if self.arch == "rwkv6":
+            return rwkv_mod.rwkv_logits(params, x)
+        return jnp.dot(x, params["embed"].T.astype(x.dtype),
+                       preferred_element_type=jnp.float32)
+
     def _layer_step(self, p_l, x, st_l):
         """One layer over a (B, 1, D) slice; returns (x, new_layer_state)."""
         if self.arch == "rwkv6":
-            return rwkv_mod.rwkv_block(p_l, x, self.cfg, self.rules, p_l, st_l)
+            return rwkv_mod.rwkv_block(p_l, x, self.cfg, self.rules, st_l)
         out, ns = mamba_mod.mamba_fwd(
             p_l["mamba"], rms_norm(x, p_l["ln"]), self.cfg, self.rules,
             st_l, chunk=1,
         )
         return x + out, ns
 
+    def _token(self, params, pool, tok, act, zero=None):
+        """One token for every row: (B,) ids, (B,) bool → ((B, D) output
+        activations, pool with the active rows' state advanced).  Rows
+        flagged in ``zero`` (B,) read a zero state instead of their pool
+        rows."""
+        x = self._embed(params, tok)[:, None, :]
+        new_states = []
+        for l in range(self.cfg.n_layers):
+            p_l = jax.tree.map(lambda a: a[l], params["layers"])
+            st_l = {k: pool[k][l] for k in pool}
+            if zero is not None:
+                st_l = {k: jnp.where(
+                    zero.reshape((-1,) + (1,) * (v.ndim - 1)),
+                    jnp.zeros((), v.dtype), v) for k, v in st_l.items()}
+            x, ns = self._layer_step(p_l, x, st_l)
+            new_states.append(ns)
+        new_pool = {}
+        for k in pool:
+            stacked = jnp.stack([ns[k] for ns in new_states])
+            mask = act.reshape((1, -1) + (1,) * (stacked.ndim - 2))
+            new_pool[k] = jnp.where(mask, stacked, pool[k])
+        return x[:, 0], new_pool
+
+    # -- programs ------------------------------------------------------------
+
     @functools.cached_property
-    def _steps(self):
-        """The one fused token-step program (prefill *and* decode).
-
-        ``lax.scan`` over ``n`` per-token steps; the body embeds the step's
-        token (given for prefill, the carried greedy argmax for decode),
-        runs every layer, and masks state write-back by ``active`` so
-        inactive rows are bit-exact no-ops.  One body → one program shape
-        per ``n``; scan bodies compile identically for every trip count, so
-        any chunking of a token stream produces identical bits.
-        """
-        cfg, vocab = self.cfg, self.cfg.vocab
-        n_layers = cfg.n_layers
-
-        def recurrent_lm_steps(layers, embed, pool, cur, toks, use_input,
-                               active):
-            # pool: name → (L, B, *row); cur (B,) i32 carried token;
-            # toks (n, B) i32; use_input (n,) bool; active (n, B) bool.
+    def _prefill(self):
+        def recurrent_lm_prefill(params, pool, toks, active, last, zero):
+            # toks, active, last, zero: (C, B); last marks each row's last
+            # fed position, zero the first token of a row that starts its
+            # sequence.  Returns the pool and (B, vocab) logits at last.
             def body(carry, xs):
+                pool_c, x_last = carry
+                tok, act, is_last, z = xs
+                x, pool_c = self._token(params, pool_c, tok, act, z)
+                return (pool_c, jnp.where(is_last[:, None], x, x_last)), None
+
+            x0 = jnp.zeros((toks.shape[1], self.cfg.d_model),
+                           self.cfg.compute_dtype)
+            (pool, x_last), _ = jax.lax.scan(body, (pool, x0),
+                                             (toks, active, last, zero))
+            return pool, self._head(params, x_last)
+
+        return jax.jit(recurrent_lm_prefill, donate_argnums=(1,))
+
+    @functools.cached_property
+    def _decode(self):
+        def recurrent_lm_decode(params, pool, cur, active, n):
+            # cur (B,) i32 carried token; active (B,) bool.  Returns the
+            # pool, the carried tokens and (n, B) tokens.
+            def body(carry, _):
                 pool_c, cur_c = carry
-                tok_in, use_in, act = xs
-                tok = jnp.where(use_in, tok_in, cur_c)
-                x = embed[tok][:, None, :].astype(cfg.compute_dtype)
-                new_states = []
-                for l in range(n_layers):
-                    p_l = jax.tree.map(lambda a: a[l], layers)
-                    st_l = {k: pool_c[k][l] for k in pool_c}
-                    x, ns = self._layer_step(p_l, x, st_l)
-                    new_states.append(ns)
-                new_pool = {
-                    k: jnp.stack([ns[k] for ns in new_states])
-                    for k in pool_c
-                }
-                new_pool = {
-                    k: jnp.where(
-                        act.reshape((1, -1) + (1,) * (new_pool[k].ndim - 2)),
-                        new_pool[k], pool_c[k],
-                    )
-                    for k in pool_c
-                }
-                logits = x[:, 0].astype(jnp.float32) @ embed.T  # (B, V)
-                nxt = jnp.argmax(logits[:, :vocab], axis=-1).astype(jnp.int32)
-                cur_new = jnp.where(act, nxt, cur_c)
-                return (new_pool, cur_new), (logits, cur_new)
+                x, pool_c = self._token(params, pool_c, cur_c, active)
+                nxt = jnp.argmax(self._head(params, x),
+                                 axis=-1).astype(jnp.int32)
+                cur_c = jnp.where(active, nxt, cur_c)
+                return (pool_c, cur_c), cur_c
 
-            (pool_f, cur_f), (logits, toks_out) = jax.lax.scan(
-                body, (pool, cur), (toks, use_input, active)
-            )
-            return pool_f, cur_f, logits, toks_out
+            (pool, cur), toks = jax.lax.scan(body, (pool, cur), None,
+                                             length=n)
+            return pool, cur, toks
 
-        return jax.jit(recurrent_lm_steps, donate_argnums=(2,))
+        return jax.jit(recurrent_lm_decode, donate_argnums=(1,),
+                       static_argnums=(4,))
 
-    def prefill_chunk(self, tensors, toks, active):
-        """Feed given tokens: toks (C, B) i32, active (C, B) bool.
-
-        Returns (new tensors, per-step logits (C, B, vocab) on device).
-        """
+    def prefill_chunk(self, tensors, toks, active, rows, fresh=None):
+        """Feed given tokens: toks, active (C, B).  Slots flagged in
+        ``fresh`` (B,) start their sequence here: their state is zero at
+        their first token, whatever their pool rows hold.  Returns (new
+        tensors, (len(rows), vocab) logits on the device): those of the
+        last active position of each slot in ``rows``."""
+        toks = np.asarray(toks, np.int32)
+        active = np.asarray(active, bool)
         c, b = toks.shape
-        cur = np.zeros((b,), np.int32)
-        use = np.ones((c,), bool)
-        rows = int(np.asarray(active).any(axis=0).sum())
-        with span("engine.launch", kind="prefill", rows=rows), \
-                _donation_noop_ok():
-            tensors, _, logits, _ = self._steps(
-                self.layers, self.embed, tensors, cur, toks, use, active
-            )
+        last = np.zeros((c, b), bool)
+        fed = active.sum(axis=0)
+        last[np.maximum(fed - 1, 0), np.arange(b)] = fed > 0
+        zero = np.zeros((c, b), bool)
+        if fresh is not None:
+            zero[0] = fresh
+        with span("engine.launch", kind="prefill", rows=int((fed > 0).sum()),
+                  row_fill=float(active.mean())), _donation_noop_ok():
+            tensors, logits = self._prefill(self.params, tensors, toks,
+                                            active, last, zero)
+            logits = _take_rows(logits, np.asarray(rows, np.int32))
         return tensors, logits
 
     def decode_chain(self, tensors, tokens, active, n: int):
@@ -277,27 +329,30 @@ class RecurrentLM:
 
         Power-of-two chaining (like ``PagedLM.decode_upto``) bounds the
         compiled-program count to O(log n); scan trip-count invariance makes
-        the chain bit-identical to ``n`` single steps.  Returns
-        (new tensors, (n, B) host tokens).
+        the chain bit-identical to ``n`` single steps.  Returns (new
+        tensors, (n, B) host tokens).
         """
-        b = active.shape[0]
+        active = np.asarray(active, bool)
         cur = np.asarray(tokens, np.int32)
+        fill = float(active.mean())
         outs: List[np.ndarray] = []
         rem = int(n)
         while rem:
             m = 1 << (rem.bit_length() - 1)
-            toks = np.zeros((m, b), np.int32)
-            use = np.zeros((m,), bool)
-            act = np.broadcast_to(np.asarray(active, bool), (m, b))
-            with span("engine.launch", kind="decode", n=m), \
+            with span("engine.launch", kind="decode", n=m, row_fill=fill), \
                     _donation_noop_ok():
-                tensors, cur, _, toks_out = self._steps(
-                    self.layers, self.embed, tensors, cur, toks, use, act
+                tensors, cur, toks_out = self._decode(
+                    self.params, tensors, cur, active, m
                 )
             with span("engine.sync", kind="decode"):
                 outs.append(np.asarray(toks_out))
             rem -= m
         return tensors, np.concatenate(outs, axis=0)
+
+
+@jax.jit
+def _take_rows(logits, rows):
+    return logits[rows]
 
 
 class RecurrentFamily(ServableFamily):
@@ -307,10 +362,9 @@ class RecurrentFamily(ServableFamily):
     unit regardless of length (``units_for``), capacity never binds
     (``token_capacity`` is unbounded), and ``grow``/``trim`` are statically
     idle.  Eviction-replay is the same protocol the paged family uses —
-    release the unit, re-admit, re-prefill — except the device half of the
-    reset is explicit: :meth:`replay` zeroes the slot's state rows through
-    the strided scatter op, since donated pools recycle rows across
-    occupants.
+    release the unit, re-admit, re-prefill.  Donated pools recycle rows
+    across occupants, so a row's prefill from position 0 reads a zero state
+    instead of its stale pool rows (``fresh`` in :meth:`prefill_batch`).
     """
 
     def __init__(self, model: RecurrentLM, pool: RecurrentStatePool):
@@ -382,19 +436,15 @@ class RecurrentFamily(ServableFamily):
         self.pool.lengths_host[slot] = 0
 
     def release(self, slot: int) -> None:
-        # Host bookkeeping only; the stale device rows are zeroed by the
-        # next occupant's replay() at admission.
+        # Host bookkeeping only; the next occupant's first prefill chunk
+        # reads a zero state in place of the stale device rows.
         self.pool.owned[slot] = False
         self.pool.lengths_host[slot] = 0
 
     def replay(self, slot: int) -> None:
-        """Zero the slot's state rows (strided scatter) — fresh-prefill
-        semantics for recycled donated pools; called at every admission."""
-        for name, t in self.pool.tensors.items():
-            zeros = jnp.zeros((t.shape[0],) + t.shape[2:], t.dtype)
-            self.pool.tensors[name] = kops.recurrent_state_write(
-                t, int(slot), zeros, impl=self.model.impl
-            )
+        """Fresh-prefill semantics at every admission: the slot's length
+        restarts at 0, and its prefill from position 0 zeroes its state on
+        the device (:meth:`prefill_batch`), so nothing is launched here."""
         self.pool.lengths_host[slot] = 0
 
     # -- compute ------------------------------------------------------------
@@ -405,17 +455,17 @@ class RecurrentFamily(ServableFamily):
         n_rows, c = tokens.shape
         toks = np.zeros((c, b), np.int32)
         act = np.zeros((c, b), bool)
+        fresh = np.zeros((b,), bool)
         for i in range(n_rows):
             ci, si = int(counts[i]), int(slots[i])
             toks[:ci, si] = tokens[i, :ci]
             act[:ci, si] = True
+            fresh[si] |= ci > 0 and int(starts[i]) == 0
         self.pool.tensors, logits = self.model.prefill_chunk(
-            self.pool.tensors, toks, act
+            self.pool.tensors, toks, act, slots, fresh
         )
         with span("engine.sync", kind="prefill"):
-            lg = np.asarray(logits)  # (C, B, vocab)
-        out = lg[np.maximum(np.asarray(counts, np.int64) - 1, 0),
-                 np.asarray(slots, np.int64)]
+            out = np.asarray(logits)  # (rows, vocab)
         # Scalar loop: padding rows alias slot 0 with count 0, and fancy
         # `+=` drops duplicate-index updates instead of accumulating them.
         for i in range(n_rows):
@@ -505,7 +555,7 @@ def recurrent_reference_generate(
         raise ValueError("empty prompt")
     tensors = pool.tensors
     maxp = max(plens)
-    last = np.zeros((len(prompts), model.cfg.vocab), np.float32)
+    out: List[List[int]] = [[] for _ in prompts]
     pos = 0
     while pos < maxp:
         c = min(chunk, maxp - pos)
@@ -516,13 +566,14 @@ def recurrent_reference_generate(
             if ci:
                 toks[:ci, i] = p[pos:pos + ci]
                 act[:ci, i] = True
-        tensors, logits = model.prefill_chunk(tensors, toks, act)
+        tensors, logits = model.prefill_chunk(tensors, toks, act,
+                                              np.arange(len(prompts)),
+                                              act[0] if pos == 0 else None)
         lg = np.asarray(logits)
         for i in range(len(prompts)):
             if pos < plens[i] <= pos + c:
-                last[i] = lg[plens[i] - pos - 1, i, :model.cfg.vocab]
+                out[i].append(int(np.argmax(lg[i])))
         pos += c
-    out = [[int(np.argmax(last[i]))] for i in range(len(prompts))]
     if max_new > 1:
         tokens = np.zeros((b,), np.int32)
         active = np.zeros((b,), bool)

@@ -936,6 +936,16 @@ class Scheduler:
                     need,
                     keep=self.prefix_index.prefix_keys(r.prompt, len(shared)),
                 )
+            if self._effective_free() < need and shared:
+                # A page-aligned match costs a copy-on-write unit on top of
+                # the retained chain it keeps, so it can outgrow a pool that
+                # a private prefill fits: drop the chain and prefill
+                # privately rather than wait for units that never free.
+                plain = self.family.units_for(
+                    min(r.prompt_len + 1, self._max_kv(r)))
+                self._drop_retained(plain)
+                if self._effective_free() >= plain:
+                    shared, tail_start, cow_extra, need = [], 0, 0, plain
             if self._effective_free() < need:
                 return
             self.queue.pop(0)
@@ -955,10 +965,11 @@ class Scheduler:
                     r.slot, tail_start, tail_start
                 )
             # Reset the slot to fresh-prefill state: a no-op for paged
-            # families (new pages are empty), a state-row zero for
-            # recurrent ones — the half of eviction-replay that lives in
-            # device state rather than in the token bookkeeping.
-            self.family.replay(r.slot)
+            # families (new pages are empty), a length restart for
+            # recurrent ones, whose first prefill launch reads a zero
+            # state in place of the slot's stale rows.
+            with span("engine.reset", slots=1):
+                self.family.replay(r.slot)
             if shared:
                 # Replay after eviction walks this same path: the lookup
                 # re-derives the mappings, so re-admission reuses the pages

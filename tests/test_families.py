@@ -36,7 +36,6 @@ from repro.core.streams import (
     StridedStream,
     recurrent_state_streams,
 )
-from repro.kernels import ops
 from repro.serve import (
     OutOfPages,
     PagedKVCache,
@@ -83,7 +82,7 @@ def _drive(sched, requests, max_steps=500):
 def test_scheduled_matches_direct_forward(arch, chunk):
     cfg = RWKV_CFG if arch == "rwkv6" else DENSE_CFG
     rng = np.random.default_rng(chunk + (0 if arch == "rwkv6" else 100))
-    model = RecurrentLM(cfg, jax.random.PRNGKey(0), arch=arch, impl="ref")
+    model = RecurrentLM(cfg, jax.random.PRNGKey(0), arch=arch)
     prompts = _prompts(rng, cfg.vocab, (8, 7, 12))
     max_new = 8
     want = recurrent_reference_generate(model, model.init_pool(3), prompts,
@@ -101,7 +100,7 @@ def test_scheduled_matches_direct_forward_ragged_arrivals():
     inactive slots bit-exact while other rows prefill/decode."""
     cfg = RWKV_CFG
     rng = np.random.default_rng(7)
-    model = RecurrentLM(cfg, jax.random.PRNGKey(0), impl="ref")
+    model = RecurrentLM(cfg, jax.random.PRNGKey(0))
     prompts = _prompts(rng, cfg.vocab, (10, 3, 6))
     max_new = 6
     want = recurrent_reference_generate(model, model.init_pool(3), prompts,
@@ -132,7 +131,7 @@ def test_eviction_replay_round_trip(arch):
     state row and reproduces the fault-free tokens exactly."""
     cfg = RWKV_CFG if arch == "rwkv6" else DENSE_CFG
     rng = np.random.default_rng(11)
-    model = RecurrentLM(cfg, jax.random.PRNGKey(0), arch=arch, impl="ref")
+    model = RecurrentLM(cfg, jax.random.PRNGKey(0), arch=arch)
     prompts = _prompts(rng, cfg.vocab, (9, 6))
     max_new = 8
     want = recurrent_reference_generate(model, model.init_pool(2), prompts,
@@ -165,7 +164,7 @@ def test_out_of_slots_staggers_admission():
     """More requests than state slots: admission staggers, everyone drains."""
     cfg = RWKV_CFG
     rng = np.random.default_rng(13)
-    model = RecurrentLM(cfg, jax.random.PRNGKey(0), impl="ref")
+    model = RecurrentLM(cfg, jax.random.PRNGKey(0))
     prompts = _prompts(rng, cfg.vocab, (8, 7, 12, 5))
     sched = Scheduler(model, model.init_pool(2), chunk=4)
     reqs = [Request(rid=i, prompt=p, max_new=5)
@@ -177,13 +176,13 @@ def test_out_of_slots_staggers_admission():
 
 
 def test_prefix_sharing_rejected_for_recurrent():
-    model = RecurrentLM(RWKV_CFG, jax.random.PRNGKey(0), impl="ref")
+    model = RecurrentLM(RWKV_CFG, jax.random.PRNGKey(0))
     with pytest.raises(ValueError, match="refcounted"):
         Scheduler(model, model.init_pool(2), prefix_sharing=True)
 
 
 def test_state_pool_exhaustion_raises_typed():
-    model = RecurrentLM(RWKV_CFG, jax.random.PRNGKey(0), impl="ref")
+    model = RecurrentLM(RWKV_CFG, jax.random.PRNGKey(0))
     fam = model.bind(model.init_pool(2))
     fam.alloc_state(0, 1)
     fam.alloc_state(1, 1)
@@ -192,6 +191,57 @@ def test_state_pool_exhaustion_raises_typed():
     fam.release(0)
     fam.alloc_state(0, 1)  # released slot is reusable
     assert fam.free_units == 0
+
+
+def test_prefill_hands_the_host_only_the_rows_logits():
+    """A prefill call returns one logits row per call row, of the row's
+    last fed position, chosen on the device: (rows, vocab), not a per-step
+    stack."""
+    model = RecurrentLM(RWKV_CFG, jax.random.PRNGKey(0))
+    fam = model.bind(model.init_pool(4))
+    rng = np.random.default_rng(3)
+    tokens = rng.integers(0, RWKV_CFG.vocab, (2, 8)).astype(np.int32)
+    counts, slots = np.array([8, 3], np.int32), np.array([2, 0], np.int32)
+    seen = []
+    chunk = model.prefill_chunk
+
+    def prefill_chunk(*args):
+        tensors, logits = chunk(*args)
+        seen.append(logits)
+        return tensors, logits
+
+    model.prefill_chunk = prefill_chunk
+    out = fam.prefill_batch(tokens, counts, slots, np.zeros(2, np.int32))
+    assert isinstance(out, np.ndarray) and out.shape == (2, RWKV_CFG.vocab)
+    assert [x.shape for x in seen] == [(2, RWKV_CFG.vocab)]
+    # Each row's logits are those of its last token: feeding that row alone
+    # gives the same numbers.
+    for i in range(2):
+        alone = model.bind(model.init_pool(4))
+        one = alone.prefill_batch(tokens[i:i + 1], counts[i:i + 1],
+                                  slots[i:i + 1], np.zeros(1, np.int32))
+        np.testing.assert_allclose(one[0], out[i], rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("arch", ["rwkv6", "mamba"])
+def test_no_program_output_is_a_per_step_logits_stack(arch):
+    cfg = RWKV_CFG if arch == "rwkv6" else DENSE_CFG
+    model = RecurrentLM(cfg, jax.random.PRNGKey(0), arch=arch)
+    b, c, v = 4, 8, cfg.vocab
+    pool = model.init_pool(b).tensors
+    toks, mask = np.zeros((c, b), np.int32), np.zeros((c, b), bool)
+    outs = {"prefill": model._prefill.lower(model.params, pool, toks, mask,
+                                            mask, mask).out_info}
+    for n in (1, 4, 8):
+        outs[f"decode{n}"] = model._decode.lower(
+            model.params, pool, np.zeros(b, np.int32), np.zeros(b, bool),
+            n).out_info
+    for name, info in outs.items():
+        shapes = [x.shape for x in jax.tree.leaves(info)]
+        assert not any(len(s) == 3 and s[-1] == v for s in shapes), (name,
+                                                                     shapes)
+        # Prefill hands out the rows' last logits; decode only its tokens.
+        assert shapes.count((b, v)) == (name == "prefill"), (name, shapes)
 
 
 # ---------------------------------------------------------------------------
@@ -205,7 +255,7 @@ def test_mixed_families_interleaved():
     both match their family's reference generation bit-for-bit."""
     rng = np.random.default_rng(17)
     pm = PagedLM(DENSE_CFG, jax.random.PRNGKey(0), impl="ref")
-    rm = RecurrentLM(RWKV_CFG, jax.random.PRNGKey(0), impl="ref")
+    rm = RecurrentLM(RWKV_CFG, jax.random.PRNGKey(0))
     p_prompts = _prompts(rng, DENSE_CFG.vocab, (8, 7, 12))
     r_prompts = _prompts(rng, RWKV_CFG.vocab, (6, 11, 4))
     max_new = 6
@@ -252,38 +302,15 @@ def test_mixed_families_interleaved():
 
 
 # ---------------------------------------------------------------------------
-# Strided state read/write ops vs ref oracles
+# Fresh-prefill state reset
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("shape", [(2, 4, 2, 64, 64), (3, 4, 128), (2, 3, 3, 256)])
-def test_recurrent_state_ops_match_ref(shape):
-    rng = np.random.default_rng(23)
-    pool = jnp.asarray(rng.normal(size=shape), jnp.float32)
-    l, b = shape[:2]
-    for slot in range(b):
-        got = ops.recurrent_state_read(pool, slot)
-        want = ops.recurrent_state_read(pool, slot, impl="ref")
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-        assert got.shape == (l,) + shape[2:]
-    value = jnp.asarray(rng.normal(size=(l,) + shape[2:]), jnp.float32)
-    for slot in range(b):
-        got = ops.recurrent_state_write(pool, slot, value)
-        want = ops.recurrent_state_write(pool, slot, value, impl="ref")
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-        # Non-target rows are untouched bitwise.
-        mask = np.ones(b, bool)
-        mask[slot] = False
-        np.testing.assert_array_equal(
-            np.asarray(got)[:, mask], np.asarray(pool)[:, mask]
-        )
-        # Target rows hold the new value.
-        np.testing.assert_array_equal(np.asarray(got)[:, slot],
-                                      np.asarray(value))
-
-
 def test_replay_zeroes_only_target_slot():
-    model = RecurrentLM(RWKV_CFG, jax.random.PRNGKey(0), impl="ref")
+    """Replay launches nothing; the slot's next prefill from position 0
+    reads a zero state, as on a fresh pool, and leaves every other slot's
+    state rows as they were."""
+    model = RecurrentLM(RWKV_CFG, jax.random.PRNGKey(0))
     fam = model.bind(model.init_pool(3))
     # Dirty all state rows, then replay slot 1.
     fam.pool.tensors = {
@@ -292,11 +319,28 @@ def test_replay_zeroes_only_target_slot():
     before = {k: np.asarray(t) for k, t in fam.pool.tensors.items()}
     fam.replay(1)
     for k, t in fam.pool.tensors.items():
+        np.testing.assert_array_equal(np.asarray(t), before[k])
+    toks = np.arange(1, 6, dtype=np.int32)[None]
+    one = np.ones(1, np.int32)
+    got = fam.prefill_batch(toks, 5 * one, one, 0 * one)
+    clean = model.bind(model.init_pool(3))
+    want = clean.prefill_batch(toks, 5 * one, one, 0 * one)
+    np.testing.assert_array_equal(got, want)
+    for k, t in fam.pool.tensors.items():
         a = np.asarray(t)
-        assert (a[:, 1] == 0).all(), f"{k}: slot 1 not zeroed"
+        np.testing.assert_array_equal(a[:, 1], np.asarray(
+            clean.pool.tensors[k])[:, 1])
         mask = np.ones(a.shape[1], bool)
         mask[1] = False
         np.testing.assert_array_equal(a[:, mask], before[k][:, mask])
+    # A later chunk of the same row (start > 0) carries its state on.
+    fam.prefill_batch(toks, 5 * one, one, 5 * one)
+    clean.prefill_batch(toks, 5 * one, one, 5 * one)
+    for k, t in fam.pool.tensors.items():
+        np.testing.assert_array_equal(np.asarray(t)[:, 1], np.asarray(
+            clean.pool.tensors[k])[:, 1])
+        assert not np.array_equal(np.asarray(t)[:, 1],
+                                  np.zeros_like(np.asarray(t)[:, 1]))
 
 
 # ---------------------------------------------------------------------------
@@ -334,7 +378,7 @@ def test_recurrent_traffic_accounting():
 
 def test_scheduler_records_strided_pack_efficiency():
     rng = np.random.default_rng(29)
-    model = RecurrentLM(RWKV_CFG, jax.random.PRNGKey(0), impl="ref")
+    model = RecurrentLM(RWKV_CFG, jax.random.PRNGKey(0))
     # 4 slots, 3 requests: occupancy < 1, so BASE pays for the idle row.
     sched = Scheduler(model, model.init_pool(4), chunk=4)
     reqs = [Request(rid=i, prompt=p, max_new=5)

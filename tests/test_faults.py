@@ -334,7 +334,7 @@ def test_chaos_recurrent_family_seed_matrix():
     from repro.serve import RecurrentLM
 
     rcfg = smoke_config("rwkv6-3b")
-    rmodel = RecurrentLM(rcfg, jax.random.PRNGKey(0), impl="ref")
+    rmodel = RecurrentLM(rcfg, jax.random.PRNGKey(0))
     for seed in range(SEED_BASE, SEED_BASE + SEEDS_PER_CASE):
         rng = np.random.default_rng(1000 + seed)
         prompts = [rng.integers(0, rcfg.vocab, int(rng.integers(2, 10)))

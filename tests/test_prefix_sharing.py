@@ -19,7 +19,8 @@ tests/_hypothesis_stub.py (CI runs both, the stub leg with
 ``REPRO_STUB_MAX_EXAMPLES=25``).  Alongside the fuzzer sit deterministic
 regressions for the sharp edges: copy-on-write on fully page-aligned
 matches, ``trim`` on shared pages (decrement, never free), retained-prefix
-reuse after retirement, and in-flight admission deferral.
+reuse after retirement, in-flight admission deferral, and an aligned match
+that outgrows the pool.
 """
 import jax
 import numpy as np
@@ -277,6 +278,28 @@ def test_retained_prefix_reused_after_retirement():
     second = drive(sched, [Request(rid=1, prompt=prompt.copy(), max_new=3)])
     assert sched.stats.prefill_tokens_saved >= 2 * PAGE
     assert second[1] == first[0]  # same prompt, same tokens
+
+
+def test_aligned_match_larger_than_the_pool_prefills_privately():
+    """A page-aligned match keeps its retained pages and adds a
+    copy-on-write unit, which can exceed a pool that a private prefill
+    fits: admission then drops the chain instead of stalling."""
+    model = MODELS["fp32"]
+    rng = np.random.default_rng(13)
+    prompt = rng.integers(0, CFG.vocab, 2 * PAGE).astype(np.int32)
+    longer = np.concatenate([prompt, [7]]).astype(np.int32)
+
+    cache = PagedKVCache.create(CFG, batch=2, max_len=MAX_LEN, page=PAGE,
+                                pool_pages=3)
+    sched = Scheduler(model, cache, chunk=3, prefix_sharing=True)
+    drive(sched, [Request(rid=0, prompt=longer, max_new=1)])
+    assert len(sched.prefix_index.entries) == 2  # retained: 1 unit free
+    out = drive(sched, [Request(rid=1, prompt=prompt, max_new=2)])
+
+    plain = Scheduler(model, PagedKVCache.create(
+        CFG, batch=2, max_len=MAX_LEN, page=PAGE, pool_pages=3), chunk=3)
+    assert out[1] == drive(plain, [Request(rid=1, prompt=prompt,
+                                           max_new=2)])[1]
 
 
 def test_concurrent_identical_prompts_share_via_deferral():

@@ -2,8 +2,9 @@
 
 The TPU compiler is installed even where no TPU is: these tests describe a
 ``v5e:2x2`` topology, lower the serving kernels with ``interpret=False`` at
-the widths ``chip_smoke.py`` serves (yi-6b: 32 q / 4 kv heads of 128; the
-rwkv6-3b state rows), and compile them for its first chip.  They catch what
+the widths ``chip_smoke.py`` serves (yi-6b: 32 q / 4 kv heads of 128), and
+the recurrent programs at the rwkv6-3b benchmark cell's, and compile them
+for its first chip.  They catch what
 interpret mode cannot — a block or DMA slice the tiling refuses, too much
 SMEM or VMEM — at no chip time.  Nothing runs, so nothing here says
 anything about results or speed.
@@ -12,6 +13,7 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 every test file.
 """
+import json
 import os
 import re
 
@@ -21,6 +23,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 import chip_smoke
+from bench.families import rwkv6 as rwkv_bench
 from bench.trace import is_pallas, scalar_operands
 from repro.configs import get_config
 from repro.kernels import ops
@@ -30,6 +33,7 @@ from repro.kernels.paged_prefill import paged_prefill_attention_kernel
 from repro.kernels.paged_verify import paged_verify_attention_kernel
 from repro.serve import PagedKVCache, PagedLM, RecurrentLM
 
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 H, KVH, HD = 32, 4, 128
 PAGE, BATCH, MAX_LEN = chip_smoke.PAGE, chip_smoke.BATCH, chip_smoke.MAX_LEN
 N_PAGES = MAX_LEN // PAGE
@@ -198,26 +202,42 @@ def test_int8_kv_writes_compile(spec, on_tpu, op, launches):
 
 
 # ---------------------------------------------------------------------------
-# Recurrent state rows (rwkv6-3b widths)
+# The recurrent programs at the rwkv6-3b cell's widths
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("op", ["read", "write"])
-def test_recurrent_state_ops_compile(spec, on_tpu, op):
-    cfg = get_config("rwkv6-3b")
-    pool = jax.eval_shape(
-        lambda k: RecurrentLM(cfg, k).init_pool(BATCH).tensors,
-        jax.random.PRNGKey(0),
-    )
-    assert {t.shape[2:] for t in pool.values()} == {(40, 64, 64), (2560,)}
-    for t in pool.values():
-        if op == "read":
-            _compile(lambda p: ops.recurrent_state_read(p, 3),
-                     spec(t.shape, t.dtype))
-        else:
-            value = spec((t.shape[0],) + t.shape[2:], t.dtype)
-            _compile(lambda p, v: ops.recurrent_state_write(p, 3, v),
-                     spec(t.shape, t.dtype), value)
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_recurrent_programs_take_the_state_pool_in_place(spec, program):
+    """The benchmark's 4-layer rwkv6-3b model over 128 slots, chunk 64: each
+    program compiles for the chip, and no instruction copies a whole state
+    pool (a new row's state is zeroed as the prefill reads it, not by a
+    pass over the pool)."""
+    with open(os.path.join(ROOT, "bench", "configs", "rwkv6-3b-l4.json")) as f:
+        cell = json.load(f)
+    cfg = rwkv_bench.arch(cell)
+    key = jax.random.PRNGKey(0)
+    params = jax.eval_shape(lambda k: RecurrentLM(cfg, k).params, key)
+    model = RecurrentLM(cfg, None, params=_on_chip(spec, params))
+    batch, chunk = cell["slots"], cell["prefill_chunk"]
+    pool = _on_chip(spec, jax.eval_shape(
+        lambda: model.init_pool(batch).tensors))
+    if program == "prefill":
+        mask = spec((chunk, batch), jnp.bool_)
+        lowered = model._prefill.lower(model.params, pool,
+                                       spec((chunk, batch), jnp.int32),
+                                       mask, mask, mask)
+    else:
+        lowered = model._decode.lower(model.params, pool,
+                                      spec((batch,), jnp.int32),
+                                      spec((batch,), jnp.bool_), 1)
+    text = lowered.compile().as_text()
+    shapes = {f"{t.dtype.name}[{','.join(map(str, t.shape))}]"
+              .replace("float32", "f32").replace("bfloat16", "bf16")
+              for t in pool.values()}
+    assert "f32[4,128,40,64,64]" in shapes
+    copied = set(re.findall(r"= (\w+\[[\d,]*\])\{[^}]*\} copy(?:-start)?\(",
+                            text))
+    assert not copied & shapes, copied & shapes
 
 
 # ---------------------------------------------------------------------------

@@ -49,6 +49,7 @@ PARENTS = {
     "sched.handoff": {"sched.prefill", "sched.decode"},
     "engine.launch": {"sched.prefill", "sched.decode"},
     "engine.sync": {"sched.prefill", "sched.decode"},
+    "engine.reset": {"sched.admit"},
 }
 
 
@@ -87,7 +88,7 @@ def _paged(spec_k):
 
 def _recurrent(spec_k):
     cfg = smoke_config("rwkv6-3b")
-    model = RecurrentLM(cfg, jax.random.PRNGKey(0), impl="ref")
+    model = RecurrentLM(cfg, jax.random.PRNGKey(0))
     return cfg, Scheduler(model, model.init_pool(2), chunk=4)
 
 
@@ -148,6 +149,41 @@ def test_spans_of_a_served_run(tmp_path, make, spec_k):
     assert kinds == {r.kind for r in records}
     if make is _paged and spec_k == 1:
         assert kinds == {"share", "prefill", "decode"}
+
+
+@pytest.mark.parametrize("make", [_paged, _recurrent],
+                         ids=["paged", "recurrent"])
+def test_each_admission_opens_one_reset_span(tmp_path, make):
+    sched, reqs = _serve(make, 1)
+    resets = _named(_profile(tmp_path, sched.run), "engine.reset")
+    assert len(resets) == sched._admit_counter == len(reqs)
+    assert all(sp[3]["slots"] == 1 for sp in resets)
+
+
+def test_recurrent_launches_carry_their_row_fill(tmp_path):
+    """Each recurrent ``engine.launch`` carries ``row_fill``: the active
+    row-steps of the launch over batch x steps, from the host masks."""
+    sched, _ = _serve(_recurrent, 1)
+    fam = sched.family
+    want = []
+    prefill, decode = fam.prefill_batch, fam.decode_steps
+
+    def prefill_batch(tokens, counts, slots, starts):
+        want.append(("prefill", counts.sum() / (fam.batch * tokens.shape[1])))
+        return prefill(tokens, counts, slots, starts)
+
+    def decode_steps(tokens, active, n):
+        # One launch per power of two in n, each with the same rows.
+        want.extend([("decode", active.sum() / fam.batch)]
+                    * bin(int(n)).count("1"))
+        return decode(tokens, active, n)
+
+    fam.prefill_batch, fam.decode_steps = prefill_batch, decode_steps
+    launches = _named(_profile(tmp_path, sched.run), "engine.launch")
+    got = [(sp[3]["kind"], sp[3]["row_fill"]) for sp in launches]
+    assert [k for k, _ in got] == [k for k, _ in want]
+    assert [f for _, f in got] == pytest.approx([f for _, f in want])
+    assert any(0 < f < 1 for _, f in got)
 
 
 #: (page, KV heads, head dim, table width) of the longctx cell, of
