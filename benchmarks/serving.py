@@ -30,8 +30,8 @@ through the very same scheduler via the :class:`repro.serve.ServableFamily`
 protocol: fixed-size state slots instead of growing page chains, and
 strided-burst PACK/BASE accounting (no index-bus term — the stride is the
 descriptor) instead of indirect page walks.  Each row asserts the scheduled
-outputs are bit-for-bit the direct sequential forward
-(:func:`repro.serve.recurrent_reference_generate` at the same batch shape)
+tokens equal the direct forward fed the same prompt chunks
+(:func:`repro.serve.recurrent_reference_generate` at the scheduler's chunk)
 before reporting throughput, so the benchmark doubles as the family
 protocol's end-to-end correctness gate.
 
@@ -312,9 +312,9 @@ def family_rows(
     (arch, batch).
 
     Every row first runs the workload once untimed to (a) compile all jit
-    entries and (b) assert the scheduled outputs equal the direct
-    sequential forward bit-for-bit (``outputs_match`` — CI fails the
-    artifact when False).  The strided PACK efficiency is ≈ 1 by
+    entries and (b) assert the scheduled tokens equal the direct forward
+    fed the same chunks (``outputs_match`` — CI fails the artifact when
+    False).  The strided PACK efficiency is ≈ 1 by
     construction (dense fixed-stride state rows, no index tax) while BASE
     efficiency is the occupancy — the serving-side contrast between the
     paper's two packed burst dialects.
@@ -332,7 +332,7 @@ def family_rows(
             prompts = [rng.integers(0, cfg.vocab, int(n)).astype(np.int32)
                        for n in lens]
             want = recurrent_reference_generate(
-                model, model.init_pool(b), prompts, n_new
+                model, model.init_pool(b), prompts, n_new, chunk=CHUNK
             )
 
             def _run() -> Scheduler:

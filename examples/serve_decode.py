@@ -143,8 +143,10 @@ assert out8 == out, "int8 greedy decode diverged from the fp32 run"
 cfgr = smoke_config("rwkv6-3b")
 rlm = RecurrentLM(cfgr, jax.random.PRNGKey(0))
 rprompts = [rng.integers(0, cfgr.vocab, n).astype(np.int32) for n in (8, 7, 12)]
-# Direct sequential forward at the same batch shape — the ground truth.
-want_r = recurrent_reference_generate(rlm, rlm.init_pool(3), rprompts, max_new)
+# Direct forward, each prompt fed in the scheduler's chunks — the ground
+# truth.
+want_r = recurrent_reference_generate(rlm, rlm.init_pool(3), rprompts, max_new,
+                                      chunk=4)
 
 # Same scheduler class, zero paged-KV anything: one fixed-size state slot
 # per resident, strided-burst accounting instead of page-table indirect.

@@ -20,11 +20,14 @@ one definition for training (:mod:`repro.models.lm`), serving
 * around the layers (:func:`rwkv_lm_defs`): ``ln0`` on the embedding,
   ``ln_out`` before an untied head.
 
-The recurrence is evaluated two ways that agree: :func:`wkv6`, the chunked
-decayed-cumsum form (exact, differentiable, O(chunk·H·hd²) live memory) for
-whole sequences, and :func:`wkv6_step`, one token, which serving and
-single-token decode run.  Decay and state are float32 whatever the compute
-dtype.
+The recurrence is evaluated three ways that agree: :func:`wkv6`, the
+chunked decayed-cumsum form (exact, differentiable, O(chunk·H·hd²) live
+memory) for whole sequences; :func:`wkv6_step`, one token, which decode
+runs; and :func:`wkv6_masked`, blocks of positions in closed form over a
+chunk whose rows end at their own position, which serving's prefill runs
+(each layer once over the whole chunk, a ``valid`` mask marking the
+positions that move the state; O(block²·H·hd) live memory).  Decay and
+state are float32 whatever the compute dtype.
 
 Departures from the published model:
 
@@ -184,6 +187,74 @@ def wkv6(
     return y, s_final
 
 
+#: Positions per step of :func:`wkv6_masked`'s scan.
+MASKED_BLOCK = 16
+
+
+def wkv6_masked(
+    r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
+    u: jax.Array, s0: jax.Array, valid: jax.Array,
+) -> Tuple[jax.Array, jax.Array]:
+    """The recurrence over T positions, some of them padding: r,k,v,w
+    (B,T,H,hd); u (H,hd); s0 (B,H,hd,hd) float32; valid (B,T) bool →
+    (y (B,T,H,hd) float32, state).
+
+    A position where ``valid`` is False is an identity step (``k = 0``,
+    decay 1): it leaves the state as it was, and its ``y`` means nothing.
+    A scan steps over blocks of n positions (the largest divisor of T up
+    to :data:`MASKED_BLOCK`), each in closed form with float32 products:
+    position t reads the carried state through the decay since the block
+    began, and each earlier position s of the block through
+    ``exp(Σ_{s<τ<t} log w_τ)``, every exponent at most 0.  Only the state
+    and one block's (B,H,n,n,hd) decays are live, never a state per
+    position.
+    """
+    b, t, h, hd = r.shape
+    n = max(d for d in range(1, min(MASKED_BLOCK, t) + 1) if t % d == 0)
+    f32 = jnp.float32
+    ok = valid[..., None, None]
+    k = jnp.where(ok, k, jnp.zeros((), k.dtype))
+    lw = jnp.where(ok, jnp.log(jnp.maximum(w, jnp.finfo(f32).tiny)), 0.0)
+
+    def blocks(a):  # (B, T, H, hd) -> (T/n, B, H, n, hd)
+        return a.reshape(b, t // n, n, h, hd).transpose(1, 0, 3, 2, 4)
+
+    hi = jax.lax.Precision.HIGHEST
+    earlier = jnp.tril(jnp.ones((n, n), bool), -1)[:, :, None]
+    bonus = u.astype(f32)[:, None, :]
+
+    def step(s, xs):
+        rb, kb, vb, lb = (x.astype(f32) for x in xs)
+        cum = jnp.cumsum(lb, axis=2)        # decay through each position
+        before = cum - lb                   # ... up to just before it
+        y = jnp.einsum("bhtk,bhkv->bhtv", rb * jnp.exp(before), s,
+                       precision=hi)
+        gap = before[:, :, :, None] - cum[:, :, None]
+        att = jnp.einsum("bhtk,bhsk,bhtsk->bhts", rb, kb,
+                         jnp.exp(jnp.where(earlier, gap, -jnp.inf)),
+                         precision=hi)
+        y = y + jnp.einsum("bhts,bhsv->bhtv", att, vb, precision=hi)
+        y = y + (rb * bonus * kb).sum(-1, keepdims=True) * vb
+        end = cum[:, :, -1:]
+        s = jnp.exp(end[:, :, 0, :, None]) * s + jnp.einsum(
+            "bhsk,bhsv->bhkv", kb * jnp.exp(end - cum), vb, precision=hi)
+        return s, y
+
+    s, ys = jax.lax.scan(step, s0.astype(f32),
+                         tuple(map(blocks, (r, k, v, lw))))
+    return ys.transpose(1, 0, 3, 2, 4).reshape(b, t, h, hd), s
+
+
+def _last_valid(x: jax.Array, valid: jax.Array,
+                x_last: jax.Array) -> jax.Array:
+    """Each row's ``x`` (B,T,D) at its last valid position; ``x_last``
+    (B,D) for a row with none."""
+    n = valid.sum(axis=1)
+    at = jnp.take_along_axis(x, jnp.maximum(n - 1, 0)[:, None, None],
+                             axis=1)[:, 0]
+    return jnp.where((n > 0)[:, None], at, x_last)
+
+
 def wkv6_step(
     r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
     u: jax.Array, s: jax.Array,
@@ -225,11 +296,15 @@ def _ddlerp(p, x, sx, dt):
 def time_mix(
     p, x, cfg: ArchConfig, rules: ShardingRules,
     state: Optional[Dict[str, jax.Array]] = None,
+    valid: Optional[jax.Array] = None,
 ):
     """state: {'s': (B,H,hd,hd), 'x_tm': (B,D)} for decode; None for train.
 
-    One token with a state runs :func:`wkv6_step`; anything longer the
-    chunked :func:`wkv6`.
+    With a ``valid`` (B,T) mask (and a state), the positions where it is
+    False are padding: :func:`wkv6_masked` runs, and the carried ``x_tm``
+    is each row's input at its last valid position.  Otherwise one token
+    with a state runs :func:`wkv6_step`; anything longer the chunked
+    :func:`wkv6`.
     """
     dt = cfg.compute_dtype
     b, t, d = x.shape
@@ -252,7 +327,9 @@ def time_mix(
         if state is None
         else state["s"]
     )
-    if state is not None and t == 1:
+    if valid is not None:
+        y, s_new = wkv6_masked(r, k, v, w, p["u"], s0, valid)
+    elif state is not None and t == 1:
         y, s_new = wkv6_step(r[:, 0], k[:, 0], v[:, 0], w[:, 0], p["u"], s0)
         y = y[:, None]
     else:
@@ -266,14 +343,18 @@ def time_mix(
         jnp.float32)
     y = y.astype(dt) * g
     out = jnp.einsum("bshk,hkd->bsd", y, p["wo"].astype(dt))
-    new_state = {"s": s_new, "x_tm": x[:, -1]}
+    x_tm = x[:, -1] if valid is None else _last_valid(x, valid, x_last)
+    new_state = {"s": s_new, "x_tm": x_tm}
     return constrain(out, rules, ("act_batch", "seq", "d_model")), new_state
 
 
 def channel_mix(
     p, x, cfg: ArchConfig, rules: ShardingRules,
     state: Optional[Dict[str, jax.Array]] = None,
+    valid: Optional[jax.Array] = None,
 ):
+    """``valid`` (B,T), as in :func:`time_mix`: the carried ``x_cm`` is each
+    row's input at its last valid position."""
     dt = cfg.compute_dtype
     x_last = None if state is None else state["x_cm"]
     sx = _shift(x, x_last) - x
@@ -284,20 +365,28 @@ def channel_mix(
     v = k @ p["wv"].astype(dt)
     r = jax.nn.sigmoid(xr @ p["wr"].astype(dt))
     out = r * v
+    x_cm = x[:, -1] if valid is None else _last_valid(x, valid, x_last)
     return (
         constrain(out, rules, ("act_batch", "seq", "d_model")),
-        {"x_cm": x[:, -1]},
+        {"x_cm": x_cm},
     )
 
 
 def rwkv_block(
     p, x, cfg: ArchConfig, rules: ShardingRules,
     state: Optional[Dict[str, jax.Array]] = None,
+    valid: Optional[jax.Array] = None,
 ):
-    """One RWKV layer: x + TM(ln1(x)); x + CM(ln2(x)). Returns (x, state)."""
-    tm_out, st_tm = time_mix(p["tm"], _ln(x, p["ln1"]), cfg, rules, state)
+    """One RWKV layer: x + TM(ln1(x)); x + CM(ln2(x)). Returns (x, state).
+
+    ``valid`` (B,T) bool, with a state, marks the positions that move it
+    (:func:`time_mix`); each row's are a prefix of the chunk.
+    """
+    tm_out, st_tm = time_mix(p["tm"], _ln(x, p["ln1"]), cfg, rules, state,
+                             valid)
     x = x + tm_out
-    cm_out, st_cm = channel_mix(p["cm"], _ln(x, p["ln2"]), cfg, rules, state)
+    cm_out, st_cm = channel_mix(p["cm"], _ln(x, p["ln2"]), cfg, rules, state,
+                                valid)
     x = x + cm_out
     return x, {**st_tm, **st_cm}
 

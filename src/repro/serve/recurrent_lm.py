@@ -15,14 +15,18 @@ Pieces:
 * :class:`RecurrentLM` — the published RWKV6 "Finch" model
   (:mod:`repro.models.rwkv6`: ``ln0``, the blocks, ``ln_out``, an untied
   head), or a tied-embedding LM over :func:`repro.models.mamba.mamba_fwd`.
-  **Every** token, prefill or decode, runs the same per-token body inside a
-  ``lax.scan`` (:meth:`RecurrentLM.prefill_chunk`,
-  :meth:`RecurrentLM.decode_chain`), identical regardless of trip count —
-  the property that makes scheduler-served output bit-for-bit equal to a
-  direct sequential forward at the same batch shape, no matter how chunked
-  prefill and fused decode slice the token stream.  Inactive rows carry
-  their state through ``jnp.where`` untouched (bit-exact), so batch
-  composition never leaks between sequences.
+  Prefill (:meth:`RecurrentLM.prefill_chunk`) gathers the call's own state
+  rows from the pool, runs each RWKV6 layer once over the whole
+  ``(rows, chunk)`` grid (the weights read once a call; only the WKV
+  recurrence steps through the chunk, a block of positions at a time, on
+  the gathered rows), and scatters the rows back in place; padding
+  positions and padding rows move no state.
+  Decode (:meth:`RecurrentLM.decode_chain`) runs one per-token body over
+  every slot inside a ``lax.scan``, identical regardless of trip count,
+  with inactive rows carried through ``jnp.where`` untouched (bit-exact).
+  Rows outside a call keep their state bit for bit, so batch composition
+  never leaks between sequences; a sequence's outputs depend on its own
+  tokens and on how its prompt is cut into chunks.
 * :class:`RecurrentStatePool` — the donated state pool + host bookkeeping
   (slot ownership, lengths), the recurrent analogue of
   :class:`repro.serve.kv.PagedKVCache`.
@@ -34,8 +38,8 @@ Pieces:
   :meth:`RecurrentFamily.replay` launches nothing), and the accounting
   dialect is :func:`repro.core.packing.recurrent_decode_traffic`
   + :func:`repro.core.streams.recurrent_state_streams`.
-* :func:`recurrent_reference_generate` — the direct sequential forward the
-  bitwise tests and the serving benchmark compare against.
+* :func:`recurrent_reference_generate` — the direct forward, with no
+  scheduler, that the tests and the serving benchmark compare against.
 """
 from __future__ import annotations
 
@@ -61,6 +65,7 @@ from repro.parallel.sharding import make_rules
 from repro.runtime.tracing import span
 from .family import OutOfPages, ServableFamily
 from .kv import _donation_noop_ok
+from .scheduler import build_prefill_rows
 
 __all__ = [
     "RecurrentFamily",
@@ -140,16 +145,18 @@ class RecurrentLM:
     are kept in ``cfg.param_dtype``, activations in ``cfg.dtype``; state and
     decay are float32.
 
-    Every token, prefill or decode, runs the same per-token body (embed, all
-    layers, state write-back masked by the row's ``active`` flag) inside a
-    ``lax.scan``, so a sequence's outputs depend only on its own tokens and
-    state rows, and scheduler-served output equals
-    :func:`recurrent_reference_generate` bit for bit at the same batch shape
-    however prefill and decode slice the stream.  Two programs run it:
-    :meth:`prefill_chunk` (given tokens; a row that starts its sequence reads
-    a zero state at its first token; the head once, after the scan, on each
-    row's last fed position) and :meth:`decode_chain` (the head and greedy
-    argmax every step; tokens out).  No per-step logits stack is made.
+    Two programs serve it.  :meth:`prefill_chunk` takes a call's ``(R, C)``
+    rows of given tokens: it gathers the R slots' state rows (zeros for a
+    row that starts its sequence), runs RWKV6's layers each once over the
+    chunk, a per-position mask keeping padding out of the state (Mamba runs
+    its per-token body over the gathered rows), scatters the rows back into
+    the donated pool, and runs the head on each row's last fed position.
+    :meth:`decode_chain` runs one per-token body (embed, all layers, state
+    write-back masked by the row's ``active`` flag) over every slot in a
+    ``lax.scan``, with the head and greedy argmax every step; tokens out.
+    No per-step logits stack is made.  A sequence's outputs depend only on
+    its own tokens, state rows and prompt chunks, so scheduler-served
+    tokens equal :func:`recurrent_reference_generate` fed the same chunks.
     """
 
     def __init__(self, cfg: ArchConfig, key: Optional[jax.Array],
@@ -237,20 +244,14 @@ class RecurrentLM:
         )
         return x + out, ns
 
-    def _token(self, params, pool, tok, act, zero=None):
+    def _token(self, params, pool, tok, act):
         """One token for every row: (B,) ids, (B,) bool → ((B, D) output
-        activations, pool with the active rows' state advanced).  Rows
-        flagged in ``zero`` (B,) read a zero state instead of their pool
-        rows."""
+        activations, pool with the active rows' state advanced)."""
         x = self._embed(params, tok)[:, None, :]
         new_states = []
         for l in range(self.cfg.n_layers):
             p_l = jax.tree.map(lambda a: a[l], params["layers"])
             st_l = {k: pool[k][l] for k in pool}
-            if zero is not None:
-                st_l = {k: jnp.where(
-                    zero.reshape((-1,) + (1,) * (v.ndim - 1)),
-                    jnp.zeros((), v.dtype), v) for k, v in st_l.items()}
             x, ns = self._layer_step(p_l, x, st_l)
             new_states.append(ns)
         new_pool = {}
@@ -262,22 +263,49 @@ class RecurrentLM:
 
     # -- programs ------------------------------------------------------------
 
+    def _chunk(self, params, pool, toks, valid, slots, fresh):
+        """RWKV6 over a call's rows: each layer once over the (R, C) grid,
+        its rows gathered from the pool and scattered back a layer at a
+        time.  Returns ((R, C, D) activations, the pool)."""
+        x = self._embed(params, toks)
+        for l in range(self.cfg.n_layers):
+            p_l = jax.tree.map(lambda a: a[l], params["layers"])
+            rows = {k: _gather_rows(t[l:l + 1], slots, fresh)[0]
+                    for k, t in pool.items()}
+            x, ns = rwkv_mod.rwkv_block(p_l, x, self.cfg, self.rules, rows,
+                                        valid)
+            pool = {k: t.at[l:l + 1].set(_scatter_rows(
+                t[l:l + 1], ns[k][None], slots, valid[:, 0]))
+                for k, t in pool.items()}
+        return x, pool
+
+    def _token_serial(self, params, pool, toks, valid, slots, fresh):
+        """The per-token body over a call's rows, position by position.
+        Returns ((R, C, D) activations, the pool)."""
+        def body(rows_c, xs):
+            tok, ok = xs
+            x, rows_c = self._token(params, rows_c, tok, ok)
+            return rows_c, x
+
+        rows = {k: _gather_rows(t, slots, fresh) for k, t in pool.items()}
+        rows, xs = jax.lax.scan(body, rows, (toks.T, valid.T))
+        pool = {k: _scatter_rows(t, rows[k], slots, valid[:, 0])
+                for k, t in pool.items()}
+        return jnp.swapaxes(xs, 0, 1), pool
+
     @functools.cached_property
     def _prefill(self):
-        def recurrent_lm_prefill(params, pool, toks, active, last, zero):
-            # toks, active, last, zero: (C, B); last marks each row's last
-            # fed position, zero the first token of a row that starts its
-            # sequence.  Returns the pool and (B, vocab) logits at last.
-            def body(carry, xs):
-                pool_c, x_last = carry
-                tok, act, is_last, z = xs
-                x, pool_c = self._token(params, pool_c, tok, act, z)
-                return (pool_c, jnp.where(is_last[:, None], x, x_last)), None
-
-            x0 = jnp.zeros((toks.shape[1], self.cfg.d_model),
-                           self.cfg.compute_dtype)
-            (pool, x_last), _ = jax.lax.scan(body, (pool, x0),
-                                             (toks, active, last, zero))
+        def recurrent_lm_prefill(params, pool, toks, counts, slots, fresh):
+            # toks (R, C); counts, slots (R,) i32; fresh (R,) bool: the
+            # row starts its sequence and reads a zero state.  A row with
+            # count 0 is padding (``valid[:, 0]`` False) and writes
+            # nothing.  Returns the pool and (R, vocab) logits at each
+            # row's last fed position.
+            valid = jnp.arange(toks.shape[1])[None, :] < counts[:, None]
+            run = self._chunk if self.arch == "rwkv6" else self._token_serial
+            x, pool = run(params, pool, toks, valid, slots, fresh)
+            last = jnp.maximum(counts - 1, 0)[:, None, None]
+            x_last = jnp.take_along_axis(x, last, axis=1)[:, 0]
             return pool, self._head(params, x_last)
 
         return jax.jit(recurrent_lm_prefill, donate_argnums=(1,))
@@ -302,27 +330,23 @@ class RecurrentLM:
         return jax.jit(recurrent_lm_decode, donate_argnums=(1,),
                        static_argnums=(4,))
 
-    def prefill_chunk(self, tensors, toks, active, rows, fresh=None):
-        """Feed given tokens: toks, active (C, B).  Slots flagged in
-        ``fresh`` (B,) start their sequence here: their state is zero at
-        their first token, whatever their pool rows hold.  Returns (new
-        tensors, (len(rows), vocab) logits on the device): those of the
-        last active position of each slot in ``rows``."""
+    def prefill_chunk(self, tensors, toks, counts, slots, fresh):
+        """Feed given tokens to a call's rows: toks (R, C), row i feeding
+        its first ``counts[i]`` tokens to slot ``slots[i]``; a row with
+        count 0 is padding and writes nothing.  Rows flagged in ``fresh``
+        (R,) start their sequence here: their state is zero at their first
+        token, whatever their pool rows hold.  Returns (new tensors,
+        (R, vocab) logits on the device): those of each row's last fed
+        position."""
         toks = np.asarray(toks, np.int32)
-        active = np.asarray(active, bool)
-        c, b = toks.shape
-        last = np.zeros((c, b), bool)
-        fed = active.sum(axis=0)
-        last[np.maximum(fed - 1, 0), np.arange(b)] = fed > 0
-        zero = np.zeros((c, b), bool)
-        if fresh is not None:
-            zero[0] = fresh
-        with span("engine.launch", kind="prefill", rows=int((fed > 0).sum()),
-                  row_fill=float(active.mean())), _donation_noop_ok():
-            tensors, logits = self._prefill(self.params, tensors, toks,
-                                            active, last, zero)
-            logits = _take_rows(logits, np.asarray(rows, np.int32))
-        return tensors, logits
+        counts = np.asarray(counts, np.int32)
+        with span("engine.launch", kind="prefill",
+                  rows=int((counts > 0).sum()),
+                  row_fill=float(counts.sum() / toks.size)), \
+                _donation_noop_ok():
+            return self._prefill(self.params, tensors, toks, counts,
+                                 np.asarray(slots, np.int32),
+                                 np.asarray(fresh, bool))
 
     def decode_chain(self, tensors, tokens, active, n: int):
         """Decode ``n`` greedy steps from current tokens; active (B,) bool.
@@ -350,9 +374,38 @@ class RecurrentLM:
         return tensors, np.concatenate(outs, axis=0)
 
 
-@jax.jit
-def _take_rows(logits, rows):
-    return logits[rows]
+def _slot_hits(slots, batch):
+    """(R, batch) bool: row i's one-hot slot."""
+    return slots[:, None] == jnp.arange(batch)[None, :]
+
+
+def _gather_rows(t, slots, fresh):
+    """``t[:, slots]`` of a ``(L, batch, *row)`` pool, zeros for the rows
+    flagged in ``fresh``.
+
+    A one-hot product over the slot axis (exact: one nonzero term), not an
+    indexed gather: the chip lays a state pool's slot axis in its lanes,
+    where an indexed gather or scatter first copies the whole pool into
+    another layout.  A non-finite row anywhere in the pool would reach
+    every gathered row (``0 · inf``); the state stays finite.
+    """
+    hit = _slot_hits(slots, t.shape[1]) & ~fresh[:, None]
+    return jnp.einsum("lb...,rb->lr...", t, hit.astype(t.dtype),
+                      precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=t.dtype)
+
+
+def _scatter_rows(t, rows, slots, keep):
+    """``t`` with ``rows[:, i]`` written to slot ``slots[i]`` where
+    ``keep[i]``, in place.  A padding row (``keep`` False) writes nothing,
+    so it never races a real row on the slot it aliases; every other slot
+    keeps its rows bit for bit."""
+    hit = _slot_hits(slots, t.shape[1]) & keep[:, None]
+    spread = jnp.einsum("lr...,rb->lb...", rows, hit.astype(t.dtype),
+                        precision=jax.lax.Precision.HIGHEST,
+                        preferred_element_type=t.dtype)
+    written = hit.any(axis=0).reshape((1, -1) + (1,) * (t.ndim - 2))
+    return jnp.where(written, spread, t)
 
 
 class RecurrentFamily(ServableFamily):
@@ -451,24 +504,14 @@ class RecurrentFamily(ServableFamily):
 
     def prefill_batch(self, tokens: np.ndarray, counts: np.ndarray,
                       slots: np.ndarray, starts: np.ndarray) -> np.ndarray:
-        b = self.batch
-        n_rows, c = tokens.shape
-        toks = np.zeros((c, b), np.int32)
-        act = np.zeros((c, b), bool)
-        fresh = np.zeros((b,), bool)
-        for i in range(n_rows):
-            ci, si = int(counts[i]), int(slots[i])
-            toks[:ci, si] = tokens[i, :ci]
-            act[:ci, si] = True
-            fresh[si] |= ci > 0 and int(starts[i]) == 0
         self.pool.tensors, logits = self.model.prefill_chunk(
-            self.pool.tensors, toks, act, slots, fresh
+            self.pool.tensors, tokens, counts, slots, np.asarray(starts) == 0
         )
         with span("engine.sync", kind="prefill"):
             out = np.asarray(logits)  # (rows, vocab)
         # Scalar loop: padding rows alias slot 0 with count 0, and fancy
         # `+=` drops duplicate-index updates instead of accumulating them.
-        for i in range(n_rows):
+        for i in range(len(counts)):
             self.pool.lengths_host[int(slots[i])] += int(counts[i])
         return out
 
@@ -539,13 +582,14 @@ def recurrent_reference_generate(
     max_new: int,
     chunk: int = 8,
 ) -> List[List[int]]:
-    """Direct sequential forward: the serving-free ground truth.
+    """Direct forward: the serving-free ground truth.
 
-    Drives the same fused step program over the same batch shape — prompt
-    tokens per-position with row masks, then greedy decode — with no
-    scheduler in the loop.  Scan-chunking invariance and bit-exact row
-    masking make the result identical to any scheduler interleaving, so
-    tests and the benchmark assert bitwise equality against this.
+    Feeds every prompt through the prefill program in chunks of ``chunk``
+    tokens from its start (one call per chunk position, the rows bucketed
+    as the scheduler buckets them), then greedy decode over the same batch
+    of slots, with no scheduler in the loop.  A sequence's outputs depend
+    only on its own tokens and on how its prompt is cut, so a scheduler of
+    the same ``chunk`` serves the same tokens whatever its interleaving.
     """
     b = pool.batch
     if len(prompts) > b:
@@ -556,24 +600,15 @@ def recurrent_reference_generate(
     tensors = pool.tensors
     maxp = max(plens)
     out: List[List[int]] = [[] for _ in prompts]
-    pos = 0
-    while pos < maxp:
-        c = min(chunk, maxp - pos)
-        toks = np.zeros((c, b), np.int32)
-        act = np.zeros((c, b), bool)
-        for i, p in enumerate(prompts):
-            ci = min(max(plens[i] - pos, 0), c)
-            if ci:
-                toks[:ci, i] = p[pos:pos + ci]
-                act[:ci, i] = True
-        tensors, logits = model.prefill_chunk(tensors, toks, act,
-                                              np.arange(len(prompts)),
-                                              act[0] if pos == 0 else None)
+    for pos in range(0, maxp, chunk):
+        items = [(p, pos, i) for i, p in enumerate(prompts) if pos < plens[i]]
+        toks, counts, slots, starts = build_prefill_rows(items, chunk, b)
+        tensors, logits = model.prefill_chunk(tensors, toks, counts, slots,
+                                              starts == 0)
         lg = np.asarray(logits)
-        for i in range(len(prompts)):
-            if pos < plens[i] <= pos + c:
-                out[i].append(int(np.argmax(lg[i])))
-        pos += c
+        for j, (_, _, i) in enumerate(items):
+            if plens[i] <= pos + chunk:
+                out[i].append(int(np.argmax(lg[j])))
     if max_new > 1:
         tokens = np.zeros((b,), np.int32)
         active = np.zeros((b,), bool)

@@ -54,8 +54,7 @@ def _served(cfg, seed=SEED):
     """Serve :data:`PROMPTS` through the scheduler; returns the requests
     and every logit row the served path computed, as
     ``{rid: {position: logits}}``."""
-    model = RecurrentLM(adapter.arch(cfg), None,
-                        params=adapter.params(cfg, ref.init_weights(cfg, seed)))
+    model = _model(cfg, seed)
     sched = Scheduler(model, model.init_pool(len(PROMPTS)), chunk=4)
     fam = sched.family
     seen = {}
@@ -79,14 +78,15 @@ def _served(cfg, seed=SEED):
         # One step per launch, bit for bit the chain.  The decode program
         # hands out only tokens, so each step's logits are read by feeding
         # the same tokens to a copy of the state through the prefill
-        # program (the same per-token body), and the step's served tokens
-        # must be their argmax.
+        # program (one-token rows, one per slot), and the step's served
+        # tokens must be their argmax.
         outs = []
         slots = np.arange(len(active))
         for s in range(n):
             copy = {k: jnp.copy(t) for k, t in tensors.items()}
-            _, logits = model.prefill_chunk(copy, tokens[None], active[None],
-                                            slots)
+            _, logits = model.prefill_chunk(
+                copy, tokens[:, None], active.astype(np.int32), slots,
+                np.zeros(len(active), bool))
             logits = np.asarray(logits)
             tensors, toks = chain(tensors, tokens, active, 1)
             for slot in np.nonzero(active)[0]:
@@ -154,18 +154,22 @@ def _parent_decay(p, x_w):
     return jnp.exp(-jnp.exp(w_log))
 
 
-def _no_carry(step):
-    def wkv6_step(r, k, v, w, u, s):
-        return step(r, k, v, w, u, jnp.zeros_like(s))
-    return wkv6_step
+def _no_carry(wkv):
+    """``wkv`` (decode's one token, or prefill's chunk) fed a zero state in
+    place of the carried one."""
+    def dropped(r, k, v, w, u, s, *rest):
+        return wkv(r, k, v, w, u, jnp.zeros_like(s), *rest)
+    return dropped
 
 
-@pytest.mark.parametrize("name,fault", [
-    ("decay", lambda: _parent_decay),
-    ("wkv6_step", lambda: _no_carry(rwkv_mod.wkv6_step)),
+@pytest.mark.parametrize("faults", [
+    lambda: {"decay": _parent_decay},
+    lambda: {name: _no_carry(getattr(rwkv_mod, name))
+             for name in ("wkv6_step", "wkv6_masked")},
 ], ids=["parent-decay-form", "dropped-state-carry"])
-def test_broken_model_fails_the_tolerance(monkeypatch, name, fault):
-    monkeypatch.setattr(rwkv_mod, name, fault())
+def test_broken_model_fails_the_tolerance(monkeypatch, faults):
+    for name, fault in faults().items():
+        monkeypatch.setattr(rwkv_mod, name, fault)
     gap, _, _ = _gap(CFG)
     assert gap > 100 * TOL_F32
 
@@ -176,6 +180,88 @@ def test_bf16_path_within_its_tolerance_and_fp8_outside():
     want = _reference(BF16, reqs, seen)
     fp8 = _reference(BF16, reqs, seen, control=True)
     assert float(np.abs(fp8 - want).max()) > TOL_BF16
+
+
+def _model(cfg=CFG, seed=SEED):
+    return RecurrentLM(adapter.arch(cfg), None,
+                       params=adapter.params(cfg, ref.init_weights(cfg, seed)))
+
+
+def _dirty_pool(model, batch, seed=1):
+    """A pool whose every row holds nonzero state, as slots left by earlier
+    occupants do."""
+    rng = np.random.default_rng(seed)
+    return {k: jnp.asarray(rng.normal(size=t.shape) * 0.5, t.dtype)
+            for k, t in model.init_pool(batch).tensors.items()}
+
+
+def _numpy(tensors):
+    return {k: np.asarray(t) for k, t in tensors.items()}
+
+
+def test_chunk_prefill_matches_token_by_token():
+    """One call of the chunk prefill: a full row, a fresh row of count < C
+    whose padding positions hold real token ids, a row carrying its state
+    on, and a padding row aliasing the fresh row's slot.  Each row's logits
+    and final state are those of its own tokens fed one at a time through
+    the per-token body; the slot outside the call is untouched."""
+    model = _model()
+    c, batch = 8, 4
+    rng = np.random.default_rng(7)
+    toks = rng.integers(0, CFG["vocab_size"], (4, c)).astype(np.int32)
+    counts = np.array([8, 3, 5, 0], np.int32)
+    slots = np.array([2, 0, 3, 0], np.int32)
+    fresh = np.array([False, True, False, False])
+    before = _numpy(_dirty_pool(model, batch))
+    after, logits = model.prefill_chunk(
+        {k: jnp.asarray(v) for k, v in before.items()}, toks, counts, slots,
+        fresh)
+    after, logits = _numpy(after), np.asarray(logits)
+
+    step = jax.jit(model._token)
+    head = jax.jit(model._head)
+    want = {k: v.copy() for k, v in before.items()}
+    for k in want:
+        want[k][:, 0] = 0                        # the fresh row's slot
+    state = {k: jnp.asarray(v) for k, v in want.items()}
+    want_logits = {}
+    for t in range(c):
+        tok = np.zeros(batch, np.int32)
+        act = np.zeros(batch, bool)
+        for i in range(3):
+            if t < counts[i]:
+                tok[slots[i]], act[slots[i]] = toks[i, t], True
+        x, state = step(model.params, state, tok, act)
+        lg = np.asarray(head(model.params, x))
+        for i in range(3):
+            if t == counts[i] - 1:
+                want_logits[i] = lg[slots[i]]
+    state = _numpy(state)
+    for i in range(3):
+        np.testing.assert_allclose(logits[i], want_logits[i],
+                                   rtol=1e-5, atol=1e-5)
+    for k in before:
+        # Padding positions (row 1's last five ids, the padding row's all)
+        # moved nothing: slot 0 holds row 1's three tokens only.
+        for slot in (0, 2, 3):
+            np.testing.assert_allclose(after[k][:, slot], state[k][:, slot],
+                                       rtol=1e-5, atol=1e-5)
+        np.testing.assert_array_equal(after[k][:, 1], before[k][:, 1])
+
+
+def test_all_padding_prefill_writes_nothing():
+    """The warm-up's calls: every row padding, aliasing slot 0, some
+    flagged fresh.  The pool comes back bit for bit."""
+    model = _model()
+    for rows in (1, 4):
+        before = _numpy(_dirty_pool(model, 4))
+        z = np.zeros(rows, np.int32)
+        toks = np.arange(rows * 8, dtype=np.int32).reshape(rows, 8)
+        after, _ = model.prefill_chunk(
+            {k: jnp.asarray(v) for k, v in before.items()}, toks, z, z,
+            np.ones(rows, bool))
+        for k, v in _numpy(after).items():
+            np.testing.assert_array_equal(v, before[k])
 
 
 def test_chunked_wkv6_matches_the_token_serial_recurrence():

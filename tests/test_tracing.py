@@ -161,15 +161,16 @@ def test_each_admission_opens_one_reset_span(tmp_path, make):
 
 
 def test_recurrent_launches_carry_their_row_fill(tmp_path):
-    """Each recurrent ``engine.launch`` carries ``row_fill``: the active
-    row-steps of the launch over batch x steps, from the host masks."""
+    """Each recurrent ``engine.launch`` carries ``row_fill``: for prefill
+    the real tokens over the call's rows x chunk, for decode the active
+    rows over the batch."""
     sched, _ = _serve(_recurrent, 1)
     fam = sched.family
     want = []
     prefill, decode = fam.prefill_batch, fam.decode_steps
 
     def prefill_batch(tokens, counts, slots, starts):
-        want.append(("prefill", counts.sum() / (fam.batch * tokens.shape[1])))
+        want.append(("prefill", counts.sum() / tokens.size))
         return prefill(tokens, counts, slots, starts)
 
     def decode_steps(tokens, active, n):
