@@ -12,19 +12,16 @@ traffic, the paper's §III-E element-size argument).
 
 Part 3 runs the continuous-batching scheduler: requests of different lengths
 enter a tight page pool, prefill chunks interleave with batched decode
-steps, one request is evicted and replayed bit-for-bit, and every decode
-step's PACK-vs-BASE traffic is accounted through the same indirect-stream
-descriptors the kernel consumes.
+steps, and one request is evicted and replayed bit-for-bit; the scheduler's
+counters say how many steps, tokens and evictions that took.
 
 Part 4 re-runs the scheduler with ``kv_dtype='int8'``: the pools hold int8
-codes plus fp32 scale sidebands, K/V rows are quantized on write, both
-attention kernels dequantize page-by-page, and the traffic accounting
-shows the quadrupled packing factor (pool bytes ÷4 vs fp32).
+codes plus fp32 scale sidebands, K/V rows are quantized on write, and both
+attention kernels dequantize page-by-page (pool bytes ÷4 vs fp32).
 
 Part 5 serves a *recurrent* model (RWKV6) through the very same scheduler:
-fixed-size state slots instead of growing page chains, strided-burst
-accounting instead of indirect, same admission/eviction/replay machinery —
-the family protocol in action.
+fixed-size state slots instead of growing page chains, same
+admission/eviction/replay machinery — the family protocol in action.
 
 Run: PYTHONPATH=src python examples/serve_decode.py
 """
@@ -109,11 +106,9 @@ for i, p in enumerate(prompts):
 out = sched.run()
 st = sched.stats
 match = all(out[i] == want[i] for i in out)
-print(f"scheduler: {st.tokens} tokens in {st.decode_steps} decode steps, "
-      f"{st.n_evictions} eviction(s); matches static batch: {match}")
-print(f"per-step bus traffic: PACK {st.pack_bytes/2**10:.0f} KiB "
-      f"({st.pack_efficiency:.0%} useful) vs BASE {st.base_bytes/2**10:.0f} "
-      f"KiB ({st.base_efficiency:.0%} useful)")
+print(f"scheduler: {st.tokens} tokens in {st.prefill_steps} prefill and "
+      f"{st.decode_steps} decode steps, {st.n_evictions} eviction(s); "
+      f"matches static batch: {match}")
 assert match, "scheduled decode diverged from the static batch"
 
 # --- Part 4: int8 page pools under the scheduler -----------------------------
@@ -130,10 +125,6 @@ print(f"int8 scheduler: {st8.tokens} tokens, pool "
       f"{cache_fp.pool_bytes/2**10:.0f} KiB fp32 → "
       f"{sched8.cache.pool_bytes/2**10:.0f} KiB int8 "
       f"({cache_fp.pool_bytes / sched8.cache.pool_bytes:.2f}x smaller)")
-print(f"int8 PACK {st8.pack_bytes/2**10:.0f} KiB vs fp32 PACK "
-      f"{st.pack_bytes/2**10:.0f} KiB on the same workload; BASE eff "
-      f"{st8.base_efficiency:.0%} (narrow elements in full-width slots) vs "
-      f"PACK eff {st8.pack_efficiency:.0%}")
 # Greedy decode is robust to the quantization noise on this workload: the
 # token streams match the full-precision run exactly.
 print("int8 tokens match fp32 run:", out8 == out)
@@ -149,17 +140,14 @@ want_r = recurrent_reference_generate(rlm, rlm.init_pool(3), rprompts, max_new,
                                       chunk=4)
 
 # Same scheduler class, zero paged-KV anything: one fixed-size state slot
-# per resident, strided-burst accounting instead of page-table indirect.
+# per resident.
 sched_r = Scheduler(rlm.bind(rlm.init_pool(3)), chunk=4)
 for i, p in enumerate(rprompts):
     sched_r.submit(Request(rid=i, prompt=p, max_new=max_new))
 out_r = sched_r.run()
 st_r = sched_r.stats
 match_r = all(out_r[i] == want_r[i] for i in out_r)
-print(f"recurrent scheduler: {st_r.tokens} tokens in {st_r.decode_steps} "
-      f"decode steps; matches direct forward: {match_r}")
-print(f"strided PACK {st_r.pack_bytes/2**10:.0f} KiB "
-      f"({st_r.pack_efficiency:.0%} useful — dense fixed-stride state, no "
-      f"index tax) vs BASE {st_r.base_bytes/2**10:.0f} KiB "
-      f"({st_r.base_efficiency:.0%})")
+print(f"recurrent scheduler: {st_r.tokens} tokens in {st_r.prefill_steps} "
+      f"prefill and {st_r.decode_steps} decode steps; matches direct "
+      f"forward: {match_r}")
 assert match_r, "recurrent scheduled decode diverged from direct forward"

@@ -7,6 +7,10 @@ Public surface:
   accounting (the reference semantics of the beat packer).
 * :mod:`repro.core.busmodel` -- analytical BASE/PACK/IDEAL cycle model.
 * :mod:`repro.core.banksim` -- cycle-approximate banked endpoint simulator.
+
+These reproduce the paper's figures (``benchmarks/``).  The serving stack
+(:mod:`repro.serve`) imports none of them: what a served kernel moves is
+counted by the chip benchmark (``bench/``) from the model's configuration.
 """
 from .streams import (
     BurstKind,
@@ -15,23 +19,12 @@ from .streams import (
     StridedStream,
     beats_for,
     elements_per_beat,
-    page_table_streams,
-    prefill_table_streams,
-    recurrent_state_streams,
-    verify_table_streams,
 )
 from .packing import (
     Traffic,
     indirect_traffic,
     pack_indirect,
     pack_strided,
-    packed_token_bytes,
-    paged_decode_traffic,
-    paged_prefill_traffic,
-    prefill_page_counts,
-    spec_verify_traffic,
-    recurrent_decode_traffic,
-    recurrent_prefill_traffic,
     strided_traffic,
     unpack_indirect,
     unpack_strided,

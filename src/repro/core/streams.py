@@ -18,13 +18,9 @@ from __future__ import annotations
 
 import dataclasses
 import enum
-import functools
-import itertools
 import math
-from typing import Optional, Sequence, Tuple
+from typing import Optional
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 __all__ = [
@@ -35,11 +31,6 @@ __all__ = [
     "IndirectStream",
     "elements_per_beat",
     "beats_for",
-    "page_table_streams",
-    "prefill_table_streams",
-    "verify_table_streams",
-    "share_table_streams",
-    "recurrent_state_streams",
 ]
 
 
@@ -170,247 +161,6 @@ class IndirectStream(StreamDescriptor):
 
     def element_offsets(self) -> np.ndarray:
         return self.base + np.asarray(self.indices, dtype=np.int64)
-
-
-def page_table_streams(
-    page_table,
-    lengths,
-    page_size: int,
-    token_bytes: int,
-    index_bits: int = 32,
-    kv_elem_bits: int = 32,
-    scale_bytes_per_token: int = 0,
-) -> Tuple["IndirectStream", ...]:
-    """Batched indirect-stream descriptors for a paged-KV decode step.
-
-    A paged KV cache is the serving-side instance of the paper's indirect
-    stream: the *element* is one physical KV page (``page_size`` tokens ×
-    the packed per-token width), and the per-sequence page-table row is the
-    memory-resident index vector.  One :class:`IndirectStream` is returned
-    per sequence with a non-zero length, covering exactly the pages a decode
-    step touches (``ceil(len / page_size)`` leading table entries).
-
-    ``token_bytes`` is the FP32-equivalent per-token footprint;
-    ``kv_elem_bits`` the real element width of the pool on the stream.
-    Narrow elements shrink the page element
-    (:func:`repro.core.packing.packed_token_bytes`): an int8 pool's page
-    descriptor carries a quarter of the fp32 bits plus the scale sideband —
-    the ``elements_per_beat`` packing factor quadrupling, visible in the
-    descriptor itself.
-
-    The scheduler builds these descriptors each step and derives both the
-    kernel operands (page ids / lengths) and the
-    :func:`repro.core.packing.paged_decode_traffic` accounting from them, so
-    the serving path and the Fig. 3 bus model share one source of truth.
-    """
-    from .packing import packed_token_bytes
-
-    pt = np.asarray(page_table)
-    lens = np.asarray(lengths)
-    elem_bits = page_size * packed_token_bytes(
-        token_bytes, kv_elem_bits, scale_bytes_per_token
-    ) * 8
-    out = []
-    for row, ln in zip(pt, lens):
-        n = -(-int(ln) // page_size)
-        if n == 0:
-            continue
-        out.append(
-            IndirectStream(
-                base=0,
-                elem_bits=elem_bits,
-                count=n,
-                indices=np.asarray(row[:n], dtype=np.int64),
-                index_bits=index_bits,
-            )
-        )
-    return tuple(out)
-
-
-def prefill_table_streams(
-    page_table,
-    starts,
-    counts,
-    page_size: int,
-    token_bytes: int,
-    index_bits: int = 32,
-    kv_elem_bits: int = 32,
-    scale_bytes_per_token: int = 0,
-) -> Tuple["IndirectStream", ...]:
-    """Batched indirect-stream descriptors for one chunked-prefill step.
-
-    The prefill-side sibling of :func:`page_table_streams`: per sequence
-    with a non-zero chunk, *two* indirect streams whose element is one
-    physical KV page —
-
-    * the **context read**: the leading ``ceil((start+count)/page)`` table
-      entries the ``paged_prefill_attention`` kernel walks (its scalar-
-      prefetch index vector, verbatim), and
-    * the **chunk write**: the entries covering positions
-      ``start .. start+count-1`` that ``paged_kv_write_chunk`` scatters
-      through.
-
-    ``kv_elem_bits``/``scale_bytes_per_token`` shrink the page element for
-    narrow (int8) pools exactly as in :func:`page_table_streams`.
-
-    Page math is shared with :func:`repro.core.packing.paged_prefill_traffic`
-    via :func:`repro.core.packing.prefill_page_counts`, so the descriptors,
-    the byte accounting, and the kernel's DMA walk are one source of truth.
-    """
-    from .packing import packed_token_bytes, prefill_page_counts
-
-    pt = np.asarray(page_table)
-    st = np.asarray(starts)
-    ct = np.asarray(counts)
-    ctx, chunk = prefill_page_counts(st, ct, page_size)
-    elem_bits = page_size * packed_token_bytes(
-        token_bytes, kv_elem_bits, scale_bytes_per_token
-    ) * 8
-    out = []
-    for row, s, n, nc, nw in zip(pt, st, ct, ctx, chunk):
-        if n == 0:
-            continue
-        out.append(
-            IndirectStream(
-                base=0,
-                elem_bits=elem_bits,
-                count=int(nc),
-                indices=np.asarray(row[: int(nc)], dtype=np.int64),
-                index_bits=index_bits,
-            )
-        )
-        p_lo = int(s) // page_size
-        out.append(
-            IndirectStream(
-                base=0,
-                elem_bits=elem_bits,
-                count=int(nw),
-                indices=np.asarray(
-                    row[p_lo : p_lo + int(nw)], dtype=np.int64
-                ),
-                index_bits=index_bits,
-            )
-        )
-    return tuple(out)
-
-
-def verify_table_streams(
-    page_table,
-    lengths,
-    scored,
-    page_size: int,
-    token_bytes: int,
-    index_bits: int = 32,
-    kv_elem_bits: int = 32,
-    scale_bytes_per_token: int = 0,
-) -> Tuple["IndirectStream", ...]:
-    """Indirect-stream descriptors for one speculative K-token verify step.
-
-    A verify chunk is a causal prefill chunk appended at the context tail,
-    so the descriptors *are* :func:`prefill_table_streams` with
-    ``starts = lengths`` — per active row one context-read stream over the
-    leading ``ceil((length + scored)/page)`` table entries (the single
-    clamped walk ``paged_verify`` amortizes over all K queries, where plain
-    decode would emit ``scored`` separate walks) and one chunk-write stream
-    over the pages the K appended tokens land in.  Shares its page math
-    with :func:`repro.core.packing.spec_verify_traffic` through
-    :func:`repro.core.packing.prefill_page_counts`.
-    """
-    return prefill_table_streams(
-        page_table, lengths, scored, page_size, token_bytes,
-        index_bits=index_bits, kv_elem_bits=kv_elem_bits,
-        scale_bytes_per_token=scale_bytes_per_token,
-    )
-
-
-def share_table_streams(
-    page_ids: Sequence[int],
-    page_size: int,
-    token_bytes: int,
-    index_bits: int = 32,
-    kv_elem_bits: int = 32,
-    scale_bytes_per_token: int = 0,
-) -> Tuple["IndirectStream", ...]:
-    """Descriptor for mapping an already-resident prompt prefix (dedup).
-
-    The admission-time sibling of :func:`page_table_streams`: when a new
-    request's page-aligned prompt prefix is already in the pool, the only
-    memory operation is fetching the ``len(page_ids)`` table entries being
-    repointed — no KV payload moves.  The returned stream is ``remap_only``;
-    its element fields still carry the packed page width so the byte value
-    of the reuse (:func:`repro.core.packing.prefix_share_traffic`) and the
-    descriptor agree on what was deduplicated.
-    """
-    from .packing import packed_token_bytes
-
-    if not len(page_ids):
-        return ()
-    elem_bits = page_size * packed_token_bytes(
-        token_bytes, kv_elem_bits, scale_bytes_per_token
-    ) * 8
-    return (
-        IndirectStream(
-            base=0,
-            elem_bits=elem_bits,
-            count=len(page_ids),
-            indices=np.asarray(page_ids, dtype=np.int64),
-            index_bits=index_bits,
-            remap_only=True,
-        ),
-    )
-
-
-def recurrent_state_streams(
-    slots: Sequence[int],
-    batch: int,
-    n_layers: int,
-    row_bytes: Sequence[int],
-) -> Tuple["StridedStream", ...]:
-    """Strided read-modify-write descriptors for one recurrent decode step.
-
-    The strided-burst sibling of :func:`page_table_streams`: recurrent
-    (RWKV/Mamba) serving state is fixed-size per sequence and lives in
-    pools of shape ``(n_layers, batch, *row)``.  Flattened to
-    ``(n_layers × batch)`` rows, one sequence's state sits at rows
-    ``slot, slot + batch, slot + 2·batch, …`` — a textbook strided stream:
-    ``base = slot``, ``stride = batch``, ``count = n_layers``, with the
-    whole per-layer row as the element.  No memory-resident index vector
-    exists; the stride in the request descriptor is the entire addressing
-    metadata (the ``pack``/``indir=0`` encoding of the paper).
-
-    A decode step both reads and writes the state, so *two* descriptors
-    are emitted per (active slot, state tensor): the read burst and the
-    write-back burst.  ``row_bytes`` carries one per-layer row footprint
-    per state tensor (RWKV6 has one — the (H, 64, 64) wkv state; Mamba has
-    two — the SSM state and the conv tail).
-
-    With ``batch == 1`` the stride degenerates to 1 and the descriptor
-    routes to the BASE converter (the never-slower-than-AXI4 guarantee),
-    exactly like :class:`StridedStream` always does.
-
-    The family builds these each step and derives the
-    :func:`repro.core.packing.recurrent_decode_traffic` accounting from the
-    same (slots, batch, layers, bytes) quantities, so descriptors and byte
-    accounting share one source of truth — mirroring the paged path.  A
-    slot's descriptors never change, so each slot's are made once and
-    shared (they are frozen).
-    """
-    shape = (int(batch), int(n_layers), tuple(int(rb) for rb in row_bytes))
-    return tuple(itertools.chain.from_iterable(
-        _slot_state_streams(int(slot), *shape) for slot in slots))
-
-
-@functools.lru_cache(maxsize=4096)
-def _slot_state_streams(slot: int, batch: int, n_layers: int,
-                        row_bytes: Tuple[int, ...]
-                        ) -> Tuple["StridedStream", ...]:
-    """One slot's read and write-back burst per state tensor."""
-    out = []
-    for rb in row_bytes:
-        burst = StridedStream(base=slot, elem_bits=rb * 8, count=n_layers,
-                              stride=batch)
-        out += [burst, burst]  # read burst + write-back burst
-    return tuple(out)
 
 
 def word_addresses(

@@ -16,8 +16,7 @@ What makes this the speculative *perf* kernel is the amortization: plain
 decode walks every context page once per generated token (K narrow
 indirect bursts for K tokens), while verify walks them once per K-token
 batch — the AXI-Pack packed-indirect-burst argument applied along the
-time axis instead of the batch axis.  ``core.packing.spec_verify_traffic``
-accounts exactly that saving.
+time axis instead of the batch axis.
 
 The grid is ``(B, ctx_pages)`` with the per-row walk clamped to
 ``ceil((lengths[r] + counts[r]) / page)`` pages; rows with

@@ -12,11 +12,8 @@ Where each span is opened:
 * ``sched.step`` (``step``): one :meth:`repro.serve.scheduler.Scheduler.step`;
   inside it ``sched.admit``, ``sched.prefill``, ``sched.decode`` (with
   ``sched.grow``) and ``sched.retire``, one per phase;
-* ``sched.account`` (``kind``, ``steps``): a family's stream accounting
-  (``step_streams``, ``prefill_account``, ``verify_account``,
-  ``share_account``);
 * ``sched.handoff``: appending served tokens to their requests and calling
-  ``on_token`` (for decode and verify, building their step records);
+  ``on_token``;
 * ``engine.launch`` (``kind``, ``n`` or ``rows``, ``ctx_pages``; for paged
   decode ``ppb`` and ``block_fill``; for recurrent launches ``row_fill``):
   one jitted program call, from the call to its return (dispatch and
@@ -46,7 +43,6 @@ SPANS = (
     "sched.prefill",
     "sched.decode",
     "sched.grow",
-    "sched.account",
     "sched.handoff",
     "sched.retire",
     "engine.launch",

@@ -34,6 +34,5 @@ from .scheduler import (
     Scheduler,
     SchedulerStalledError,
     ServeStats,
-    StepRecord,
     build_prefill_rows,
 )

@@ -3,17 +3,15 @@
 The scheduler (``repro.serve.scheduler``) implements continuous batching,
 SLA-aware admission, eviction with bit-for-bit replay, prefix sharing, and
 chaos degradation — none of which is specific to transformers.  What *is*
-family-specific is how a sequence's serving state lives in device memory
-and what bus traffic touching it costs:
+family-specific is how a sequence's serving state lives in device memory:
 
 * **Paged attention** (``repro.serve.paged_lm.PagedFamily``): KV state
-  grows one token per decode step, lives in fixed-size pages, and every
-  access is an *indirect* burst — the page table is the memory-resident
-  index vector of the AXI-Pack gather.
+  grows one token per decode step and lives in fixed-size pages that the
+  kernels walk through the page table — the memory-resident index vector
+  of the AXI-Pack gather.
 * **Recurrent state** (``repro.serve.recurrent_lm.RecurrentFamily``):
   RWKV6/Mamba state is fixed-size per sequence — the degenerate
-  "single page that never grows" — and every decode step is a *strided*
-  read-modify-write over the (layer, slot) state pool.
+  "single page that never grows" — updated in place in the state pool.
 
 The protocol speaks in **resource units** so both map onto the same
 admission/eviction arithmetic: a unit is a page for the paged family
@@ -26,21 +24,14 @@ allocated at admission, never grown).  Eviction is identical in both:
 are empty by construction).
 
 The scheduler holds exactly one ``ServableFamily`` and calls nothing
-else — no ``isinstance(PagedLM)``, no KV-specific attribute.  Traffic
-accounting is part of the protocol (``step_streams`` /
-``prefill_account``) so each family reports its own stream dialect:
-:class:`repro.core.streams.IndirectStream` page walks for paged KV,
-:class:`repro.core.streams.StridedStream` state walks for recurrent
-state — and ``BENCH_serving.json`` can compare the two on equal terms.
+else — no ``isinstance(PagedLM)``, no KV-specific attribute.
 """
 from __future__ import annotations
 
 import abc
-from typing import List, Sequence, Tuple
+from typing import List, Tuple
 
 import numpy as np
-
-from repro.core.packing import Traffic
 
 __all__ = ["OutOfPages", "ServableFamily"]
 
@@ -59,8 +50,7 @@ class ServableFamily(abc.ABC):
 
     A family binds a model to its resource pool (page pool or state pool)
     and owns all device state; the scheduler only does bookkeeping in
-    resource units and records the (Traffic, stream) accounts the family
-    hands back.  Implementations: ``PagedFamily`` (``serve/paged_lm.py``)
+    resource units.  Implementations: ``PagedFamily`` (``serve/paged_lm.py``)
     and ``RecurrentFamily`` (``serve/recurrent_lm.py``).
     """
 
@@ -100,11 +90,6 @@ class ServableFamily(abc.ABC):
         math); 0 for families whose units are whole-sequence state."""
         return 0
 
-    @property
-    @abc.abstractmethod
-    def pool_bytes(self) -> int:
-        """Device bytes held by the family's resource pool."""
-
     @abc.abstractmethod
     def units_for(self, n_tokens: int) -> int:
         """Resource units a sequence of ``n_tokens`` occupies."""
@@ -116,13 +101,6 @@ class ServableFamily(abc.ABC):
     @abc.abstractmethod
     def token_capacity(self, slot: int) -> int:
         """Tokens ``slot`` can hold before it must ``grow`` again."""
-
-    @abc.abstractmethod
-    def state_bytes(self, n_tokens: int) -> int:
-        """Full-width device bytes of serving state for ``n_tokens`` live
-        tokens — what a packing-oblivious BASE server streams per touch.
-        Linear in ``n_tokens`` for paged KV; constant for recurrent
-        state."""
 
     @abc.abstractmethod
     def lengths(self) -> np.ndarray:
@@ -200,29 +178,6 @@ class ServableFamily(abc.ABC):
         the replay guarantee extends to speculation unchanged."""
         raise NotImplementedError(f"{self.name}: no speculative decoding")
 
-    def verify_account(self, lens0: np.ndarray, active: np.ndarray,
-                       counts: np.ndarray) -> List[Tuple[Traffic, tuple]]:
-        """Per-step (Traffic, stream descriptors) for a verify run that
-        just completed — called *after* ``verify_steps`` with the
-        pre-launch length shadow ``lens0`` and the emitted ``counts``,
-        since speculative context lengths are data-dependent."""
-        raise NotImplementedError(f"{self.name}: no speculative decoding")
-
-    # -- traffic accounting -------------------------------------------------
-
-    @abc.abstractmethod
-    def step_streams(self, active: np.ndarray,
-                     n: int) -> List[Tuple[Traffic, tuple]]:
-        """Per-step (Traffic, stream descriptors) for the ``n`` decode
-        steps about to run on ``active`` slots.  Called immediately
-        before ``decode_steps``; derived from host shadows only."""
-
-    @abc.abstractmethod
-    def prefill_account(self, slots: np.ndarray, starts: np.ndarray,
-                        counts: np.ndarray) -> Tuple[Traffic, tuple]:
-        """(Traffic, stream descriptors) for the prefill chunk that just
-        ran over these rows."""
-
     # -- prefix sharing capability (optional) -------------------------------
 
     @property
@@ -252,10 +207,6 @@ class ServableFamily(abc.ABC):
         """Copy-on-write any shared unit covering [lo, hi]; returns the
         number of copies.  Default: nothing is ever shared, 0 copies."""
         return 0
-
-    def share_account(self, shared_tokens: int,
-                      unit_ids: Sequence[int]) -> Tuple[Traffic, tuple]:
-        raise NotImplementedError(f"{self.name}: no prefix sharing")
 
     # -- invariants ---------------------------------------------------------
 

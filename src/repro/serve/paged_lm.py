@@ -5,7 +5,7 @@ and ``serve/family.py`` (the scheduler protocol).  This module owns the
 jitted prefill/decode programs, the :class:`PagedLM` wrapper that keeps the
 cache's host shadows in step, and :class:`PagedFamily` — the
 :class:`repro.serve.family.ServableFamily` implementation the scheduler
-drives (resource units = pages, streams = indirect page walks).
+drives (resource units = pages).
 
 The paged path is built as a *device-resident fast path*: the page pools are
 donated into every jitted call (``donate_argnums``) so they update in place
@@ -14,8 +14,8 @@ instead of being copied per step, greedy sampling happens on device, and
 that feeds its own samples back — the host only sees tokens when the
 scheduler reaches a scheduling boundary (admission, page growth,
 retirement).  Host-side shadow state (``lengths_host``/``page_table_host``)
-lets all bookkeeping and traffic accounting run without a single
-device→host sync on the hot path.
+lets all bookkeeping run without a single device→host sync on the hot
+path.
 """
 from __future__ import annotations
 
@@ -29,19 +29,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig
-from repro.core.packing import (
-    Traffic,
-    paged_decode_traffic,
-    paged_prefill_traffic,
-    prefix_share_traffic,
-    spec_verify_traffic,
-)
-from repro.core.streams import (
-    page_table_streams,
-    prefill_table_streams,
-    share_table_streams,
-    verify_table_streams,
-)
 from repro.kernels import ops as kops
 from repro.kernels.paged_decode import pages_per_block
 from repro.runtime.tracing import span
@@ -452,36 +439,6 @@ class PagedLM:
             self._prefill_cache.move_to_end(key)
         return fn
 
-    @property
-    def quantized(self) -> bool:
-        return self.kv_dtype == jnp.int8
-
-    @functools.cached_property
-    def kv_token_bytes(self) -> int:
-        """FP32-equivalent bytes per live KV token (K+V, all layers).
-
-        This is the *full-width* footprint — what a packing-oblivious BASE
-        server streams per token regardless of the pool's element width.
-        The packed width is derived from it via :attr:`kv_elem_bits` and
-        :attr:`kv_scale_token_bytes` (see
-        ``repro.core.packing.packed_token_bytes``).
-        """
-        return 2 * self.cfg.n_layers * self.kvh * self.hd * 4
-
-    @functools.cached_property
-    def kv_elem_bits(self) -> int:
-        """Element width of the KV pools on the stream (32/16/8 bits)."""
-        return jnp.dtype(self.kv_dtype).itemsize * 8
-
-    @functools.cached_property
-    def kv_scale_token_bytes(self) -> int:
-        """Sideband scale bytes PACK moves per live KV token (int8 mode).
-
-        One fp32 scale per (token, kv-head) per pool per layer; zero in
-        full-precision modes.
-        """
-        return 2 * self.cfg.n_layers * self.kvh * 4 if self.quantized else 0
-
     # -- decode --------------------------------------------------------------
 
     def _shift_lengths(self, cache: PagedKVCache, active, steps: int):
@@ -707,12 +664,10 @@ class PagedLM:
 class PagedFamily(ServableFamily):
     """:class:`ServableFamily` over a :class:`PagedLM` + :class:`PagedKVCache`.
 
-    Resource units are physical pages; traffic accounting is the indirect
-    dialect (``page_table_streams`` / ``paged_decode_traffic`` — the page
-    table as a memory-resident index vector).  Every method delegates to
-    the exact calls the scheduler used to make directly, with identical
-    argument values, so PagedLM serving output and Traffic/stream records
-    are bit-for-bit unchanged by the protocol indirection.
+    Resource units are physical pages.  Every method delegates to the
+    exact calls the scheduler used to make directly, with identical
+    argument values, so PagedLM serving output is bit-for-bit unchanged by
+    the protocol indirection.
 
     The family is the *stateful* face of the functional cache: pool-mutating
     methods rebind ``self.cache`` to the returned cache, and the scheduler
@@ -723,9 +678,8 @@ class PagedFamily(ServableFamily):
     name = "paged"
 
     def __init__(self, model: PagedLM, cache: PagedKVCache):
-        # Element width drives the traffic accounting AND the math the model
-        # runs, so any model/cache width mismatch (not just int8-vs-float)
-        # must fail loudly rather than mis-report PACK bytes.
+        # Element width drives the math the model runs, so any model/cache
+        # width mismatch (not just int8-vs-float) must fail loudly.
         if jnp.dtype(model.kv_dtype) != jnp.dtype(cache.k_pages.dtype):
             raise ValueError(
                 f"model kv_dtype ({jnp.dtype(model.kv_dtype).name}) does not "
@@ -764,10 +718,6 @@ class PagedFamily(ServableFamily):
     def page_size(self) -> int:
         return self.cache.page_size
 
-    @property
-    def pool_bytes(self) -> int:
-        return self.cache.pool_bytes
-
     def units_for(self, n_tokens: int) -> int:
         return self.cache.pages_for(n_tokens)
 
@@ -776,9 +726,6 @@ class PagedFamily(ServableFamily):
 
     def token_capacity(self, slot: int) -> int:
         return self.cache._mapped(slot) * self.cache.page_size
-
-    def state_bytes(self, n_tokens: int) -> int:
-        return n_tokens * self.model.kv_token_bytes
 
     def lengths(self) -> np.ndarray:
         if self.cache.lengths_host is not None:
@@ -842,92 +789,6 @@ class PagedFamily(ServableFamily):
             )
         return toks, counts
 
-    def verify_account(self, lens0: np.ndarray, active,
-                       counts: np.ndarray) -> List[Tuple[Traffic, tuple]]:
-        """Per-launch-step (Traffic, streams) for a verify run that just
-        completed.  Unlike :meth:`step_streams` this runs *after* the
-        launch: per-step context lengths depend on data-dependent
-        acceptance, so they are reconstructed from the pre-launch length
-        shadow ``lens0`` plus the synced emitted ``counts`` (n, B) — the
-        scored count per step is re-derived with the same
-        ``min(spec_k, caps - len)`` clamp the device loop applied."""
-        k = self.model.spec_k
-        page = self.cache.page_size
-        b = self.batch
-        table = np.array(self._host_table())
-        slots = np.nonzero(np.asarray(active))[0]
-        caps = np.array(
-            [self.cache._mapped(s) * page for s in range(b)], np.int64
-        )
-        lens = np.asarray(lens0, np.int64).copy()
-        accounts: List[Tuple[Traffic, tuple]] = []
-        for s in range(counts.shape[0]):
-            scored = np.zeros((b,), np.int64)
-            scored[slots] = np.clip(caps[slots] - lens[slots], 0, k)
-            traffic = spec_verify_traffic(
-                lens, scored, page, self.cache.pages_per_seq,
-                self.model.kv_token_bytes,
-                elem_bits=self.model.kv_elem_bits,
-                scale_bytes_per_token=self.model.kv_scale_token_bytes,
-            )
-            streams = verify_table_streams(
-                table, lens, scored, page, self.model.kv_token_bytes,
-                kv_elem_bits=self.model.kv_elem_bits,
-                scale_bytes_per_token=self.model.kv_scale_token_bytes,
-            )
-            accounts.append((traffic, streams))
-            lens += np.asarray(counts[s], np.int64)
-        return accounts
-
-    # -- traffic accounting -------------------------------------------------
-
-    def step_streams(self, active, n: int) -> List[Tuple[Traffic, tuple]]:
-        """Per-step indirect accounting for the next ``n`` fused decode
-        steps, from the same host shadows the old scheduler read: the
-        page-table snapshot before the launch and ``lens0 + s + 1`` per
-        step ``s``."""
-        b = self.batch
-        lens0 = self.lengths().copy()
-        table = np.array(self._host_table())
-        slots = np.nonzero(np.asarray(active))[0]
-        accounts: List[Tuple[Traffic, tuple]] = []
-        for s in range(n):
-            step_lens = np.zeros((b,), np.int64)
-            for slot in slots:
-                step_lens[slot] = int(lens0[slot]) + s + 1
-            streams = page_table_streams(
-                table, step_lens,
-                self.cache.page_size, self.model.kv_token_bytes,
-                kv_elem_bits=self.model.kv_elem_bits,
-                scale_bytes_per_token=self.model.kv_scale_token_bytes,
-            )
-            traffic = paged_decode_traffic(
-                step_lens[step_lens > 0], self.cache.page_size,
-                self.cache.pages_per_seq, self.model.kv_token_bytes,
-                elem_bits=self.model.kv_elem_bits,
-                scale_bytes_per_token=self.model.kv_scale_token_bytes,
-            )
-            accounts.append((traffic, streams))
-        return accounts
-
-    def prefill_account(self, slots, starts, counts) -> Tuple[Traffic, tuple]:
-        table = self._host_table()
-        traffic = paged_prefill_traffic(
-            starts, counts,
-            self.cache.page_size, self.cache.pages_per_seq,
-            self.model.kv_token_bytes,
-            elem_bits=self.model.kv_elem_bits,
-            scale_bytes_per_token=self.model.kv_scale_token_bytes,
-        )
-        streams = prefill_table_streams(
-            table[slots],  # fancy indexing: bounded per-row copy
-            starts, counts,
-            self.cache.page_size, self.model.kv_token_bytes,
-            kv_elem_bits=self.model.kv_elem_bits,
-            scale_bytes_per_token=self.model.kv_scale_token_bytes,
-        )
-        return traffic, streams
-
     # -- prefix sharing -----------------------------------------------------
 
     @property
@@ -956,22 +817,6 @@ class PagedFamily(ServableFamily):
             slot, lo_token, hi_token
         )
         return n_cow
-
-    def share_account(self, shared_tokens: int,
-                      unit_ids: Sequence[int]) -> Tuple[Traffic, tuple]:
-        page = self.cache.page_size
-        traffic = prefix_share_traffic(
-            shared_tokens, len(unit_ids), page,
-            self.model.kv_token_bytes,
-            elem_bits=self.model.kv_elem_bits,
-            scale_bytes_per_token=self.model.kv_scale_token_bytes,
-        )
-        streams = share_table_streams(
-            unit_ids, page, self.model.kv_token_bytes,
-            kv_elem_bits=self.model.kv_elem_bits,
-            scale_bytes_per_token=self.model.kv_scale_token_bytes,
-        )
-        return traffic, streams
 
     # -- invariants ---------------------------------------------------------
 

@@ -2,13 +2,8 @@
 
 The transformer serving stack moves a *growing* KV cache through indirect
 (page-table) bursts; recurrent architectures invert the memory story: each
-sequence owns a **fixed-size** state vector, one slot per resident, laid out
-``(layer, slot, *row)`` so a sequence's rows sit at a fixed stride of
-``batch`` rows in the flattened pool.  That makes recurrent serving the
-strided-burst dialect of AXI-Pack — no memory-resident index vector exists,
-the stride in the request descriptor is the whole addressing metadata — and
-the natural counterpart to compare against the paged families' indirect
-accounting in ``BENCH_serving.json``.
+sequence owns a **fixed-size** state vector, one slot per resident, in
+pools laid out ``(layer, slot, *row)``.
 
 Pieces:
 
@@ -35,11 +30,9 @@ Pieces:
   (``units_for(n) == 1``), capacity is unbounded so growth/lookahead never
   fire, eviction-replay re-prefills from a zeroed state row (the prefill
   program zeroes each row it starts at position 0, so
-  :meth:`RecurrentFamily.replay` launches nothing), and the accounting
-  dialect is :func:`repro.core.packing.recurrent_decode_traffic`
-  + :func:`repro.core.streams.recurrent_state_streams`.
+  :meth:`RecurrentFamily.replay` launches nothing).
 * :func:`recurrent_reference_generate` — the direct forward, with no
-  scheduler, that the tests and the serving benchmark compare against.
+  scheduler, that the tests compare against.
 """
 from __future__ import annotations
 
@@ -52,12 +45,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig
-from repro.core.packing import (
-    Traffic,
-    recurrent_decode_traffic,
-    recurrent_prefill_traffic,
-)
-from repro.core.streams import recurrent_state_streams
 from repro.models import mamba as mamba_mod
 from repro.models import rwkv6 as rwkv_mod
 from repro.models.common import Param, init_params, rms_norm, stack_layer_defs
@@ -84,8 +71,8 @@ UNBOUNDED_TOKENS = 1 << 62
 class RecurrentStatePool:
     """Donated per-sequence state pools + host-side slot bookkeeping.
 
-    ``tensors`` maps state name → a ``(n_layers, batch, *row)`` array (the
-    layer-major layout the strided accounting assumes).  Device state is
+    ``tensors`` maps state name → a ``(n_layers, batch, *row)`` array
+    (layer-major).  Device state is
     functional: every fused launch donates the pools and the family rebinds
     ``tensors``, exactly like the paged cache's page pools.
     """
@@ -117,21 +104,6 @@ class RecurrentStatePool:
     @property
     def n_free(self) -> int:
         return int(self.batch - self.owned.sum())
-
-    @property
-    def row_bytes(self) -> Tuple[int, ...]:
-        """Per-layer row footprint of each state tensor (stream elements)."""
-        lb = self.n_layers * self.batch
-        return tuple(int(t.nbytes) // lb for t in self.tensors.values())
-
-    @property
-    def state_slot_bytes(self) -> int:
-        """Bytes of one sequence's full state (all layers, all tensors)."""
-        return sum(int(t.nbytes) // self.batch for t in self.tensors.values())
-
-    @property
-    def pool_bytes(self) -> int:
-        return sum(int(t.nbytes) for t in self.tensors.values())
 
 
 class RecurrentLM:
@@ -455,10 +427,6 @@ class RecurrentFamily(ServableFamily):
     def slot_token_capacity(self) -> int:
         return UNBOUNDED_TOKENS
 
-    @property
-    def pool_bytes(self) -> int:
-        return self.pool.pool_bytes
-
     def units_for(self, n_tokens: int) -> int:
         return 1 if n_tokens > 0 else 0
 
@@ -467,9 +435,6 @@ class RecurrentFamily(ServableFamily):
 
     def token_capacity(self, slot: int) -> int:
         return UNBOUNDED_TOKENS if self.pool.owned[slot] else 0
-
-    def state_bytes(self, n_tokens: int) -> int:
-        return self.pool.state_slot_bytes if n_tokens > 0 else 0
 
     def lengths(self) -> np.ndarray:
         return self.pool.lengths_host
@@ -522,32 +487,6 @@ class RecurrentFamily(ServableFamily):
         )
         self.pool.lengths_host[np.asarray(active, bool)] += int(n)
         return out
-
-    # -- accounting ---------------------------------------------------------
-
-    def step_streams(self, active: np.ndarray,
-                     n: int) -> List[Tuple[Traffic, tuple]]:
-        slots = [int(s) for s in np.nonzero(np.asarray(active, bool))[0]]
-        traffic = recurrent_decode_traffic(
-            len(slots), self.batch, self.pool.state_slot_bytes
-        )
-        streams = recurrent_state_streams(
-            slots, self.batch, self.pool.n_layers, self.pool.row_bytes
-        )
-        # State size is length-free, so every fused step moves identical
-        # bytes — one record shared n times, like a step-at-a-time run.
-        return [(traffic, streams)] * int(n)
-
-    def prefill_account(self, slots: np.ndarray, starts: np.ndarray,
-                        counts: np.ndarray) -> Tuple[Traffic, tuple]:
-        traffic = recurrent_prefill_traffic(
-            counts, self.batch, self.pool.state_slot_bytes
-        )
-        streams = recurrent_state_streams(
-            [int(s) for s in slots], self.batch, self.pool.n_layers,
-            self.pool.row_bytes,
-        )
-        return traffic, streams
 
     # -- invariants ---------------------------------------------------------
 

@@ -4,19 +4,16 @@ The serving-side payoff of the paper's irregular streams: a fixed physical
 resource pool, per-sequence descriptors as memory-resident index vectors,
 and a scheduler that keeps the pool full of *useful* state.  Requests of
 arbitrary length enter and leave mid-flight; every decode step is one
-batched fused launch whose operands — and whose BASE-vs-PACK traffic
-accounting — come from the family's own stream descriptors.
+batched fused launch over the family's pool.
 
 The scheduler is **family-agnostic**: it drives exactly one
 :class:`repro.serve.family.ServableFamily` and speaks only the protocol —
 resource *units* (pages for paged attention, state slots for recurrent
-models), ``prefill_batch``/``decode_steps`` for compute,
-``step_streams``/``prefill_account`` for accounting, and
+models), ``prefill_batch``/``decode_steps`` for compute, and
 ``alloc_state``/``grow``/``release``/``replay`` for the lifecycle.  No
 ``isinstance`` check or KV-specific attribute appears below; the paged
-transformer path (``repro.serve.paged_lm.PagedFamily``, indirect page-walk
-streams) and the recurrent path (``repro.serve.recurrent_lm``, strided
-state streams) run through the same code.
+transformer path (``repro.serve.paged_lm.PagedFamily``) and the recurrent
+path (``repro.serve.recurrent_lm``) run through the same code.
 
 Scheduling policy (vLLM-shaped, deliberately simple and deterministic):
 
@@ -54,8 +51,7 @@ Scheduling policy (vLLM-shaped, deliberately simple and deterministic):
 * **Prefill** — chunked and batched: each scheduler step advances *every*
   pending request by one fixed-size chunk in a single family
   ``prefill_batch`` call, interleaved with decode (prefill never starves
-  decode and vice versa).  Each prefill step records the family's
-  ``prefill_account`` descriptors the way decode steps record theirs.
+  decode and vice versa).
 * **Decode fast path** — between scheduling boundaries (admission, prefill,
   unit growth, retirement) every decode quantity is known on the host, so
   the scheduler *fuses* all steps up to the next boundary into one
@@ -63,9 +59,7 @@ Scheduling policy (vLLM-shaped, deliberately simple and deterministic):
   and syncs the token matrix back exactly once per boundary.  When nothing
   can be admitted or prefilled first, units for each request's remaining
   generation are preallocated from the free pool (lookahead never evicts),
-  so growth stops being a boundary.  Per-step records come from the
-  family's ``step_streams`` (host shadows only), so the PACK-vs-BASE
-  accounting is unchanged from the step-at-a-time path.
+  so growth stops being a boundary.
 * **Eviction** — when a decode step needs a unit and the pool is empty, the
   *youngest* resident request is preempted: its units return to the pool and
   it re-enters the queue front.  On re-admission ``replay(slot)`` resets
@@ -100,13 +94,9 @@ Scheduling policy (vLLM-shaped, deliberately simple and deterministic):
 * **Hooks** — ``on_token(request, token)`` streams each newly generated
   token; ``on_finish(request)`` fires at completion.
 
-Every decode step records a :class:`repro.core.packing.Traffic`: BASE is
-the padded contiguous state a packing-oblivious server would stream, PACK
-is the mapped units plus the near-memory descriptor fetch — connecting
-serving throughput back to the Fig. 3 bus model.  The stream dialect is
-the family's: :class:`repro.core.streams.IndirectStream` page walks for
-paged KV (8-bit element width under int8 pools), strided read-modify-write
-:class:`repro.core.streams.StridedStream` pairs for recurrent state.
+:class:`ServeStats` counts what the scheduler did (steps, tokens, shared
+pages, evictions, rejections) in a fixed set of integers, however long the
+scheduler runs.
 """
 from __future__ import annotations
 
@@ -121,7 +111,6 @@ from typing import (
 
 import numpy as np
 
-from repro.core.packing import Traffic
 from repro.runtime.tracing import install_gc_spans, span
 from .family import ServableFamily
 from .faults import FaultPlan
@@ -134,7 +123,6 @@ __all__ = [
     "RequestState",
     "Scheduler",
     "SchedulerStalledError",
-    "StepRecord",
     "ServeStats",
     "build_prefill_rows",
 ]
@@ -294,8 +282,7 @@ def build_prefill_rows(
     stays O(log batch).  Returns ``(tokens (R, chunk), counts, slots,
     starts)`` with zero-filled padding rows past the pending set.  Single
     source of the bucketing/assembly shared by ``Scheduler._prefill_all``
-    and the serving benchmark's isolated prefill phase — so the benchmark
-    times exactly the calls the scheduler issues.
+    and :func:`repro.serve.recurrent_reference_generate`.
     """
     rows = min(1 << max(len(items) - 1, 0).bit_length(), batch)
     toks = np.zeros((rows, chunk), np.int32)
@@ -393,25 +380,23 @@ class Request:
 
 
 @dataclasses.dataclass
-class StepRecord:
-    """Per-model-step accounting (a fused launch emits one record per step).
+class ServeStats:
+    """What the scheduler did, as counters.
 
-    ``streams`` holds the family's descriptor dialect —
-    ``IndirectStream`` page walks for paged KV, ``StridedStream``
-    read-modify-write pairs for recurrent state.
+    A fused launch of ``n`` steps counts ``n`` decode steps (verify steps
+    included, and counted again in ``spec_steps``); every batched prefill
+    call counts one prefill step.  ``tokens`` counts tokens appended to
+    request outputs by prefill, decode and verify (replayed tokens are not
+    counted again).  ``share_events`` counts admissions that mapped a
+    shared prefix, and ``shared_pages`` the pages they mapped.
     """
 
-    step: int
-    kind: str                 # 'decode' | 'verify' | 'prefill' | 'share'
-    n_active: int
-    new_tokens: int
-    traffic: Optional[Traffic]
-    streams: Tuple[Any, ...] = ()
-
-
-@dataclasses.dataclass
-class ServeStats:
-    records: List[StepRecord] = dataclasses.field(default_factory=list)
+    decode_steps: int = 0           # model steps of decode and verify launches
+    spec_steps: int = 0             # of which speculative verify steps
+    prefill_steps: int = 0          # batched prefill calls
+    tokens: int = 0                 # tokens appended to request outputs
+    share_events: int = 0           # admissions that mapped a shared prefix
+    shared_pages: int = 0           # pages mapped by refcount bump
     n_evictions: int = 0            # evict-and-requeue events (replayable)
     prefill_tokens_saved: int = 0   # prompt tokens mapped instead of prefilled
     cow_copies: int = 0             # copy-on-write page copies performed
@@ -422,13 +407,13 @@ class ServeStats:
     n_stragglers: int = 0           # watchdog-flagged slow steps
     n_prefix_drops: int = 0         # fault-injected prefix-index drops
     n_prefix_drop_skips: int = 0    # prefix-drop faults skipped (no index)
-    # Speculative-decoding token accounting (kind='verify' records).  A
-    # verify step *drafts* spec_k - 1 tokens per active slot, *accepts* the
-    # matched prefix of them, and *emits* accepted + 1 bonus tokens into
-    # request outputs (minus any dropped past a request's max_new).  Only
-    # emitted tokens ever enter ``Request.generated`` — so ``replay_cost``
-    # (and thus ``replay_budget`` charging) counts accepted work only,
-    # never the drafts the verifier rejected.
+    # Speculative-decoding token accounting.  A verify step *drafts*
+    # spec_k - 1 tokens per active slot, *accepts* the matched prefix of
+    # them, and *emits* accepted + 1 bonus tokens into request outputs
+    # (minus any dropped past a request's max_new).  Only emitted tokens
+    # ever enter ``Request.generated`` — so ``replay_cost`` (and thus
+    # ``replay_budget`` charging) counts accepted work only, never the
+    # drafts the verifier rejected.
     n_drafted: int = 0              # draft tokens proposed to the verifier
     n_accepted: int = 0             # draft tokens the verifier accepted
     n_emitted: int = 0              # tokens appended to request outputs
@@ -437,120 +422,6 @@ class ServeStats:
     def acceptance_rate(self) -> float:
         """Fraction of drafted tokens the verifier accepted."""
         return self.n_accepted / self.n_drafted if self.n_drafted else 0.0
-
-    #: Decode-side record kinds: plain fused decode and speculative verify
-    #: launches both stream the same per-step KV state, so the serving
-    #: BASE/PACK aggregates fold them together.
-    _DECODE_KINDS = ("decode", "verify")
-
-    @property
-    def decode_steps(self) -> int:
-        return sum(1 for r in self.records if r.kind in self._DECODE_KINDS)
-
-    @property
-    def spec_steps(self) -> int:
-        return sum(1 for r in self.records if r.kind == "verify")
-
-    @property
-    def tokens(self) -> int:
-        return sum(r.new_tokens for r in self.records)
-
-    def _sum(self, attr: str, kind=("decode", "verify")) -> int:
-        kinds = (kind,) if isinstance(kind, str) else kind
-        return sum(
-            getattr(r.traffic, attr)
-            for r in self.records
-            if r.kind in kinds and r.traffic is not None
-        )
-
-    @property
-    def base_bytes(self) -> int:
-        return self._sum("base_bytes")
-
-    @property
-    def pack_bytes(self) -> int:
-        return self._sum("pack_bytes") + self._sum("index_bus_bytes_pack")
-
-    @property
-    def useful_bytes(self) -> int:
-        return self._sum("useful_bytes")
-
-    @property
-    def base_efficiency(self) -> float:
-        return self.useful_bytes / self.base_bytes if self.base_bytes else 1.0
-
-    @property
-    def pack_efficiency(self) -> float:
-        return self.useful_bytes / self.pack_bytes if self.pack_bytes else 1.0
-
-    # -- prefill-side aggregates (same Traffic records, kind='prefill') ------
-
-    @property
-    def prefill_steps(self) -> int:
-        return sum(1 for r in self.records if r.kind == "prefill")
-
-    @property
-    def prefill_base_bytes(self) -> int:
-        return self._sum("base_bytes", "prefill")
-
-    @property
-    def prefill_pack_bytes(self) -> int:
-        return (self._sum("pack_bytes", "prefill")
-                + self._sum("index_bus_bytes_pack", "prefill"))
-
-    @property
-    def prefill_useful_bytes(self) -> int:
-        return self._sum("useful_bytes", "prefill")
-
-    @property
-    def prefill_base_efficiency(self) -> float:
-        b = self.prefill_base_bytes
-        return self.prefill_useful_bytes / b if b else 1.0
-
-    @property
-    def prefill_pack_efficiency(self) -> float:
-        p = self.prefill_pack_bytes
-        return self.prefill_useful_bytes / p if p else 1.0
-
-    # -- prefix-sharing aggregates (kind='share' records) --------------------
-
-    @property
-    def shared_pages(self) -> int:
-        """Physical pages mapped by refcount bump instead of prefilled."""
-        return sum(
-            r.traffic.shared_pages
-            for r in self.records
-            if r.traffic is not None
-        )
-
-    @property
-    def share_events(self) -> int:
-        return sum(1 for r in self.records if r.kind == "share")
-
-    @property
-    def shared_useful_bytes(self) -> int:
-        return self._sum("useful_bytes", "share")
-
-    @property
-    def shared_index_bytes(self) -> int:
-        return self._sum("index_bus_bytes_pack", "share")
-
-    @property
-    def prefill_effective_pack_efficiency(self) -> float:
-        """Prefill-side PACK efficiency with dedup folded in.
-
-        Bytes of prompt KV the pool ends up serving (prefilled + shared)
-        over the bytes PACK actually moved to get there (prefill payload
-        and table fetches, plus the share remaps' table fetches).  Exceeds
-        :attr:`prefill_pack_efficiency` exactly when prefix sharing elided
-        prefill work — the dedup-before-packing multiplier; unlike a plain
-        packing ratio it can exceed 1.
-        """
-        moved = (self.prefill_pack_bytes
-                 + self._sum("pack_bytes", "share")
-                 + self.shared_index_bytes)
-        served = self.prefill_useful_bytes + self.shared_useful_bytes
-        return served / moved if moved else 1.0
 
 
 class Scheduler:
@@ -975,14 +846,8 @@ class Scheduler:
                 # re-derives the mappings, so re-admission reuses the pages
                 # (bit-identical KV, int8 scales included) it had before.
                 self.stats.prefill_tokens_saved += tail_start
-                with span("sched.account", kind="share", steps=1):
-                    traffic, streams = self.family.share_account(
-                        tail_start, shared
-                    )
-                self.stats.records.append(StepRecord(
-                    step=self._step, kind="share", n_active=1, new_tokens=0,
-                    traffic=traffic, streams=streams,
-                ))
+                self.stats.share_events += 1
+                self.stats.shared_pages += len(shared)
             self.resident.append(r)
 
     # -- prefill ------------------------------------------------------------
@@ -1007,7 +872,7 @@ class Scheduler:
                     r.slot, int(starts[i]), int(starts[i] + counts[i]) - 1
                 )
         logits = self.family.prefill_batch(toks, counts, slots, starts)
-        new_tokens = 0
+        self.stats.prefill_steps += 1
         completed = []
         for i, r in enumerate(pending):
             r.prefill_pos += int(counts[i])
@@ -1037,20 +902,9 @@ class Scheduler:
                 for i, r in completed:
                     tok = int(np.argmax(lg[i, : self.family.vocab]))
                     r.generated.append(tok)
-                    new_tokens += 1
+                    self.stats.tokens += 1
                     if r.on_token:
                         r.on_token(r, tok)
-        # Stream descriptors + traffic in the family's own dialect, from
-        # the same host-shadow math its kernels resolve (as decode does).
-        n = len(pending)
-        with span("sched.account", kind="prefill", steps=1):
-            traffic, streams = self.family.prefill_account(
-                slots[:n], starts[:n], counts[:n]
-            )
-        self.stats.records.append(StepRecord(
-            step=self._step, kind="prefill", n_active=n,
-            new_tokens=new_tokens, traffic=traffic, streams=streams,
-        ))
 
     # -- decode -------------------------------------------------------------
 
@@ -1115,29 +969,20 @@ class Scheduler:
         if k > 1:
             self._decode_speculative(running, tokens, active, n)
             return
-        # Per-step accounting snapshots come *before* the launch mutates the
-        # family's host shadows — identical records to a step-at-a-time run.
-        with span("sched.account", kind="decode", steps=n):
-            accounts = self.family.step_streams(active, n)
         out = self.family.decode_steps(tokens, active, n)
+        self.stats.decode_steps += n
 
         with span("sched.handoff"):
             for s in range(n):
-                traffic, streams = accounts[s]
-                new_tokens = 0
                 for r in running:
                     r.fed += 1
                     if r.fed < len(r.generated):
                         continue  # replay after eviction: output already known
                     tok = int(out[s, r.slot])
                     r.generated.append(tok)
-                    new_tokens += 1
+                    self.stats.tokens += 1
                     if r.on_token:
                         r.on_token(r, tok)
-                self.stats.records.append(StepRecord(
-                    step=self._step, kind="decode", n_active=len(running),
-                    new_tokens=new_tokens, traffic=traffic, streams=streams,
-                ))
 
     def _decode_speculative(self, running: List[Request],
                             tokens: np.ndarray, active: np.ndarray,
@@ -1146,9 +991,8 @@ class Scheduler:
 
         One ``verify_steps`` launch covers ``n`` draft→verify→accept
         iterations; the emitted tokens per (step, slot) are data-dependent,
-        so traffic accounting runs *after* the launch from the pre-launch
-        length shadow (``verify_account``), and the host consumption loop
-        walks ``counts[s, slot]`` emissions instead of exactly one.  A
+        so the host consumption loop walks ``counts[s, slot]`` emissions
+        instead of exactly one.  A
         request that completes mid-launch simply drops the surplus
         emissions (the device kept verifying its own greedy continuation;
         the extra KV dies with the slot at retirement).  Replay is the
@@ -1157,14 +1001,11 @@ class Scheduler:
         ``fed`` catches up with ``generated``.
         """
         k = self.family.spec_k
-        lens0 = np.array(self._lengths(), copy=True)
         toks, counts = self.family.verify_steps(tokens, active, n)
-        with span("sched.account", kind="verify", steps=n):
-            accounts = self.family.verify_account(lens0, active, counts)
+        self.stats.decode_steps += n
+        self.stats.spec_steps += n
         with span("sched.handoff"):
             for s in range(n):
-                traffic, streams = accounts[s]
-                new_tokens = 0
                 for r in running:
                     c = int(counts[s, r.slot])
                     self.stats.n_drafted += k - 1
@@ -1177,14 +1018,10 @@ class Scheduler:
                             continue  # replay after eviction: output known
                         tok = int(toks[s, r.slot, i])
                         r.generated.append(tok)
-                        new_tokens += 1
+                        self.stats.tokens += 1
                         self.stats.n_emitted += 1
                         if r.on_token:
                             r.on_token(r, tok)
-                self.stats.records.append(StepRecord(
-                    step=self._step, kind="verify", n_active=len(running),
-                    new_tokens=new_tokens, traffic=traffic, streams=streams,
-                ))
 
     def _grow_units(self, running: List[Request]) -> List[Request]:
         """Allocate a unit for every running request whose next token lands

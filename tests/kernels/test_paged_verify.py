@@ -282,8 +282,9 @@ def test_scheduler_matches_static_batch(spec_k):
     assert st.n_drafted > 0 and st.n_accepted > 0
     assert st.n_emitted == sum(len(v) for v in out.values()) - len(out)
     assert 0.0 < st.acceptance_rate <= 1.0
-    # Verify launches carry decode-side traffic accounting.
-    assert st.pack_bytes > 0 and st.base_bytes > 0 and st.useful_bytes > 0
+    # Every verify step is a decode step, and every token counts once.
+    assert st.decode_steps == st.spec_steps
+    assert st.tokens == sum(len(v) for v in out.values())
 
 
 def test_scheduler_spec_k1_is_plain_decode_path():

@@ -3,11 +3,10 @@
 The conflict counts below are hand-derived from the crossbar's arbitration
 rules (one grant per bank per cycle, words issued round-robin across
 ``n_ports`` lanes, one packed beat retired per cycle) on streams small
-enough to trace by hand, then pinned exactly.  The serving-side replay
-regression feeds the scheduler's own page-table stream descriptors —
-including the prefix-sharing ``remap_only`` kind — through the simulator
-and pins their cycle counts, so the accounting path from scheduler records
-to Fig.-5-style endpoint numbers cannot silently drift.
+enough to trace by hand, then pinned exactly.  The page-walk replay
+regression feeds page-table stream descriptors — one indirect stream per
+sequence over its page-table row, and the prefix-sharing ``remap_only``
+kind — through the simulator and pins their cycle counts.
 """
 import math
 
@@ -22,12 +21,7 @@ from repro.core.banksim import (
     simulate_words,
     strided_utilization,
 )
-from repro.core.streams import (
-    IndirectStream,
-    StridedStream,
-    page_table_streams,
-    share_table_streams,
-)
+from repro.core.streams import IndirectStream, StridedStream
 
 
 # ---------------------------------------------------------------------------
@@ -114,18 +108,31 @@ def test_indirect_utilization_below_index_ceiling():
 
 
 # ---------------------------------------------------------------------------
-# Serving replay: scheduler descriptors through the endpoint
+# Page walks: paged-KV descriptors through the endpoint
 # ---------------------------------------------------------------------------
+
+#: One KV page as a stream element: 4 tokens of 64 bytes, in bits.
+PAGE_BITS = 4 * 64 * 8
+
+
+def _page_walk(row, length, page=4, remap_only=False):
+    """The indirect stream over the pages holding ``length`` tokens of a
+    page-table ``row``: the table row is the memory-resident index vector."""
+    n = -(-length // page)
+    return IndirectStream(base=0, elem_bits=PAGE_BITS, count=n,
+                          indices=np.asarray(row[:n], dtype=np.int64),
+                          index_bits=32, remap_only=remap_only)
+
 
 def test_page_table_streams_replay_pinned():
     """The paged-KV gather descriptors (one indirect stream per active
-    sequence, elem_bits = one page's packed bytes) replay through the
-    simulator with pinned cycle counts: a 3-page walk costs 25 cycles
-    (24 data beats + 1 index stall) at the 8×17 endpoint."""
+    sequence, elem_bits = one page's bytes) replay through the simulator
+    with pinned cycle counts: a 3-page walk costs 25 cycles (24 data beats
+    + 1 index stall) at the 8×17 endpoint."""
     table = np.array([[3, 1, 2, 0], [5, 4, 0, 0]])
-    lengths = np.array([10, 6])
-    streams = page_table_streams(table, lengths, page_size=4, token_bytes=64)
-    assert len(streams) == 2
+    streams = [_page_walk(row, n) for row, n in zip(table, (10, 6))]
+    np.testing.assert_array_equal(streams[0].indices, [3, 1, 2])
+    np.testing.assert_array_equal(streams[1].indices, [5, 4])
     cfg = BankConfig(n_ports=8, n_banks=17)
     r0, r1 = (simulate_stream(s, cfg) for s in streams)
     assert (r0.cycles, r0.data_beats, r0.stall_cycles) == (25, 24, 1)
@@ -136,24 +143,16 @@ def test_page_table_streams_replay_pinned():
 def test_share_table_streams_remap_only_replay():
     """Prefix-sharing remap descriptors move no KV payload: only the table
     entries (one 32-bit index per shared page) cross the endpoint, so a
-    3-page share drains in a single cycle — the dedup multiplier the
-    accounting claims is really there at the endpoint."""
-    (s,) = share_table_streams([3, 1, 2], page_size=4, token_bytes=64)
+    3-page share drains in a single cycle."""
+    s = _page_walk([3, 1, 2], 12, remap_only=True)
     assert s.remap_only and s.count == 3
     r = simulate_stream(s, BankConfig(n_ports=8, n_banks=17))
     assert r == type(r)(cycles=1, data_beats=1, utilization=1.0,
                         stall_cycles=0)
     # The equivalent *copy* would have drained the full page payload:
-    full = simulate_stream(
-        page_table_streams(
-            np.array([[3, 1, 2, 0]]), np.array([12]),
-            page_size=4, token_bytes=64,
-        )[0],
-        BankConfig(n_ports=8, n_banks=17),
-    )
+    full = simulate_stream(_page_walk([3, 1, 2, 0], 12),
+                           BankConfig(n_ports=8, n_banks=17))
     assert full.data_beats > r.data_beats * 8  # >8× fewer beats via remap
-
-    assert share_table_streams([], page_size=4, token_bytes=64) == ()
 
 
 # ---------------------------------------------------------------------------

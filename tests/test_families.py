@@ -12,13 +12,11 @@ What this file locks down (ISSUE 8 acceptance criteria):
   family-generic invariant oracle asserted after every step;
 * the strided state read/write ops match their ref oracles bitwise and
   never disturb non-target rows;
-* the strided-burst accounting dialect (`recurrent_state_streams`,
-  `recurrent_decode_traffic`/`recurrent_prefill_traffic`) is internally
-  consistent: PACK efficiency ≈ 1, BASE efficiency = occupancy, no index
-  bus term;
-* the scheduler module itself never references the paged implementation —
-  it speaks only the `ServableFamily` protocol.
+* the scheduler and the protocol modules never reference the paged
+  implementation or the paper's traffic model — the scheduler speaks only
+  the `ServableFamily` protocol.
 """
+import ast
 import inspect
 
 import jax
@@ -27,17 +25,9 @@ import numpy as np
 import pytest
 
 from repro.configs import smoke_config
-from repro.core.packing import (
-    recurrent_decode_traffic,
-    recurrent_prefill_traffic,
-)
-from repro.core.streams import (
-    BurstKind,
-    StridedStream,
-    recurrent_state_streams,
-)
 from repro.serve import (
     OutOfPages,
+    PagedFamily,
     PagedKVCache,
     PagedLM,
     RecurrentFamily,
@@ -46,6 +36,7 @@ from repro.serve import (
     Request,
     RequestState,
     Scheduler,
+    ServableFamily,
     check_scheduler_invariants,
     recurrent_reference_generate,
     static_batch_generate,
@@ -293,13 +284,12 @@ def test_mixed_families_interleaved():
     r_out = {rid: r.generated for rid, r in rs.finished.items()}
     assert p_out == {i: p_want[i] for i in range(3)}
     assert r_out == {i: r_want[i] for i in range(3)}
-    # The two families report disjoint accounting dialects.
-    assert any(s.kind is BurstKind.INDIRECT
-               for rec in ps.stats.records for s in rec.streams)
-    assert all(s.kind is not BurstKind.INDIRECT
-               for rec in rs.stats.records for s in rec.streams)
-    assert any(s.kind is BurstKind.STRIDED
-               for rec in rs.stats.records for s in rec.streams)
+    # Both families count the same way: every token once, each request
+    # max_new - 1 decode steps, the longest prompt 3 prefill chunks.
+    for st in (ps.stats, rs.stats):
+        assert st.tokens == 3 * max_new
+        assert st.decode_steps >= max_new - 1
+        assert st.prefill_steps >= 3
 
 
 # ---------------------------------------------------------------------------
@@ -345,66 +335,39 @@ def test_replay_zeroes_only_target_slot():
 
 
 # ---------------------------------------------------------------------------
-# Strided-burst accounting dialect
-# ---------------------------------------------------------------------------
-
-
-def test_recurrent_state_streams_descriptors():
-    streams = recurrent_state_streams([1, 3], batch=4, n_layers=2,
-                                      row_bytes=(4096, 512))
-    # 2 slots × 2 tensors × (read + write) = 8 descriptors.
-    assert len(streams) == 8
-    assert all(isinstance(s, StridedStream) for s in streams)
-    assert {s.base for s in streams} == {1, 3}
-    assert all(s.stride == 4 and s.count == 2 for s in streams)
-    assert {s.elem_bits for s in streams} == {4096 * 8, 512 * 8}
-    # batch == 1 degenerates to the contiguous BASE converter (stride 1).
-    assert all(s.stride == 1 for s in
-               recurrent_state_streams([0], 1, 2, (64,)))
-
-
-def test_recurrent_traffic_accounting():
-    sb = 1000
-    t = recurrent_decode_traffic(n_active=3, batch=8, state_bytes=sb)
-    assert t.useful_bytes == 2 * 3 * sb
-    assert t.base_bytes == 2 * 8 * sb
-    assert t.index_bus_bytes_pack == 0  # the stride IS the descriptor
-    assert t.useful_bytes <= t.pack_bytes < t.useful_bytes + 32
-    # Idle step moves nothing under PACK.
-    assert recurrent_decode_traffic(0, 8, sb).pack_bytes == 0
-    p = recurrent_prefill_traffic([4, 0, 2], batch=8, state_bytes=sb)
-    assert p.useful_bytes == 2 * 2 * sb  # two active rows, chunk-amortized
-    assert p.base_bytes == 2 * 8 * 4 * sb  # padded pool per chunk position
-
-
-def test_scheduler_records_strided_pack_efficiency():
-    rng = np.random.default_rng(29)
-    model = RecurrentLM(RWKV_CFG, jax.random.PRNGKey(0))
-    # 4 slots, 3 requests: occupancy < 1, so BASE pays for the idle row.
-    sched = Scheduler(model, model.init_pool(4), chunk=4)
-    reqs = [Request(rid=i, prompt=p, max_new=5)
-            for i, p in enumerate(_prompts(rng, RWKV_CFG.vocab, (8, 5, 7)))]
-    _drive(sched, reqs)
-    st = sched.stats
-    assert st.pack_bytes > 0 and st.base_bytes > 0
-    assert 0.9 <= st.pack_efficiency <= 1.0  # dense strided bursts
-    assert st.base_efficiency <= 0.75  # at most 3 of 4 rows ever live
-    assert st.pack_efficiency > st.base_efficiency
-
-
-# ---------------------------------------------------------------------------
 # Protocol purity: the scheduler speaks only ServableFamily
 # ---------------------------------------------------------------------------
 
 
-def test_scheduler_module_is_family_agnostic():
-    import repro.serve.scheduler as sched_mod
+#: The per-step traffic methods the protocol no longer has.
+ACCOUNTING = ("step_streams", "prefill_account", "verify_account",
+              "share_account", "state_bytes")
 
-    src = inspect.getsource(sched_mod)
-    assert "PagedLM" not in src
-    assert "PagedKVCache" not in src
-    assert "kv_pages" not in src and "page_table" not in src
-    assert "ServableFamily" in src
+
+@pytest.mark.parametrize("module", ["scheduler", "family"])
+def test_scheduler_module_is_family_agnostic(module):
+    mod = __import__(f"repro.serve.{module}", fromlist=[module])
+    tree = ast.parse(inspect.getsource(mod))
+    # Code only: the docstrings may name the families they abstract over.
+    for node in ast.walk(tree):
+        body = getattr(node, "body", None)
+        if (isinstance(body, list) and body
+                and isinstance(body[0], ast.Expr)
+                and isinstance(body[0].value, ast.Constant)
+                and isinstance(body[0].value.value, str)):
+            node.body = body[1:] or [ast.Pass()]
+    code = ast.unparse(tree)
+    assert "PagedLM" not in code
+    assert "PagedKVCache" not in code
+    assert "kv_pages" not in code and "page_table" not in code
+    assert "ServableFamily" in code
+    imported = [n.module or "" for n in ast.walk(tree)
+                if isinstance(n, ast.ImportFrom)]
+    imported += [a.name for n in ast.walk(tree) if isinstance(n, ast.Import)
+                 for a in n.names]
+    assert not any(m.startswith("repro.core") for m in imported), imported
+    for fam in (ServableFamily, PagedFamily, RecurrentFamily):
+        assert not [m for m in ACCOUNTING if hasattr(fam, m)], fam
 
 
 def test_scheduler_rejects_non_family():

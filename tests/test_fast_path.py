@@ -384,12 +384,21 @@ def test_scheduler_syncs_only_at_boundaries():
     prompts = _prompts(rng, (4,))
     cache = PagedKVCache.create(CFG, batch=1, max_len=64, page=8)
     sched = Scheduler(MODEL, cache, chunk=8)
+    fam = sched.family
+    launches = []  # (scheduler step, fused model steps) per decode call
+    decode_steps = fam.decode_steps
+
+    def count(tokens, active, n):
+        launches.append((sched._step, int(n)))
+        return decode_steps(tokens, active, n)
+
+    fam.decode_steps = count
     sched.submit(Request(rid=0, prompt=prompts[0], max_new=16))
     sched.run()
-    decode_records = [r for r in sched.stats.records if r.kind == "decode"]
-    sched_iters = len({r.step for r in decode_records})
-    assert len(decode_records) == 15          # max_new - 1 model steps
-    assert sched_iters < len(decode_records)  # fused: fewer syncs than steps
+    steps = sum(n for _, n in launches)
+    sched_iters = len({s for s, _ in launches})
+    assert steps == sched.stats.decode_steps == 15  # max_new - 1 model steps
+    assert sched_iters < steps                      # fused: fewer syncs
 
 
 def test_lookahead_pages_reclaimed_for_late_submission():

@@ -148,6 +148,50 @@ def test_chaos_pool_pressure_matrix(kv, sharing):
         assert sorted(chaos_sched.cache.free) == list(range(pool))
 
 
+
+# ---------------------------------------------------------------------------
+# Degradation: a mixed-SLA workload under shrinking pools
+# ---------------------------------------------------------------------------
+
+
+def _degradation_run(pool_frac, faults):
+    """Six requests (alternating priorities, deadlines on the odd half,
+    replay budgets on every third) for three slots, in a pool cut to
+    ``pool_frac`` of the pages all six need fully grown at once."""
+    page, chunk, max_len, max_new = 8, 8, 64, 8
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, CFG.vocab, int(n)).astype(np.int32)
+               for n in rng.integers(4, 25, 6)]
+    full = sum(-(-(len(p) + max_new - 1) // page) for p in prompts)
+    cache = PagedKVCache.create(CFG, batch=3, max_len=max_len, page=page,
+                                pool_pages=max(2, round(full * pool_frac)))
+    reqs = [Request(rid=i, prompt=p, max_new=max_new, priority=i % 2,
+                    deadline_steps=40 if i % 2 else None,
+                    replay_budget=(2 * (len(p) + max_new) if i % 3 == 0
+                                   else None))
+            for i, p in enumerate(prompts)]
+    sched = Scheduler(MODELS["fp32"], cache, chunk=chunk, faults=faults)
+    return chaos_drive(sched, reqs), reqs
+
+
+@pytest.mark.parametrize("pool_frac,chaos", [
+    (1.0, False), (0.5, False), (0.12, False), (0.5, True),
+], ids=["pool1", "pool0.5", "pool0.12", "chaos-pool0.5"])
+def test_degradation_all_terminal_outputs_match_full_pool(pool_frac, chaos):
+    """Under pool pressure, and under seeded faults on a halved pool, every
+    request reaches a terminal state and every finished output equals the
+    fault-free full-pool run's."""
+    reference, _ = _degradation_run(1.0, None)
+    assert len(reference) == 6
+    faults = (FaultPlan.random(0, n_steps=24, p_exhaust=0.3, p_deny=0.2)
+              if chaos else None)
+    out, reqs = _degradation_run(pool_frac, faults)
+    states = terminal_states(reqs)
+    assert len(states) == len(reqs)
+    for rid, toks in out.items():
+        assert toks == reference[rid]
+
+
 # ---------------------------------------------------------------------------
 # Targeted fault classes
 # ---------------------------------------------------------------------------

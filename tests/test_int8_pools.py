@@ -4,8 +4,7 @@ Quantize-on-write through both write ops (codes + per-(page-token, kv-head)
 scales through the same indirect burst), both attention kernels reading the
 quantized pool (in-VMEM dequant vs the shared ``dequantize_pages`` oracle
 rule), the engine/scheduler serving mode (``kv_dtype='int8'``: donated
-scale pools, eviction/replay rebuilding codes *and* scales bit-for-bit),
-and the 8-bit packing factor in the PACK traffic accounting.
+scale pools, eviction/replay rebuilding codes *and* scales bit-for-bit).
 """
 import jax
 import jax.numpy as jnp
@@ -13,14 +12,6 @@ import numpy as np
 import pytest
 
 from repro.configs import smoke_config
-from repro.core import (
-    elements_per_beat,
-    packed_token_bytes,
-    page_table_streams,
-    paged_decode_traffic,
-    paged_prefill_traffic,
-    prefill_table_streams,
-)
 from repro.kernels import ops, ref
 from repro.serve import (
     PagedKVCache,
@@ -324,7 +315,7 @@ def test_scheduler_rejects_mismatched_kv_dtype():
     with pytest.raises(ValueError):
         Scheduler(model, cache, chunk=4)
     # Width mismatches among float pools are rejected too — a bf16 model
-    # over fp32 pools would silently halve every PACK byte count.
+    # over fp32 pools would silently run its KV math at the wrong width.
     model16 = PagedLM(CFG, jax.random.PRNGKey(0), impl="ref",
                       kv_dtype="bf16")
     with pytest.raises(ValueError):
@@ -334,111 +325,3 @@ def test_scheduler_rejects_mismatched_kv_dtype():
     cache16 = PagedKVCache.create(CFG, batch=1, max_len=8, page=4,
                                   kv_dtype=model16.kv_dtype)
     Scheduler(model16, cache16, chunk=4)
-
-
-# ---------------------------------------------------------------------------
-# 8-bit PACK traffic accounting
-# ---------------------------------------------------------------------------
-
-
-def test_packed_token_bytes_packing_factor():
-    # 8-bit elements quadruple the FP32 packing factor (bus/elem, §II-C)...
-    assert elements_per_beat(256, 8) == 4 * elements_per_beat(256, 32)
-    # ...which is exactly the byte scaling packed_token_bytes applies.
-    assert packed_token_bytes(256, elem_bits=8) * 4 == packed_token_bytes(256)
-    assert packed_token_bytes(256, elem_bits=16) * 2 == packed_token_bytes(256)
-    assert packed_token_bytes(256, elem_bits=8, scale_bytes_per_token=16) \
-        == 256 // 4 + 16
-
-
-def test_paged_decode_traffic_elem8_vs_elem32():
-    kw = dict(lengths=[5, 12], page_size=4, pages_per_seq=4, token_bytes=256)
-    t32 = paged_decode_traffic(**kw)
-    t8 = paged_decode_traffic(elem_bits=8, **kw)
-    # BASE is the packing-oblivious full-width stream: unchanged.
-    assert t8.base_bytes == t32.base_bytes == 2 * 4 * 4 * 256
-    # PACK packs the narrow elements densely: exactly a quarter.
-    assert t8.pack_bytes * 4 == t32.pack_bytes == 5 * 4 * 256
-    assert t8.useful_bytes * 4 == t32.useful_bytes
-    # Index fetch is element-width independent.
-    assert t8.index_bus_bytes_pack == t32.index_bus_bytes_pack
-    # Efficiencies: PACK stays high; BASE quarters (narrow-beat penalty).
-    assert t8.pack_efficiency == pytest.approx(t32.pack_efficiency, rel=0.05)
-    assert t8.base_efficiency == pytest.approx(t32.base_efficiency / 4)
-
-
-def test_paged_decode_traffic_elem8_page_boundary():
-    """Length exactly on a page multiple: the 8-bit path must touch the same
-    page count as the 32-bit path (page math is width-independent)."""
-    for length in (4, 8, 16):  # page_size=4 → exact page multiples
-        t32 = paged_decode_traffic([length], 4, 4, 256)
-        t8 = paged_decode_traffic([length], 4, 4, 256, elem_bits=8)
-        pages = length // 4
-        assert t32.pack_bytes == pages * 4 * 256
-        assert t8.pack_bytes == pages * 4 * 64
-        assert t8.index_bus_bytes_pack == t32.index_bus_bytes_pack
-
-
-def test_paged_prefill_traffic_elem8_vs_elem32_with_boundary():
-    # Row 0 ends exactly on a page boundary (start+count = 8 = 2 pages);
-    # row 1 straddles; page math identical across widths.
-    kw = dict(starts=[4, 5], counts=[4, 6], page_size=4, pages_per_seq=4,
-              token_bytes=256)
-    t32 = paged_prefill_traffic(**kw)
-    t8 = paged_prefill_traffic(elem_bits=8, **kw)
-    ctx_pages = 2 + 3     # ceil(8/4), ceil(11/4)
-    chunk_pages = 1 + 2   # pages covering [4,8), [5,11)
-    assert t32.pack_bytes == (ctx_pages + chunk_pages) * 4 * 256
-    assert t8.pack_bytes * 4 == t32.pack_bytes
-    assert t8.base_bytes == t32.base_bytes       # full-width BASE + granules
-    assert t8.index_bus_bytes_pack == t32.index_bus_bytes_pack
-
-
-def test_int8_scale_sideband_charged_to_pack():
-    t = paged_decode_traffic([8], 4, 4, token_bytes=256, elem_bits=8,
-                             scale_bytes_per_token=16)
-    # 2 pages × 4 tokens × (64 narrow + 16 scale) bytes.
-    assert t.pack_bytes == 2 * 4 * (64 + 16)
-    assert t.useful_bytes == 8 * (64 + 16)
-
-
-def test_stream_descriptors_carry_packed_element_width():
-    table = np.array([[3, 1, 0, 0]])
-    streams32 = page_table_streams(table, np.array([5]), page_size=4,
-                                   token_bytes=256)
-    streams8 = page_table_streams(table, np.array([5]), page_size=4,
-                                  token_bytes=256, kv_elem_bits=8,
-                                  scale_bytes_per_token=16)
-    assert streams32[0].elem_bits == 4 * 256 * 8
-    assert streams8[0].elem_bits == 4 * (64 + 16) * 8
-    np.testing.assert_array_equal(streams8[0].indices, streams32[0].indices)
-    p8 = prefill_table_streams(table, np.array([0]), np.array([4]),
-                               page_size=4, token_bytes=256, kv_elem_bits=8)
-    assert all(s.elem_bits == 4 * 64 * 8 for s in p8)
-
-
-def test_int8_scheduler_stats_reflect_packing_factor():
-    """Same workload under fp32 and int8 pools: BASE bytes identical (the
-    packing-oblivious stream), PACK bytes ~quartered (up to the scale
-    sideband and granule rounding), so the PACK-vs-BASE win quadruples."""
-    rng = np.random.default_rng(7)
-    prompt_sets = [_prompts(rng, (6, 9))]
-    stats = {}
-    for kv_dtype in (None, "int8"):
-        model = PagedLM(CFG, jax.random.PRNGKey(0), impl="ref",
-                        kv_dtype=kv_dtype)
-        cache = PagedKVCache.create(CFG, batch=2, max_len=32, page=4,
-                                    kv_dtype=kv_dtype)
-        sched = Scheduler(model, cache, chunk=4)
-        for i, p in enumerate(prompt_sets[0]):
-            sched.submit(Request(rid=i, prompt=p, max_new=6))
-        sched.run()
-        stats[kv_dtype or "fp32"] = sched.stats
-    fp, i8 = stats["fp32"], stats["int8"]
-    assert i8.base_bytes == fp.base_bytes
-    assert i8.prefill_base_bytes == fp.prefill_base_bytes
-    # Scale sideband = 1/hd of the narrow payload here (hd=32): pack bytes
-    # land between a clean 1/4 and 1/4 · (1 + 4/hd) of the fp32 bytes.
-    assert fp.pack_bytes / 4 <= i8.pack_bytes < fp.pack_bytes / 3
-    assert i8.base_efficiency < fp.base_efficiency / 3
-    assert i8.pack_efficiency > 0.8 * fp.pack_efficiency

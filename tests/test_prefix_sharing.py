@@ -330,6 +330,35 @@ def test_concurrent_identical_prompts_share_via_deferral():
     assert out == out_plain
 
 
+
+@pytest.mark.parametrize("b", [2, 4, 8], ids=lambda b: f"b={b}")
+def test_shared_system_prompt_saves_prefill(b):
+    """``b`` prompts repeat one page-aligned 32-token system prompt with
+    short distinct tails: sharing skips at least 40% of the prompt tokens'
+    prefill, and the outputs are bit-for-bit the unshared run's."""
+    page, chunk, max_len, max_new = 8, 8, 64, 8
+    rng = np.random.default_rng(7)
+    system = rng.integers(0, CFG.vocab, 4 * page)
+    prompts = [np.concatenate([system, rng.integers(0, CFG.vocab, int(t))])
+               .astype(np.int32) for t in rng.integers(4, 9, b)]
+
+    def run(sharing):
+        cache = PagedKVCache.create(CFG, batch=b, max_len=max_len, page=page)
+        sched = Scheduler(MODELS["fp32"], cache, chunk=chunk,
+                          prefix_sharing=sharing)
+        out = drive(sched, [Request(rid=i, prompt=p, max_new=max_new)
+                            for i, p in enumerate(prompts)])
+        return out, sched.stats
+
+    out, st = run(True)
+    out_plain, st_plain = run(False)
+    assert out == out_plain
+    assert len(out) == b
+    saved_frac = st.prefill_tokens_saved / sum(len(p) for p in prompts)
+    assert saved_frac >= 0.4
+    assert st.share_events == b - 1 and st_plain.share_events == 0
+
+
 def test_prefix_sharing_requires_refcounted_cache():
     cache = PagedKVCache.create(CFG, batch=1, max_len=MAX_LEN, page=PAGE)
     import dataclasses

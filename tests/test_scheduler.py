@@ -1,31 +1,29 @@
 """Continuous-batching scheduler tests: page-pool admission control,
-eviction/re-admission round-trips, and bit-for-bit equivalence between
-scheduled continuous batching and a single static batch."""
+eviction/re-admission round-trips, bit-for-bit equivalence between
+scheduled continuous batching and a single static batch, and the
+scheduler's counters."""
+import glob
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import smoke_config
-from repro.core import (
-    IndirectStream,
-    page_table_streams,
-    paged_decode_traffic,
-    paged_prefill_traffic,
-    prefill_page_counts,
-    prefill_table_streams,
-)
 from repro.kernels import ops, ref
 from repro.serve import (
     OutOfPages,
     PagedKVCache,
     PagedLM,
+    RecurrentLM,
     RejectReason,
     Request,
     RequestRejected,
     RequestState,
     Scheduler,
     SchedulerStalledError,
+    ServeStats,
     static_batch_generate,
 )
 
@@ -199,10 +197,13 @@ def test_scheduled_equals_static_batch():
     # Streaming hooks: every token exactly once, in generation order per rid.
     for i in range(3):
         assert [t for rid, t in streamed if rid == i] == got[i]
-    # Traffic accounting: PACK strictly beats the padded BASE stream.
-    assert 0.0 < sched.stats.pack_efficiency <= 1.0
-    assert sched.stats.base_efficiency < sched.stats.pack_efficiency
-    assert sched.stats.tokens == 3 * max_new
+    # Counters: every token once; the longest prompt needs 3 prefill
+    # chunks, and each request max_new - 1 decode steps.
+    st = sched.stats
+    assert st.tokens == 3 * max_new
+    assert st.prefill_steps >= 3
+    assert st.decode_steps >= max_new - 1
+    assert st.spec_steps == st.share_events == st.shared_pages == 0
 
 
 # ---------------------------------------------------------------------------
@@ -254,86 +255,6 @@ def test_paged_kv_append_pallas_matches_ref():
 
 
 # ---------------------------------------------------------------------------
-# Stream descriptors + traffic accounting
-# ---------------------------------------------------------------------------
-
-
-def test_page_table_streams_describe_mapped_pages():
-    table = np.array([[3, 1, 0, 0], [2, 5, 7, 0], [0, 0, 0, 0]])
-    lengths = np.array([5, 12, 0])  # page=4 → 2, 3, 0 pages
-    streams = page_table_streams(table, lengths, page_size=4, token_bytes=256)
-    assert len(streams) == 2
-    assert all(isinstance(s, IndirectStream) for s in streams)
-    np.testing.assert_array_equal(streams[0].indices, [3, 1])
-    np.testing.assert_array_equal(streams[1].indices, [2, 5, 7])
-    assert streams[0].elem_bits == 4 * 256 * 8
-
-
-def test_prefill_table_streams_match_traffic_page_math():
-    """The prefill stream descriptors (context read + chunk write per row)
-    and ``paged_prefill_traffic`` must account exactly the same pages —
-    one source of truth (``prefill_page_counts``) for descriptors, bytes,
-    and the kernel's scalar-prefetch walk."""
-    table = np.array([[3, 1, 6, 0], [2, 5, 7, 4], [9, 8, 0, 0]])
-    starts = np.array([0, 5, 0])
-    counts = np.array([4, 6, 0])    # page=4: ctx 1|3|0, chunk 1|2|0 pages
-    streams = prefill_table_streams(
-        table, starts, counts, page_size=4, token_bytes=256
-    )
-    assert len(streams) == 4        # two per real row, none for padding
-    assert all(isinstance(s, IndirectStream) for s in streams)
-    np.testing.assert_array_equal(streams[0].indices, [3])        # row0 ctx
-    np.testing.assert_array_equal(streams[1].indices, [3])        # row0 chunk
-    np.testing.assert_array_equal(streams[2].indices, [2, 5, 7])  # row1 ctx
-    np.testing.assert_array_equal(streams[3].indices, [5, 7])     # row1 chunk
-    ctx, chunk = prefill_page_counts(starts, counts, 4)
-    assert sum(s.count for s in streams) == int(ctx.sum() + chunk.sum())
-    t = paged_prefill_traffic(
-        starts, counts, page_size=4, pages_per_seq=4, token_bytes=256
-    )
-    page_bytes = 4 * 256
-    assert t.pack_bytes == int(ctx.sum() + chunk.sum()) * page_bytes
-
-
-def test_scheduler_prefill_records_carry_streams():
-    """Prefill StepRecords expose their indirect-stream descriptors (as
-    decode records already do), and the stats aggregate the prefill-side
-    PACK/BASE traffic separately."""
-    rng = np.random.default_rng(21)
-    prompts = _prompts(rng, (9, 5))
-    cache = PagedKVCache.create(CFG, batch=2, max_len=32, page=4)
-    sched = Scheduler(MODEL, cache, chunk=4)
-    for i, p in enumerate(prompts):
-        sched.submit(Request(rid=i, prompt=p, max_new=3))
-    sched.run()
-    prefills = [r for r in sched.stats.records if r.kind == "prefill"]
-    assert prefills and all(r.streams for r in prefills)
-    assert all(isinstance(s, IndirectStream)
-               for r in prefills for s in r.streams)
-    # Stream pages == traffic pages, step by step.
-    page_bytes = 4 * MODEL.kv_token_bytes
-    for r in prefills:
-        assert sum(s.count for s in r.streams) * page_bytes \
-            == r.traffic.pack_bytes
-    assert sched.stats.prefill_steps == len(prefills)
-    assert 0.0 < sched.stats.prefill_pack_efficiency <= 1.0
-    assert sched.stats.prefill_pack_efficiency \
-        > sched.stats.prefill_base_efficiency
-
-
-def test_paged_decode_traffic_base_vs_pack():
-    t = paged_decode_traffic(
-        lengths=[5, 12], page_size=4, pages_per_seq=4, token_bytes=256
-    )
-    assert t.useful_bytes == 17 * 256
-    assert t.base_bytes == 2 * 4 * 4 * 256          # padded contiguous cache
-    assert t.pack_bytes == 5 * 4 * 256              # 5 mapped pages
-    assert t.index_bus_bytes_base == 0              # BASE has no indices
-    assert t.index_bus_bytes_pack == 32             # 5 ids, granule-rounded
-    assert t.pack_efficiency > t.base_efficiency
-
-
-# ---------------------------------------------------------------------------
 # Cache bookkeeping under mid-flight entry/exit
 # ---------------------------------------------------------------------------
 
@@ -355,3 +276,99 @@ def test_paged_cache_midflight_extend_and_release():
     assert int(np.asarray(cache.lengths)[1]) == 0
     cache = cache.release(0)
     assert sorted(cache.free) == list(range(8))
+
+
+# ---------------------------------------------------------------------------
+# ServeStats: fixed-size counters that agree with the launches
+# ---------------------------------------------------------------------------
+
+
+def _stats_scheduler(case):
+    """A scheduler for one ``test_serve_stats_counters`` case."""
+    if case in ("rwkv6", "mamba"):
+        cfg = smoke_config("rwkv6-3b" if case == "rwkv6" else "yi-6b")
+        model = RecurrentLM(cfg, jax.random.PRNGKey(0), arch=case)
+        return cfg, Scheduler(model, model.init_pool(2), chunk=4)
+    kv = "int8" if case == "int8" else None
+    model = PagedLM(CFG, jax.random.PRNGKey(0), impl="ref", kv_dtype=kv,
+                    spec_k=4 if case == "spec4" else 1)
+    cache = PagedKVCache.create(CFG, batch=2, max_len=32, page=4,
+                                kv_dtype=kv)
+    return CFG, Scheduler(model, cache, chunk=4,
+                          prefix_sharing=case == "sharing")
+
+
+def _launch_spans(tmp_path, fn):
+    """Run ``fn`` inside a profiler window; returns the stats of its
+    ``engine.launch`` spans."""
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                     recursive=True)[0]
+    return [dict(e.stats) for pl in ProfileData.from_file(path).planes
+            if pl.name == "/host:CPU" for ln in pl.lines for e in ln.events
+            if e.name == "engine.launch"]
+
+
+def _stored(stats: ServeStats) -> int:
+    """Values ``stats`` holds: one per counter, one per reject reason."""
+    return sum(len(v) if isinstance(v, dict) else 1
+               for v in vars(stats).values())
+
+
+@pytest.mark.parametrize(
+    "case", ["fp32", "int8", "spec4", "sharing", "rwkv6", "mamba"])
+def test_serve_stats_counters(tmp_path, case):
+    """Requests arrive for 100 steps and then drain.  The counters equal
+    what the launches and the requests show, and ``ServeStats`` holds as
+    many values after 100 steps as after 10."""
+    cfg, sched = _stats_scheduler(case)
+    rng = np.random.default_rng(11)
+    system = rng.integers(0, cfg.vocab, 8)
+    reqs = []
+    sizes = {}
+
+    def serve():
+        while sched._step < 100:
+            if len(sched.queue) < 2:
+                tail = rng.integers(0, cfg.vocab, int(rng.integers(1, 6)))
+                prompt = np.concatenate([system, tail]).astype(np.int32)
+                reqs.append(Request(rid=len(reqs), prompt=prompt,
+                                    max_new=int(rng.integers(2, 9))))
+                sched.submit(reqs[-1])
+            sched.step()
+            if sched._step in (10, 100):
+                sizes[sched._step] = _stored(sched.stats)
+        sched.run()
+
+    launches = _launch_spans(tmp_path, serve)
+    st = sched.stats
+    assert sizes[10] == sizes[100]
+    assert all(isinstance(v, int) for k, v in vars(st).items()
+               if k != "reject_reasons")
+    assert len(sched.finished) == len(reqs) > 10
+
+    def launched(*kinds):
+        return [sp for sp in launches if sp["kind"] in kinds]
+
+    assert st.prefill_steps == len(launched("prefill")) > 0
+    assert st.decode_steps == sum(sp["n"]
+                                  for sp in launched("decode", "verify"))
+    assert st.spec_steps == sum(sp["n"] for sp in launched("verify"))
+    assert st.tokens == sum(len(r.generated) for r in reqs)
+    assert (st.spec_steps > 0) == (case == "spec4")
+    # Every request's first token comes from its prefill; verify emits
+    # the rest.
+    assert st.n_emitted == (st.tokens - len(reqs) if case == "spec4" else 0)
+    if case == "sharing":
+        assert st.share_events > 0
+        assert 0 < st.prefill_tokens_saved <= 4 * st.shared_pages
+    else:
+        assert st.share_events == st.shared_pages == 0
