@@ -441,8 +441,13 @@ class PagedKVCache:
         pt = self._host_table()
         kp, vp = self.k_pages, self.v_pages
         ks, vs = self.k_scale, self.v_scale
+        copied = 0
         with _donation_noop_ok():
             for pi, src in shared:
+                if refs[src] == 1:
+                    # This slot mapped ``src`` twice and an earlier copy
+                    # left it the page's last owner: write in place.
+                    continue
                 dst = free.pop()
                 src_i = np.int32(src)
                 dst_i = np.int32(dst)
@@ -454,8 +459,9 @@ class PagedKVCache:
                 refs[src] -= 1
                 refs[dst] = 1
                 pt[seq, pi] = dst
+                copied += 1
         return dataclasses.replace(
             self, k_pages=kp, v_pages=vp, k_scale=ks, v_scale=vs,
             page_table=jnp.asarray(pt), page_table_host=pt,
             free=free, refcounts=refs,
-        ), len(shared)
+        ), copied

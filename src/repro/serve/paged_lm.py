@@ -9,7 +9,9 @@ drives (resource units = pages).
 
 The paged path is built as a *device-resident fast path*: the page pools are
 donated into every jitted call (``donate_argnums``) so they update in place
-instead of being copied per step, greedy sampling happens on device, and
+instead of being copied per step — each layer addresses its pages inside the
+one donated pool (:func:`_layer_view`), never a slice of it — greedy
+sampling happens on device, and
 ``decode_steps`` fuses ``n`` decode iterations into one ``lax.scan`` launch
 that feeds its own samples back — the host only sees tokens when the
 scheduler reaches a scheduling boundary (admission, page growth,
@@ -50,6 +52,29 @@ def _block_fill(lengths, active, page: int, ppb: int) -> float:
     return float(used.sum() / slots) if slots else 0.0
 
 
+def _layer_view(*pools):
+    """Each ``(L, P, ...)`` pool (or ``None``) as one ``(L·P, ...)`` array.
+
+    Merging the two leading dims is a bitcast, so the view is the donated
+    buffer itself: a program addresses layer ``l``'s page ``j`` as row
+    ``l·P + j`` (its page table plus ``l·P``) and every layer's write
+    aliases the one pool in place.  Slicing ``pool[l]`` instead would hand
+    the aliasing write kernels an operand that does not own its buffer, so
+    XLA would copy each layer out and stack the layers back, a whole-pool
+    copy per pool per program.
+    """
+    return [None if a is None else a.reshape((-1,) + a.shape[2:])
+            for a in pools]
+
+
+def _pool_view(shape, k_pages, v_pages, k_scale, v_scale):
+    """The :func:`_layer_view` pools back in the cache's ``(L, P, ...)``
+    ``shape`` (the scale pools without its last dim)."""
+    return (k_pages.reshape(shape), v_pages.reshape(shape),
+            None if k_scale is None else k_scale.reshape(shape[:-1]),
+            None if v_scale is None else v_scale.reshape(shape[:-1]))
+
+
 def _paged_lm_decode_step(params, tokens, k_pages, v_pages, k_scale, v_scale,
                           page_table, lengths, active, *, h, kvh, hd, impl):
     """One batched decode step against the paged pool.
@@ -64,42 +89,35 @@ def _paged_lm_decode_step(params, tokens, k_pages, v_pages, k_scale, v_scale,
     quantizes on write (codes + scales through the same indirect burst) and
     attention dequantizes page-by-page in VMEM.
 
-    The per-layer pool updates are collected and stacked once at the end
-    (rather than chained through ``k_pages.at[l].set``), so the trace holds
-    one full-pool value instead of L intermediates; with the pools donated
-    at the jit boundary XLA aliases that single value back into the input
-    buffers — an in-place update of the resident pool.
+    Every layer writes and reads its own pages inside the one pool value
+    (:func:`_layer_view`), so with the pools donated at the jit boundary
+    XLA aliases each layer's write into the input buffers — an in-place
+    update of the resident pool, with no layer sliced out or stacked back.
     """
-    n_layers = params["wq"].shape[0]
+    n_layers, n_pool = k_pages.shape[:2]
     b = tokens.shape[0]
     quantized = k_scale is not None
     x = jnp.take(params["embed"], tokens, axis=0)          # (B, d)
     new_len = lengths + active.astype(lengths.dtype)
-    kps, vps, kss, vss = [], [], [], []
+    kp, vp, ks, vs = _layer_view(k_pages, v_pages, k_scale, v_scale)
     for l in range(n_layers):
         q = (x @ params["wq"][l]).reshape(b, h, hd)
         kn = (x @ params["wk"][l]).reshape(b, kvh, hd)
         vn = (x @ params["wv"][l]).reshape(b, kvh, hd)
-        scales = (dict(k_scale=k_scale[l], v_scale=v_scale[l])
-                  if quantized else {})
+        table = page_table + l * n_pool
+        scales = dict(k_scale=ks, v_scale=vs) if quantized else {}
         out = kops.paged_kv_append(
-            k_pages[l], v_pages[l], kn, vn, page_table, lengths, active,
-            impl=impl, **scales,
+            kp, vp, kn, vn, table, lengths, active, impl=impl, **scales,
         )
         kp, vp = out[0], out[1]
-        ks, vs = (out[3], out[4]) if quantized else (None, None)
-        kps.append(kp)
-        vps.append(vp)
-        kss.append(ks)
-        vss.append(vs)
+        if quantized:
+            ks, vs = out[3], out[4]
         attn = kops.paged_decode_attention(
-            q, kp, vp, page_table, new_len, k_scale=ks, v_scale=vs, impl=impl
+            q, kp, vp, table, new_len, k_scale=ks, v_scale=vs, impl=impl
         )
         x = x + attn.reshape(b, h * hd) @ params["wo"][l]
     logits = x @ params["embed"].T                          # (B, vocab)
-    return (logits, jnp.stack(kps), jnp.stack(vps),
-            jnp.stack(kss) if quantized else None,
-            jnp.stack(vss) if quantized else None, new_len)
+    return (logits, *_pool_view(k_pages.shape, kp, vp, ks, vs), new_len)
 
 
 def _paged_lm_decode_steps(params, tokens, k_pages, v_pages, k_scale,
@@ -158,32 +176,27 @@ def _paged_lm_prefill_batch(params, tokens, counts, seqs, starts, k_pages,
     attention dequantize per context page.  Returns the last *real* token's
     logits per row plus the updated pools.
     """
-    n_layers = params["wq"].shape[0]
+    n_layers, n_pool = k_pages.shape[:2]
     r, c = tokens.shape
     quantized = k_scale is not None
     x = jnp.take(params["embed"], tokens, axis=0)          # (R, C, d)
     rows = jnp.take(page_table, seqs, axis=0)              # (R, n_pages)
-    ctx_rows = rows[:, :ctx_pages]
-    kps, vps, kss, vss = [], [], [], []
+    kp, vp, ks, vs = _layer_view(k_pages, v_pages, k_scale, v_scale)
     for l in range(n_layers):
         kn = (x @ params["wk"][l]).reshape(r, c, kvh, hd)
         vn = (x @ params["wv"][l]).reshape(r, c, kvh, hd)
-        scales = (dict(k_scale=k_scale[l], v_scale=v_scale[l])
-                  if quantized else {})
+        rows_l = rows + l * n_pool
+        scales = dict(k_scale=ks, v_scale=vs) if quantized else {}
         out = kops.paged_kv_write_chunk(
-            k_pages[l], v_pages[l], kn, vn, rows, starts, counts,
-            impl=impl, **scales,
+            kp, vp, kn, vn, rows_l, starts, counts, impl=impl, **scales,
         )
         kp, vp = out[0], out[1]
-        ks, vs = (out[2], out[3]) if quantized else (None, None)
-        kps.append(kp)
-        vps.append(vp)
-        kss.append(ks)
-        vss.append(vs)
+        if quantized:
+            ks, vs = out[2], out[3]
         q = (x @ params["wq"][l]).reshape(r, c, h, hd)
         attn = kops.paged_prefill_attention(
-            q, kp, vp, ctx_rows, starts, counts, k_scale=ks, v_scale=vs,
-            impl=impl,
+            q, kp, vp, rows_l[:, :ctx_pages], starts, counts, k_scale=ks,
+            v_scale=vs, impl=impl,
         )
         x = x + attn.astype(x.dtype).reshape(r, c, h * hd) @ params["wo"][l]
     last = jnp.take_along_axis(
@@ -195,9 +208,8 @@ def _paged_lm_prefill_batch(params, tokens, counts, seqs, starts, k_pages,
     new_len = lengths.at[jnp.where(counts > 0, seqs, b)].set(
         (starts + counts).astype(lengths.dtype), mode="drop"
     )
-    return (last @ params["embed"].T, jnp.stack(kps), jnp.stack(vps),
-            jnp.stack(kss) if quantized else None,
-            jnp.stack(vss) if quantized else None, new_len)
+    return (last @ params["embed"].T,
+            *_pool_view(k_pages.shape, kp, vp, ks, vs), new_len)
 
 
 def _paged_lm_verify_step(params, q_tokens, k_pages, v_pages, k_scale,
@@ -218,37 +230,30 @@ def _paged_lm_verify_step(params, q_tokens, k_pages, v_pages, k_scale,
     updated pools; lengths are *not* advanced here (the accept step owns
     that, since only accepted tokens survive).
     """
-    n_layers = params["wq"].shape[0]
+    n_layers, n_pool = k_pages.shape[:2]
     b, k = q_tokens.shape
     quantized = k_scale is not None
     x = jnp.take(params["embed"], q_tokens, axis=0)        # (B, K, d)
-    ctx_rows = page_table[:, :ctx_pages]
-    kps, vps, kss, vss = [], [], [], []
+    kp, vp, ks, vs = _layer_view(k_pages, v_pages, k_scale, v_scale)
     for l in range(n_layers):
         kn = (x @ params["wk"][l]).reshape(b, k, kvh, hd)
         vn = (x @ params["wv"][l]).reshape(b, k, kvh, hd)
-        scales = (dict(k_scale=k_scale[l], v_scale=v_scale[l])
-                  if quantized else {})
+        table = page_table + l * n_pool
+        scales = dict(k_scale=ks, v_scale=vs) if quantized else {}
         out = kops.paged_kv_write_chunk(
-            k_pages[l], v_pages[l], kn, vn, page_table, lengths, counts,
-            impl=impl, **scales,
+            kp, vp, kn, vn, table, lengths, counts, impl=impl, **scales,
         )
         kp, vp = out[0], out[1]
-        ks, vs = (out[2], out[3]) if quantized else (None, None)
-        kps.append(kp)
-        vps.append(vp)
-        kss.append(ks)
-        vss.append(vs)
+        if quantized:
+            ks, vs = out[2], out[3]
         q = (x @ params["wq"][l]).reshape(b, k, h, hd)
         attn = kops.paged_verify(
-            q, kp, vp, ctx_rows, lengths, counts, k_scale=ks, v_scale=vs,
-            impl=impl,
+            q, kp, vp, table[:, :ctx_pages], lengths, counts, k_scale=ks,
+            v_scale=vs, impl=impl,
         )
         x = x + attn.astype(x.dtype).reshape(b, k, h * hd) @ params["wo"][l]
     logits = x @ params["embed"].T                          # (B, K, vocab)
-    return (logits, jnp.stack(kps), jnp.stack(vps),
-            jnp.stack(kss) if quantized else None,
-            jnp.stack(vss) if quantized else None)
+    return (logits, *_pool_view(k_pages.shape, kp, vp, ks, vs))
 
 
 def _paged_lm_verify_steps(params, feed, dstate, k_pages, v_pages, k_scale,

@@ -6,6 +6,8 @@ quantized pool (in-VMEM dequant vs the shared ``dequantize_pages`` oracle
 rule), the engine/scheduler serving mode (``kv_dtype='int8'``: donated
 scale pools, eviction/replay rebuilding codes *and* scales bit-for-bit).
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -325,3 +327,115 @@ def test_scheduler_rejects_mismatched_kv_dtype():
     cache16 = PagedKVCache.create(CFG, batch=1, max_len=8, page=4,
                                   kv_dtype=model16.kv_dtype)
     Scheduler(model16, cache16, chunk=4)
+
+
+# ---------------------------------------------------------------------------
+# Layer addressing: each layer's pages inside the one pool
+# ---------------------------------------------------------------------------
+
+
+#: The write and attention ops' keywords for the scale pools, in order.
+SCALES = ("k_scale", "v_scale")
+
+
+def _layerwise_prefill(model, pools, table, tokens, counts, seqs, starts,
+                       ctx):
+    """The engine's prefill with each layer's pools sliced out and stacked
+    back: the addressing its one-pool view replaces."""
+    p, impl, (h, kvh, hd) = model.params, model.impl, (model.h, model.kvh,
+                                                       model.hd)
+    r, c = tokens.shape
+    x = jnp.take(p["embed"], tokens, axis=0)
+    rows = jnp.take(table, seqs, axis=0)
+    new = []
+    for l in range(model.cfg.n_layers):
+        k, v, *scales = [a[l] for a in pools]
+        kn = (x @ p["wk"][l]).reshape(r, c, kvh, hd)
+        vn = (x @ p["wv"][l]).reshape(r, c, kvh, hd)
+        out = ops.paged_kv_write_chunk(k, v, kn, vn, rows, starts, counts,
+                                       impl=impl, **dict(zip(SCALES, scales)))
+        new.append(out)
+        q = (x @ p["wq"][l]).reshape(r, c, h, hd)
+        attn = ops.paged_prefill_attention(q, out[0], out[1], rows[:, :ctx],
+                                           starts, counts, impl=impl,
+                                           **dict(zip(SCALES, out[2:])))
+        x = x + attn.astype(x.dtype).reshape(r, c, h * hd) @ p["wo"][l]
+    last = x[jnp.arange(r), jnp.clip(counts - 1, 0, c - 1)]
+    return last @ p["embed"].T, [jnp.stack(a) for a in zip(*new)]
+
+
+def _layerwise_decode(model, pools, table, lengths, tokens, active):
+    """One decode step, layer by layer over sliced pools (as above)."""
+    p, impl, (h, kvh, hd) = model.params, model.impl, (model.h, model.kvh,
+                                                       model.hd)
+    b = tokens.shape[0]
+    x = jnp.take(p["embed"], tokens, axis=0)
+    new_len = lengths + active.astype(lengths.dtype)
+    new = []
+    for l in range(model.cfg.n_layers):
+        k, v, *scales = [a[l] for a in pools]
+        kn = (x @ p["wk"][l]).reshape(b, kvh, hd)
+        vn = (x @ p["wv"][l]).reshape(b, kvh, hd)
+        out = ops.paged_kv_append(k, v, kn, vn, table, lengths, active,
+                                  impl=impl, **dict(zip(SCALES, scales)))
+        out = out[:2] + out[3:]
+        new.append(out)
+        q = (x @ p["wq"][l]).reshape(b, h, hd)
+        attn = ops.paged_decode_attention(q, out[0], out[1], table, new_len,
+                                          impl=impl,
+                                          **dict(zip(SCALES, out[2:])))
+        x = x + attn.reshape(b, h * hd) @ p["wo"][l]
+    return x @ p["embed"].T, [jnp.stack(a) for a in zip(*new)]
+
+
+def _pools(cache):
+    out = [cache.k_pages, cache.v_pages]
+    if cache.quantized:
+        out += [cache.k_scale, cache.v_scale]
+    return [np.asarray(a) for a in out]
+
+
+@pytest.mark.parametrize("impl", ["ref", "pallas"])
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_engine_writes_each_layer_into_its_own_pages(kv, impl):
+    """A prefill chunk and three decode steps through the engine, which
+    addresses layer ``l``'s page ``j`` as row ``l·P + j`` of one pool, leave
+    every layer's K/V (and scales) bitwise what the same ops write into that
+    layer's own sliced pool; the pages no slot maps stay as created in every
+    layer.  Three layers, so an offset of ``P`` alone cannot pass."""
+    cfg = dataclasses.replace(CFG, n_layers=3)
+    model = PagedLM(cfg, jax.random.PRNGKey(3), impl=impl, kv_dtype=kv)
+    cache = PagedKVCache.create(cfg, batch=3, max_len=16, page=4,
+                                kv_dtype=kv)
+    cache = cache.allocate(0, 3).allocate(2, 3)
+    fresh = _pools(cache)
+    mapped = sorted(set(cache.page_table_host[[0, 2], :3].ravel()))
+    rng = np.random.default_rng(7)
+    tokens = rng.integers(0, cfg.vocab, (2, 8)).astype(np.int32)
+    counts, seqs = np.asarray([5, 7], np.int32), np.asarray([0, 2], np.int32)
+    starts = np.zeros(2, np.int32)
+
+    want_lg, want = jax.jit(_layerwise_prefill, static_argnums=(0, 7))(
+        model, _pools(cache), cache.page_table, tokens, counts, seqs, starts,
+        2)
+    lg, cache = model.prefill_batch(tokens, counts, seqs, starts, cache)
+    np.testing.assert_array_equal(np.asarray(lg), np.asarray(want_lg))
+    for got, w in zip(_pools(cache), want):
+        np.testing.assert_array_equal(got, np.asarray(w))
+
+    active = jnp.asarray([True, False, True])
+    feed = jnp.asarray([1, 0, 2], jnp.int32)
+    for _ in range(3):
+        want_lg, want = jax.jit(_layerwise_decode, static_argnums=0)(
+            model, _pools(cache), cache.page_table, cache.lengths, feed,
+            active)
+        lg, cache = model.decode_step(feed, cache, active)
+        np.testing.assert_array_equal(np.asarray(lg), np.asarray(want_lg))
+        for got, w in zip(_pools(cache), want):
+            np.testing.assert_array_equal(got, np.asarray(w))
+        feed = jnp.argmax(lg[:, :cfg.vocab], axis=-1).astype(jnp.int32)
+
+    free = np.setdiff1d(np.arange(cache.total_pages), mapped)
+    assert free.size
+    for got, f in zip(_pools(cache), fresh):
+        np.testing.assert_array_equal(got[:, free], f[:, free])

@@ -217,6 +217,26 @@ def test_refcount_lifecycle_fuzz(seed, n_ops):
     assert sorted(cache.free) == list(range(pool))
 
 
+def test_cow_of_a_page_one_slot_maps_twice():
+    """A slot that shares its own page (table ``[8, 8]``, two of the page's
+    owners) copies it once: the first copy leaves the slot the second
+    mapping's only owner, which is then written in place, and no page is
+    left owned by nobody and missing from the free list."""
+    pool = 10
+    cache = PagedKVCache.create(CFG, batch=2, max_len=MAX_LEN, page=PAGE,
+                                pool_pages=pool)
+    cache = cache.allocate(0, 1)
+    own = int(cache.page_table_host[0, 0])
+    cache = cache.share(0, [own])
+    assert list(cache.page_table_host[0, :2]) == [own, own]
+    cache, copied = cache.ensure_writable(0, 0, 2 * PAGE - 1)
+    assert copied == 1
+    owned = {p for p in range(pool) if cache.refcounts[p] > 0}
+    assert owned == set(int(p) for p in cache.page_table_host[0, :2])
+    assert len(owned) == 2 and not owned & set(cache.free)
+    assert len(owned) + len(cache.free) == pool
+
+
 def test_cow_on_page_aligned_full_match():
     """A prompt that fully matches a page-multiple indexed prefix must
     copy-on-write its final shared page (the re-prefilled last token writes

@@ -14,6 +14,7 @@ process at a time may load the TPU library, and every test worker imports
 every test file.
 """
 import json
+import math
 import os
 import re
 
@@ -23,6 +24,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 import chip_smoke
+from bench.families import paged_attn as paged_bench
 from bench.families import rwkv6 as rwkv_bench
 from bench.trace import is_pallas, scalar_operands
 from repro.configs import get_config
@@ -283,3 +285,76 @@ def test_paged_lm_fused_decode_program_compiles(spec, on_tpu):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert used < 16e9, f"decode program needs {used / 1e9:.1f} GB"
+
+
+# ---------------------------------------------------------------------------
+# PagedLM's programs at the longctx cell's widths take the pools in place
+# ---------------------------------------------------------------------------
+
+#: Instructions that move no bytes: they name a buffer another one wrote.
+PLUMBING = ("parameter", "bitcast", "get-tuple-element", "tuple", "while")
+
+
+@pytest.mark.parametrize("program,size", [("decode", 1), ("decode", 4),
+                                          ("prefill", 4), ("prefill", 16),
+                                          ("verify", 2)],
+                         ids=["decode-n1", "decode-n4", "prefill-r4",
+                              "prefill-r16", "verify-n2"])
+def test_paged_lm_programs_address_layers_inside_the_pool(spec, on_tpu,
+                                                          program, size):
+    """The benchmark's 4-layer yi-6b model over 64 slots of 256 pages (two
+    1.07 GB bf16 pools): fused decode of 1 and 4 steps, prefill of 4 and
+    16 rows at 256 context pages, and two fused speculative verify steps
+    of 4 tokens.  Only the aliasing write kernels
+    output an array the size of a layer's pool or more, tuples included —
+    no slice of a layer out of a pool, no stack of the layers back — and
+    the program's temporaries stay far below one pool."""
+    with open(os.path.join(ROOT, "bench", "configs",
+                           "yi6b-attn-l4.json")) as f:
+        cell = json.load(f)
+    cfg = paged_bench.arch(cell)
+    params = jax.eval_shape(lambda k: PagedLM(cfg, k).params,
+                            jax.random.PRNGKey(0))
+    model = PagedLM(cfg, None, params=_on_chip(spec, params),
+                    kv_dtype=cell["kv_dtype"], spec_k=4)
+
+    def pools():
+        c = PagedKVCache.create(cfg, batch=cell["slots"],
+                                max_len=cell["max_position_embeddings"],
+                                page=cell["page_size"],
+                                kv_dtype=model.kv_dtype)
+        return c.k_pages, c.v_pages, c.page_table, c.lengths
+
+    kp, vp, table, lens = _on_chip(spec, jax.eval_shape(pools))
+    assert table.shape == (CELL_BATCH, CELL_PAGES)
+    assert kp.dtype == jnp.bfloat16 and kp.shape[0] == 4
+    if program == "decode":
+        rows = spec((CELL_BATCH,), jnp.int32)
+        compiled = model._decode_many.lower(
+            model.params, rows, kp, vp, None, None, table, lens,
+            spec((CELL_BATCH,), jnp.bool_), n=size).compile()
+    elif program == "verify":
+        rows = spec((CELL_BATCH,), jnp.int32)
+        drafts = _on_chip(spec, jax.eval_shape(
+            lambda: model.drafter.init_state(CELL_BATCH)))
+        compiled = model._verify(4, CELL_PAGES).lower(
+            model.params, rows, drafts, kp, vp, None, None, table, lens,
+            spec((CELL_BATCH,), jnp.bool_), rows, n=size).compile()
+    else:
+        rows = spec((size,), jnp.int32)
+        tokens = spec((size, cell["prefill_chunk"]), jnp.int32)
+        compiled = model._prefill(cell["page_size"], CELL_PAGES).lower(
+            model.params, tokens, rows, rows, rows, kp, vp, None, None,
+            table, lens).compile()
+    layer_pool = math.prod(kp.shape[1:])
+    for ln in _hlo_lines(compiled):
+        m = re.search(r"= (.+?) ([\w-]+)\(", ln)
+        if not m or m.group(2) in PLUMBING:
+            continue
+        sizes = [math.prod(map(int, dims.split(",")))
+                 for dims in re.findall(r"bf16\[([\d,]+)\]", m.group(1))]
+        if max(sizes, default=0) >= layer_pool:
+            assert (m.group(2) == "custom-call" and "tpu_custom_call" in ln
+                    and "output_to_operand_aliasing" in ln), ln
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 0.5e9, f"{program} temporaries {temp / 1e9:.2f} GB"
